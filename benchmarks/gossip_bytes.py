@@ -39,12 +39,12 @@ import time
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 
 from benchmarks.common import emit
 from repro.core import consensus
 from repro.dist.sharding import gossip_specs
 from repro.launch.hlo import collective_bytes
+from repro.launch.mesh import make_mesh
 
 ROWS = 256          # message rows: (rows, 128) per worker, ~131 KB f32
 DELTA, J = 0.05, 1.0
@@ -54,7 +54,7 @@ def bench_topology(topology: str, n: int, rows: int = ROWS) -> dict:
     Q = consensus.gossip_matrix(topology, n)
     lam2 = consensus.lambda2(Q)
     r = consensus.min_rounds(DELTA, n, J, lam2)
-    mesh = jax.sharding.Mesh(np.asarray(jax.devices()[:n]), ("worker",))
+    mesh = make_mesh((n,), ("worker",))
     sp = gossip_specs().msg
 
     rng = np.random.default_rng(0)
@@ -75,8 +75,8 @@ def bench_topology(topology: str, n: int, rows: int = ROWS) -> dict:
             def local(x, res):
                 return consensus.gossip_rounds_shard(
                     x, "worker", topology, n, r), res
-        fn = jax.jit(shard_map(local, mesh=mesh, in_specs=(sp, sp),
-                               out_specs=(sp, sp), check_rep=False))
+        fn = jax.jit(jax.shard_map(local, mesh=mesh, in_specs=(sp, sp),
+                                   out_specs=(sp, sp), check_vma=False))
         compiled = fn.lower(v, res0).compile()
         coll = collective_bytes(compiled.as_text())
         # the SPMD program text is per-device, so the census is

@@ -66,8 +66,6 @@ class _Timed:
             state, grads, counts)
         self.compiled = lowered.compile()
         cost = self.compiled.cost_analysis()
-        if isinstance(cost, (list, tuple)):
-            cost = cost[0] if cost else {}
         self.bytes_accessed = int(cost.get("bytes accessed", -1))
         self.copy_bytes = copy_bytes(self.compiled.as_text())
         self.state = state

@@ -70,7 +70,7 @@ def measure(cfg: ModelConfig, shape_name: str, n_mb: int = 1,
                    mesh=mesh_config(False),
                    ambdg=AmbdgConfig(tau=tau, n_microbatches=n_mb),
                    remat="none")
-    mesh = make_mesh(rc.mesh)
+    mesh = make_mesh(rc.mesh.shape, rc.mesh.axis_names)
     if rc.shape.kind == "train":
         lowered = dr.lower_train(rc, mesh)
     elif rc.shape.kind == "prefill":

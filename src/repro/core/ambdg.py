@@ -215,8 +215,22 @@ def build_step_fns(model: Model, rc: RunConfig):
         return g, c, m["loss_sum"]
 
     def train_step(state: TrainState, batch) -> Tuple[TrainState, Dict]:
-        from repro.dist.context import sharding_profile
-        with sharding_profile(rc.mesh if rc.mesh.n_devices > 1 else None):
+        from repro.dist.context import (active_mesh, ambient_mesh,
+                                        profile_set, sharding_profile)
+        # a caller's profile holds: under `sharding_profile(None)` a
+        # multi-pod config runs as one program on one device (the pod
+        # exchange a local fold), the reference a sharded run is
+        # checked against
+        mesh_cfg = (active_mesh() if profile_set()
+                    else rc.mesh if rc.mesh.n_devices > 1 else None)
+        if (mesh_cfg is not None and mesh_cfg.n_devices > 1
+                and ambient_mesh() is None):
+            raise ValueError(
+                f"the {mesh_cfg.shape} mesh profile needs an ambient "
+                "mesh: run the step under `with jax.set_mesh(mesh):`, "
+                "or under `sharding_profile(None)` to run it as one "
+                "program on one device")
+        with sharding_profile(mesh_cfg):
             return _train_step_inner(state, batch)
 
     def _train_step_inner(state: TrainState, batch) -> Tuple[TrainState, Dict]:

@@ -699,11 +699,11 @@ def push_pop(layout: ArenaLayout, arena: GradArena, pod_grads, pod_counts,
     ``_push_pop_v2``); the only kernel left is the int8 rotate —
     impl="auto" picks Pallas for it on TPU, the XLA elementwise chain
     elsewhere, and the shard_map-wrapped kernel on a multi-pod mesh
-    (requires an ambient physical mesh; the pop's pod reduction then
-    happens inside the wrapper, int8 payload crossing the DCN
-    compressed). v1 rings keep the stacked-buffer paths: lax.switch
-    scatter + dynamic pop on XLA, scalar-prefetched-head kernel on
-    single-pod TPU.
+    (requires an ambient mesh, ``jax.set_mesh``; the pop's pod
+    reduction then happens inside the wrapper, int8 payload crossing
+    the DCN compressed). v1 rings keep the stacked-buffer paths:
+    lax.switch scatter + dynamic pop on XLA, scalar-prefetched-head
+    kernel on single-pod TPU.
     """
     from repro.kernels import resolve_impl
     from repro.kernels.delay_ring.ops import ring_push_pop

@@ -30,7 +30,6 @@ from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple, Type
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from repro.configs.base import RunConfig
 from repro.core import ambdg, anytime, consensus
@@ -447,8 +446,8 @@ class DecentralizedStrategy(Strategy):
         self.gossip_impl = self._resolve_gossip_impl(cc)
         self._mesh = None
         if self.gossip_impl == "shard_map":
-            self._mesh = jax.sharding.Mesh(
-                np.asarray(jax.devices()[:n]), ("worker",))
+            from repro.launch.mesh import make_mesh
+            self._mesh = make_mesh((n,), ("worker",))
         self.init_state, self.train_step = self._build()
 
     @staticmethod
@@ -494,7 +493,6 @@ class DecentralizedStrategy(Strategy):
         if self.gossip_impl != "shard_map":
             raise ValueError(f"unknown gossip_impl "
                              f"{self.gossip_impl!r}")
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec
 
         from repro.dist.sharding import gossip_specs
@@ -510,11 +508,11 @@ class DecentralizedStrategy(Strategy):
                 return consensus.gossip_rounds_shard_masked(
                     x, "worker", topology, n, rounds, active), res
 
-            return shard_map(local_masked, mesh=self._mesh,
-                             in_specs=(msg_spec, msg_spec,
-                                       PartitionSpec()),
-                             out_specs=(msg_spec, msg_spec),
-                             check_rep=False)
+            return jax.shard_map(local_masked, mesh=self._mesh,
+                                 in_specs=(msg_spec, msg_spec,
+                                           PartitionSpec()),
+                                 out_specs=(msg_spec, msg_spec),
+                                 check_vma=False)
 
         def local(x, res):   # x, res: (1, rows, 128) — this worker's
             if compression == "int8":
@@ -523,9 +521,9 @@ class DecentralizedStrategy(Strategy):
             return consensus.gossip_rounds_shard(
                 x, "worker", topology, n, rounds), res
 
-        return shard_map(local, mesh=self._mesh,
-                         in_specs=(msg_spec, msg_spec),
-                         out_specs=(msg_spec, msg_spec), check_rep=False)
+        return jax.shard_map(local, mesh=self._mesh,
+                             in_specs=(msg_spec, msg_spec),
+                             out_specs=(msg_spec, msg_spec), check_vma=False)
 
     def _build(self):
         model, rc = self.model, self.rc

@@ -7,6 +7,7 @@ shardings.
     batch_specs(model, rc)          specs for the global batch pytree
     state_specs(model, rc, init)    specs for the whole TrainState
     to_shardings(specs, mesh)       PartitionSpec tree -> NamedSharding
+    jit_train_step(...)             the step jitted with those shardings
 """
 from __future__ import annotations
 
@@ -16,7 +17,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.dist.sharding import (_is_axes_leaf, shapes_and_axes,  # noqa: F401
                                  spec_for)
 
-__all__ = ["batch_specs", "shapes_and_axes", "spec_for",
+__all__ = ["batch_specs", "jit_train_step", "shapes_and_axes", "spec_for",
            "specs_for_state", "state_specs", "to_shardings"]
 
 
@@ -137,3 +138,24 @@ def retree_specs(specs, target):
     leaves = jax.tree.flatten(specs,
                               is_leaf=lambda x: isinstance(x, P))[0]
     return jax.tree.unflatten(jax.tree.structure(target), leaves)
+
+
+def jit_train_step(train_step, st_specs, b_specs, state, batch, mesh):
+    """``train_step`` jitted on ``mesh`` with the state (donated) and
+    batch placed by ``st_specs`` / ``b_specs``; metrics replicated.
+    ``state`` and ``batch`` (arrays or ShapeDtypeStructs) fix the input
+    structure: the arena's static slot phase advances every step, so a
+    fixed-delay ring needs one jitted step per phase, and the output
+    specs are the input specs moved onto the advanced structure."""
+    st_specs = retree_specs(st_specs, state)
+    with jax.set_mesh(mesh):
+        out_state, out_metrics = jax.eval_shape(train_step, state, batch)
+    metrics_spec = jax.tree.map(lambda _: P(), out_metrics)
+    return jax.jit(
+        train_step,
+        in_shardings=(to_shardings(st_specs, mesh),
+                      to_shardings(b_specs, mesh)),
+        out_shardings=(to_shardings(retree_specs(st_specs, out_state),
+                                    mesh),
+                       to_shardings(metrics_spec, mesh)),
+        donate_argnums=(0,))

@@ -38,14 +38,18 @@ def active_mesh():
     return active[0] if active is not None else None
 
 
-def active_physical_mesh():
-    """The ambient physical ``jax.sharding.Mesh`` (set by ``with
-    mesh:``), or None. The shard_map wrapper around the delay-ring
-    kernel needs the actual mesh object — a MeshConfig names the axes
-    but owns no devices; without an ambient mesh the wrapper cannot
-    lower and the caller falls back to the XLA ref path."""
-    from jax.interpreters import pxla
-    mesh = pxla.thread_resources.env.physical_mesh
+def profile_set() -> bool:
+    """Whether a caller has entered ``sharding_profile`` (``None``
+    counts: it is an explicit request for no mesh)."""
+    return bool(getattr(_state, "stack", None))
+
+
+def ambient_mesh():
+    """The mesh set by ``with jax.set_mesh(mesh):`` as an
+    ``AbstractMesh`` (readable inside and outside jit), or None. The
+    shard_map wrappers around the arena kernels run over it — a
+    MeshConfig names the axes but owns no devices."""
+    mesh = jax.sharding.get_abstract_mesh()
     return None if mesh.empty else mesh
 
 
@@ -71,4 +75,10 @@ def constrain(x, axes):
         return x
     mesh_cfg, profile = active
     spec = spec_for(tuple(axes), tuple(x.shape), mesh_cfg, profile=profile)
+    if not isinstance(x, jax.core.Tracer):
+        # outside jit the constraint is a transfer onto the concrete
+        # devices; a bare spec names only the abstract mesh
+        mesh = jax.sharding.get_mesh()
+        if not mesh.empty:
+            spec = jax.sharding.NamedSharding(mesh, spec)
     return jax.lax.with_sharding_constraint(x, spec)

@@ -25,6 +25,9 @@ Each kernel directory: kernel.py (pl.pallas_call + BlockSpec), ops.py
 from __future__ import annotations
 
 import math
+from typing import Optional
+
+import jax
 
 
 def dim_shard(entry, mesh) -> int:
@@ -35,11 +38,34 @@ def dim_shard(entry, mesh) -> int:
     return math.prod(int(mesh.shape[n]) for n in names)
 
 
-def fit_block_rows(rows: int, want: int) -> int:
+def fit_block_rows(rows: int, want: int, *, int8: bool = False,
+                   interpret: bool = False) -> int:
     """Largest block <= ``want`` dividing ``rows`` (gcd keeps it a
     multiple of 8 whenever rows is, which the arena layout guarantees
-    down to any power-of-two device count)."""
-    return math.gcd(rows, want)
+    down to any power-of-two device count).
+
+    The chip refuses a block whose last two dims are neither whole
+    tiles nor the full dims. A 32-bit row block is whole at 8 rows. The
+    int8 kernels also stream per-row scales whose row dim is the 128
+    lanes, so their block is 128 rows (or all of them). Outside
+    interpret mode a block the chip would refuse raises here."""
+    blk = math.gcd(rows, want)
+    align = 128 if int8 else 8
+    if not interpret and blk % align and blk != rows:
+        raise ValueError(f"{rows} rows admit no block <= {want} rows "
+                         f"that is a multiple of {align}")
+    return blk
+
+
+def on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
+
+
+def resolve_interpret(interpret: Optional[bool]) -> bool:
+    """Pallas interpret mode unless asked otherwise: off on the TPU,
+    on elsewhere (the CPU tests run the kernels through the
+    interpreter)."""
+    return (not on_tpu()) if interpret is None else interpret
 
 
 def resolve_impl(impl: str = "auto", *, pod_shard_map: bool = False) -> str:
@@ -50,21 +76,26 @@ def resolve_impl(impl: str = "auto", *, pod_shard_map: bool = False) -> str:
     Multi-pod meshes: a bare pallas_call on a pod-sharded arena buffer
     would make GSPMD gather the whole buffer per device, so "auto"
     resolves to "ref" — UNLESS the caller has a shard_map wrapper
-    (``pod_shard_map=True``: the v2 delay ring and the dual_update
-    arena entry point) and an ambient physical mesh is available to
-    shard_map over, in which case it resolves to "pallas_sharded" and
-    the fused kernel runs per shard."""
+    (``pod_shard_map=True``: the v2 delay ring, the variable pop and
+    the dual_update arena entry point), in which case it resolves to
+    "pallas_sharded" and the fused kernel runs per shard. That wrapper
+    needs the ambient concrete mesh (``jax.set_mesh``); a caller that
+    has the wrapper but set no mesh is an error on the TPU, not a
+    silent fall back to the XLA path."""
     if impl != "auto":
         return impl
-    import jax
-
-    from repro.dist.context import active_mesh, active_physical_mesh
+    from repro.dist.context import active_mesh, ambient_mesh
     mesh = active_mesh()
     multi_pod = mesh is not None and mesh.n_pods > 1
-    if jax.default_backend() != "tpu":
+    if not on_tpu():
         return "ref"
     if not multi_pod:
         return "pallas"
-    if pod_shard_map and active_physical_mesh() is not None:
-        return "pallas_sharded"
-    return "ref"
+    if not pod_shard_map:
+        return "ref"
+    if ambient_mesh() is None:
+        raise ValueError(
+            "multi-pod sharding profile without an ambient mesh: run "
+            "the step under `with jax.set_mesh(mesh):` so the arena "
+            "kernels can shard_map over the 'pod' axis")
+    return "pallas_sharded"

@@ -44,10 +44,10 @@ def _ring_kernel_int8(head_ref, ring_ref, scales_ref, fed_ref,
     # the residual per step. residual_out aliases fed's buffer.
     del head_ref
     q_old = ring_ref[0].astype(jnp.float32)            # (1, B, 128)
-    s_old = scales_ref[0][..., None]                   # (1, B, 1)
+    s_old = scales_ref[0, 0][..., None]                # (1, B, 1)
     popped_ref[...] = q_old * s_old
     fed = fed_ref[...]
-    s = scale_new_ref[...][..., None]                  # (1, B, 1)
+    s = scale_new_ref[0][..., None]                    # (1, B, 1)
     q = jnp.clip(jnp.round(fed / s), -127, 127)
     ring_out_ref[...] = q[None].astype(jnp.int8)
     scales_out_ref[...] = scale_new_ref[...][None]
@@ -64,10 +64,10 @@ def _slot_kernel_int8(pop_ref, pop_scales_ref, push_ref, push_scales_ref,
     # are dead by construction); residual_out aliases fed's buffer.
     del push_ref, push_scales_ref
     q_old = pop_ref[...].astype(jnp.float32)           # (1, B, 128)
-    s_old = pop_scales_ref[...][..., None]             # (1, B, 1)
+    s_old = pop_scales_ref[0][..., None]               # (1, B, 1)
     popped_ref[...] = q_old * s_old
     fed = fed_ref[...]
-    s = scale_new_ref[...][..., None]                  # (1, B, 1)
+    s = scale_new_ref[0][..., None]                    # (1, B, 1)
     q = jnp.clip(jnp.round(fed / s), -127, 127)
     slot_out_ref[...] = q.astype(jnp.int8)
     scales_out_ref[...] = scale_new_ref[...]
@@ -90,12 +90,18 @@ def delay_ring_slot_fwd(slot_pop, scales_pop, slot_push, scales_push,
     error feedback, write the push slot — ring state donated end-to-end
     via input_output_aliases. (The f32 ring needs no kernel under v2:
     its pop is a plain read and its push a scatter into the spare
-    slot.) Returns (popped f32, slot_new, scales_new, residual_new)."""
+    slot.) Returns (popped f32, slot_new, scales_new, residual_new).
+
+    The kernel sees every (n_pods, rows) scales array as (n_pods, 1,
+    rows): the chip needs a block's last two dims to be whole (8, 128)
+    tiles or the full dims, and a (1, block_rows) block of an
+    (n_pods, rows) array is neither once n_pods > 1."""
     n_pods, rows, lanes = slot_pop.shape
     assert lanes == _LANES and rows % block_rows == 0, (slot_pop.shape,)
     grid = (n_pods, rows // block_rows)
     pods3 = pl.BlockSpec((1, block_rows, _LANES), lambda p, r: (p, r, 0))
-    pods2 = pl.BlockSpec((1, block_rows), lambda p, r: (p, r))
+    pods2 = pl.BlockSpec((1, 1, block_rows), lambda p, r: (p, 0, r))
+    scales3 = lambda s: s.reshape((n_pods, 1, rows))
 
     popped, slot_new, scales_new, residual_new = pl.pallas_call(
         _slot_kernel_int8, grid=grid,
@@ -104,14 +110,16 @@ def delay_ring_slot_fwd(slot_pop, scales_pop, slot_push, scales_push,
         out_shape=[
             jax.ShapeDtypeStruct((n_pods, rows, _LANES), jnp.float32),
             jax.ShapeDtypeStruct(slot_push.shape, jnp.int8),
-            jax.ShapeDtypeStruct(scales_push.shape, jnp.float32),
+            jax.ShapeDtypeStruct((n_pods, 1, rows), jnp.float32),
             jax.ShapeDtypeStruct(fed.shape, jnp.float32),
         ],
         # donate the push slot/scales; residual_new reuses fed's buffer
         input_output_aliases={2: 1, 3: 2, 4: 3},
         interpret=interpret,
-    )(slot_pop, scales_pop, slot_push, scales_push, fed, scale_new)
-    return popped, slot_new, scales_new, residual_new
+    )(slot_pop, scales3(scales_pop), slot_push, scales3(scales_push), fed,
+      scales3(scale_new))
+    return popped, slot_new, scales_new.reshape((n_pods, rows)), \
+        residual_new
 
 
 def _variable_meta(mask_ref, cs_ref, n_slots, meta_ref):
@@ -150,7 +158,7 @@ def _variable_pop_kernel_int8(mask_ref, cs_ref, ring_ref, scales_ref,
     acc = jnp.zeros(popped_ref.shape, jnp.float32)
     for j in range(ring_ref.shape[0]):
         m = mask_ref[j].astype(jnp.float32)
-        x = ring_ref[j].astype(jnp.float32) * scales_ref[j][..., None]
+        x = ring_ref[j].astype(jnp.float32) * scales_ref[j, 0][..., None]
         acc = acc + m * x
     popped_ref[...] = acc
     _variable_meta(mask_ref, cs_ref, ring_ref.shape[0], meta_ref)
@@ -216,15 +224,17 @@ def variable_pop_fwd(ring, mask, scales=None, counts_stale=None, *,
         )(mask, cs, ring)
         return (popped, meta.reshape((2,))) if with_meta else popped
 
-    slots3 = pl.BlockSpec((n_slots, 1, block_rows),
-                          lambda p, r, mask, cs: (0, p, r))
+    # scales viewed as (n_slots, n_pods, 1, rows): see
+    # delay_ring_slot_fwd for the chip's block-shape rule
+    scales4 = pl.BlockSpec((n_slots, 1, 1, block_rows),
+                           lambda p, r, mask, cs: (0, p, 0, r))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2, grid=grid,
-        in_specs=[slots4, slots3], out_specs=[pods3, meta_spec])
+        in_specs=[slots4, scales4], out_specs=[pods3, meta_spec])
     popped, meta = pl.pallas_call(
         _variable_pop_kernel_int8, grid_spec=grid_spec,
         out_shape=out_shape, interpret=interpret,
-    )(mask, cs, ring, scales)
+    )(mask, cs, ring, scales.reshape((n_slots, n_pods, 1, rows)))
     return (popped, meta.reshape((2,))) if with_meta else popped
 
 
@@ -259,9 +269,11 @@ def delay_ring_fwd(ring, g, head, scales=None, scale_new=None, *,
         )(head, ring, g)
         return popped, ring_new, None, None
 
-    slot2 = pl.BlockSpec((1, 1, block_rows),
-                         lambda p, r, head: (head[0], p, r))
-    pods2 = pl.BlockSpec((1, block_rows), lambda p, r, head: (p, r))
+    # scales viewed with a unit dim before rows: see
+    # delay_ring_slot_fwd for the chip's block-shape rule
+    slot2 = pl.BlockSpec((1, 1, 1, block_rows),
+                         lambda p, r, head: (head[0], p, 0, r))
+    pods2 = pl.BlockSpec((1, 1, block_rows), lambda p, r, head: (p, 0, r))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1, grid=grid,
         in_specs=[slot3, slot2, pods3, pods2],
@@ -271,11 +283,12 @@ def delay_ring_fwd(ring, g, head, scales=None, scale_new=None, *,
         out_shape=[
             jax.ShapeDtypeStruct((n_pods, rows, _LANES), jnp.float32),
             jax.ShapeDtypeStruct(ring.shape, jnp.int8),
-            jax.ShapeDtypeStruct(scales.shape, jnp.float32),
+            jax.ShapeDtypeStruct((tau, n_pods, 1, rows), jnp.float32),
             jax.ShapeDtypeStruct(g.shape, jnp.float32),
         ],
         # donate ring / scales in place; residual_new reuses fed's buffer
         input_output_aliases={1: 1, 2: 2, 3: 3},
         interpret=interpret,
-    )(head, ring, scales, g, scale_new)
-    return popped, ring_new, scales_new, residual_new
+    )(head, ring, scales.reshape((tau, n_pods, 1, rows)), g,
+      scale_new.reshape((n_pods, 1, rows)))
+    return popped, ring_new, scales_new.reshape(scales.shape), residual_new

@@ -47,10 +47,6 @@ from repro.kernels.delay_ring.ref import (ring_push_pop_ref,
                                           ring_variable_pop_ref)
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
 def ring_push_pop(ring, g, head, *, scales=None, scale_new=None,
                   impl: str = "auto", interpret: Optional[bool] = None,
                   block_rows: int = 256, constrain_axes=None):
@@ -61,13 +57,13 @@ def ring_push_pop(ring, g, head, *, scales=None, scale_new=None,
     residual is written into its donated buffer. Returns (popped,
     ring, scales, residual); state buffers are donated end-to-end.
     See ref.py for shapes."""
-    from repro.kernels import resolve_impl
+    from repro.kernels import resolve_impl, resolve_interpret
     impl = resolve_impl(impl)
     if impl == "ref":
         return ring_push_pop_ref(ring, g, head, scales=scales,
                                  scale_new=scale_new,
                                  constrain_axes=constrain_axes)
-    interp = (not _on_tpu()) if interpret is None else interpret
+    interp = resolve_interpret(interpret)
     return delay_ring_fwd(ring, g, head, scales=scales,
                           scale_new=scale_new, block_rows=block_rows,
                           interpret=interp)
@@ -82,12 +78,12 @@ def ring_slot_rotate_int8(slot_pop, scales_pop, slot_push, scales_push,
     fused pass (the two slots are different buffers, statically chosen
     by the caller's phase). Returns (popped f32, slot_new, scales_new,
     residual_new); residual_new reuses fed's buffer."""
-    from repro.kernels import resolve_impl
+    from repro.kernels import resolve_impl, resolve_interpret
     impl = resolve_impl(impl)
     if impl == "ref":
         return ring_slot_rotate_int8_ref(slot_pop, scales_pop, fed,
                                          scale_new)
-    interp = (not _on_tpu()) if interpret is None else interpret
+    interp = resolve_interpret(interpret)
     return delay_ring_slot_fwd(slot_pop, scales_pop, slot_push,
                                scales_push, fed, scale_new,
                                block_rows=block_rows, interpret=interp)
@@ -117,17 +113,17 @@ def ring_variable_pop(ring, mask, *, scales=None, counts_stale=None,
     bit-identity tests — the production CPU path is the O(arrivals)
     gather inside ``arena.push_pop_variable``, which never reaches this
     wrapper."""
-    from repro.kernels import fit_block_rows, resolve_impl
+    from repro.kernels import (fit_block_rows, resolve_impl,
+                               resolve_interpret)
     impl = resolve_impl(impl)
     if impl == "ref":
         popped = ring_variable_pop_ref(ring, mask, scales=scales)
         if counts_stale is None:
             return popped
         return popped, ring_variable_meta_ref(mask, counts_stale)
-    interp = (not _on_tpu()) if interpret is None else interpret
-    blk = fit_block_rows(ring.shape[2], block_rows)
-    if not interp:
-        assert blk % 8 == 0, (ring.shape, blk)
+    interp = resolve_interpret(interpret)
+    blk = fit_block_rows(ring.shape[2], block_rows,
+                         int8=scales is not None, interpret=interp)
     return variable_pop_fwd(ring, mask, scales=scales,
                             counts_stale=counts_stale, block_rows=blk,
                             interpret=interp)
@@ -155,25 +151,23 @@ def ring_variable_pop_sharded(ring, mask, *, scales=None,
     summed over pods — like the sharded rotate, the pod reduction
     happens inside (it IS the DCN collective) — or (grad_sum, meta)
     with ``counts_stale``."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
-    from repro.dist.context import active_physical_mesh
+    from repro.dist.context import ambient_mesh
     from repro.dist.sharding import arena_ring_specs
-    from repro.kernels import dim_shard, fit_block_rows
+    from repro.kernels import dim_shard, fit_block_rows, resolve_interpret
 
-    mesh = active_physical_mesh()
+    mesh = ambient_mesh()
     if mesh is None:
         raise ValueError("ring_variable_pop_sharded needs an ambient "
-                         "physical mesh (`with mesh:`)")
-    interp = (not _on_tpu()) if interpret is None else interpret
+                         "mesh (`with jax.set_mesh(mesh):`)")
+    interp = resolve_interpret(interpret)
     n_slots, n_pods, rows, _ = ring.shape
     ring_spec, scales_spec, row_spec = arena_ring_specs(mesh_cfg, rows)
     rows_local = rows // dim_shard(
         ring_spec[2] if len(ring_spec) > 2 else None, mesh)
-    blk = fit_block_rows(rows_local, block_rows)
-    if not interp:
-        assert blk % 8 == 0, (rows_local, blk)
+    blk = fit_block_rows(rows_local, block_rows, int8=scales is not None,
+                         interpret=interp)
     mask_spec = P()
     with_meta = counts_stale is not None
 
@@ -190,16 +184,16 @@ def ring_variable_pop_sharded(ring, mask, *, scales=None,
 
     out_specs = (row_spec, mask_spec) if with_meta else row_spec
     if scales is None:
-        fn = shard_map(lambda r, m, cs: local_pop(r, None, m, cs),
-                       mesh=mesh,
-                       in_specs=(ring_spec, mask_spec, mask_spec),
-                       out_specs=out_specs, check_rep=False)
+        fn = jax.shard_map(lambda r, m, cs: local_pop(r, None, m, cs),
+                           mesh=mesh,
+                           in_specs=(ring_spec, mask_spec, mask_spec),
+                           out_specs=out_specs, check_vma=False)
         args = (ring, mask)
     else:
-        fn = shard_map(local_pop, mesh=mesh,
-                       in_specs=(ring_spec, scales_spec, mask_spec,
-                                 mask_spec),
-                       out_specs=out_specs, check_rep=False)
+        fn = jax.shard_map(local_pop, mesh=mesh,
+                           in_specs=(ring_spec, scales_spec, mask_spec,
+                                     mask_spec),
+                           out_specs=out_specs, check_vma=False)
         args = (ring, scales, mask)
     cs = (jnp.asarray(counts_stale, jnp.float32) if with_meta
           else jnp.zeros((2, n_slots), jnp.float32))
@@ -234,25 +228,23 @@ def ring_slot_rotate_int8_sharded(slot_pop, scales_pop, slot_push,
     slot_new, scales_new, residual_new) — unlike the unsharded entry
     points, the pod reduction happens inside (it IS the DCN
     collective)."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
-    from repro.dist.context import active_physical_mesh
+    from repro.dist.context import ambient_mesh
     from repro.dist.sharding import arena_slot_specs
-    from repro.kernels import dim_shard, fit_block_rows
+    from repro.kernels import dim_shard, fit_block_rows, resolve_interpret
 
-    mesh = active_physical_mesh()
+    mesh = ambient_mesh()
     if mesh is None:
         raise ValueError("ring_slot_rotate_int8_sharded needs an "
-                         "ambient physical mesh (`with mesh:`)")
-    interp = (not _on_tpu()) if interpret is None else interpret
+                         "ambient mesh (`with jax.set_mesh(mesh):`)")
+    interp = resolve_interpret(interpret)
     n_pods, rows, _ = slot_pop.shape
     slot_spec, scales_spec, row_spec = arena_slot_specs(mesh_cfg, rows)
     rows_local = rows // dim_shard(
         slot_spec[1] if len(slot_spec) > 1 else None, mesh)
-    blk = fit_block_rows(rows_local, block_rows)
-    if not interp:
-        assert blk % 8 == 0, (rows_local, blk)
+    blk = fit_block_rows(rows_local, block_rows, int8=True,
+                         interpret=interp)
 
     def local_rotate(slot_pop, scales_pop, slot_push, scales_push,
                      fed, scale_new):
@@ -269,12 +261,12 @@ def ring_slot_rotate_int8_sharded(slot_pop, scales_pop, slot_push,
             scale_new, block_rows=blk, interpret=interp)
         return acc, slot_new, scales_new, residual
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local_rotate, mesh=mesh,
         in_specs=(slot_spec, scales_spec, slot_spec, scales_spec,
                   slot_spec, scales_spec),
         out_specs=(row_spec, slot_spec, scales_spec, slot_spec),
-        check_rep=False)
+        check_vma=False)
     return fn(slot_pop, scales_pop, slot_push, scales_push, fed,
               scale_new)
 

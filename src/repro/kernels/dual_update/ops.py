@@ -30,10 +30,6 @@ _LANES = 128
 _BLOCK_ROWS = 256
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
 def _flatten(tree):
     leaves, treedef = jax.tree.flatten(tree)
     sizes = [int(x.size) for x in leaves]
@@ -63,12 +59,12 @@ def dual_update_arena(z, g_sum, count, alpha, *, impl: str = "auto",
     w = -alpha z — one read/write pass over the donated (rows, 128)
     arena. impl dispatch as in kernels.delay_ring.ops ("auto" = Pallas
     on TPU, pure-XLA reference elsewhere). Returns (z_new, w)."""
-    from repro.kernels import resolve_impl
+    from repro.kernels import resolve_impl, resolve_interpret
     denom = jnp.maximum(count, 1e-12)
     impl = resolve_impl(impl)
     if impl == "ref":
         return dual_update_fused_ref(z, g_sum, denom, alpha)
-    interp = (not _on_tpu()) if interpret is None else interpret
+    interp = resolve_interpret(interpret)
     return dual_update_fused_fwd(z, g_sum, denom, jnp.float32(alpha),
                                  block_rows=block_rows, interpret=interp)
 
@@ -85,36 +81,33 @@ def dual_update_arena_sharded(z, g_sum, count, alpha, *, mesh_cfg,
     ``dist.sharding.arena_slot_specs``), so the wrapper needs NO
     cross-shard communication at all — count and alpha are replicated
     scalars. Returns (z_new, w) exactly like ``dual_update_arena``."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
-    from repro.dist.context import active_physical_mesh
+    from repro.dist.context import ambient_mesh
     from repro.dist.sharding import arena_slot_specs
-    from repro.kernels import dim_shard, fit_block_rows
+    from repro.kernels import dim_shard, fit_block_rows, resolve_interpret
 
-    mesh = active_physical_mesh()
+    mesh = ambient_mesh()
     if mesh is None:
         raise ValueError("dual_update_arena_sharded needs an ambient "
-                         "physical mesh (`with mesh:`)")
-    interp = (not _on_tpu()) if interpret is None else interpret
+                         "mesh (`with jax.set_mesh(mesh):`)")
+    interp = resolve_interpret(interpret)
     rows, _ = z.shape
     _, _, row_spec = arena_slot_specs(mesh_cfg, rows)
     rows_local = rows // dim_shard(row_spec[0] if len(row_spec) else None,
                                    mesh)
-    blk = fit_block_rows(rows_local, block_rows)
-    if not interp:
-        assert blk % 8 == 0, (rows_local, blk)
+    blk = fit_block_rows(rows_local, block_rows, interpret=interp)
     denom = jnp.maximum(count, 1e-12)
 
     def local_update(z, g, scal):
         return dual_update_fused_fwd(z, g, scal[0], scal[1],
                                      block_rows=blk, interpret=interp)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local_update, mesh=mesh,
         in_specs=(row_spec, row_spec, P()),
         out_specs=(row_spec, row_spec),
-        check_rep=False)
+        check_vma=False)
     scal = jnp.stack([jnp.float32(denom), jnp.float32(alpha)])
     return fn(z, g_sum, scal)
 
@@ -126,7 +119,8 @@ def dual_update(z_tree, g_tree, alpha, *, interpret: Optional[bool] = None
 
     Legacy pytree wrapper (per-call re-flatten); production runs on
     ``dual_update_arena``."""
-    interp = (not _on_tpu()) if interpret is None else interpret
+    from repro.kernels import resolve_interpret
+    interp = resolve_interpret(interpret)
     z_mat, meta = _flatten(z_tree)
     g_mat, _ = _flatten(g_tree)
     z_new, w_new = dual_update_fwd(z_mat, g_mat, jnp.float32(alpha),

@@ -12,12 +12,9 @@ from typing import Optional
 
 import jax
 
+from repro.kernels import resolve_interpret
 from repro.kernels.flash_attention.kernel import flash_attention_fwd
 from repro.kernels.flash_attention.ref import attention_ref
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -27,7 +24,7 @@ def flash_attention(q, k, v, *, causal: bool = True,
                     block_q: int = 128, block_k: int = 128,
                     interpret: Optional[bool] = None):
     """q: (B, Hq, Sq, D); k, v: (B, Hkv, Skv, D) -> (B, Hq, Sq, D)."""
-    interp = (not _on_tpu()) if interpret is None else interpret
+    interp = resolve_interpret(interpret)
     return flash_attention_fwd(q, k, v, causal=causal, window=window,
                                block_q=block_q, block_k=block_k,
                                interpret=interp)

@@ -12,18 +12,15 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import resolve_interpret
 from repro.kernels.linear_scan.kernel import linear_scan_fwd
 from repro.kernels.linear_scan.ref import linear_scan_ref
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
 def linear_scan(g, q, k, v, *, chunk: int = 128,
                 interpret: Optional[bool] = None):
-    interp = (not _on_tpu()) if interpret is None else interpret
+    interp = resolve_interpret(interpret)
     return linear_scan_fwd(g, q, k, v, chunk=chunk, interpret=interp)
 
 
@@ -41,7 +38,7 @@ def ssd_mamba2(x, dt, A, B, C, *, chunk: int = 128,
     gdec = (dt * A[None, None, :]).transpose(0, 2, 1).reshape(Bt * nh, S)
     q = C.transpose(0, 2, 1, 3).reshape(Bt * g_grp, S, ds)
     k = B.transpose(0, 2, 1, 3).reshape(Bt * g_grp, S, ds)
-    interp = (not _on_tpu()) if interpret is None else interpret
+    interp = resolve_interpret(interpret)
     y = linear_scan_fwd(gdec.astype(jnp.float32), q, k, v,
                         chunk=chunk, interpret=interp)
     return y.reshape(Bt, nh, S, hd).transpose(0, 2, 1, 3)

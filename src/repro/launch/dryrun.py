@@ -35,7 +35,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 import repro.configs as C
 from repro.configs.base import (AmbdgConfig, MeshConfig, RunConfig,
                                 ShapeConfig, SHAPES)
-from repro.dist import (batch_specs, retree_specs, shapes_and_axes,
+from repro.dist import (batch_specs, jit_train_step, shapes_and_axes,
                         state_specs, to_shardings)
 from repro.dist.sharding import spec_for
 # the collective census lives in launch.hlo (no import side effects)
@@ -103,26 +103,9 @@ def lower_train(rc: RunConfig, mesh):
         b_specs = dict(b_specs, b_sched=P())
     batch_in = shard_struct(b_specs, batch_shapes)
 
-    with mesh:
-        # the output TrainState's structure differs from the input's in
-        # static metadata (the arena's slot phase advances each step):
-        # transplant the specs onto the output structure for
-        # out_shardings (traced under the mesh: constrain() needs it).
-        # Metrics are per-strategy (kbatch adds staleness, decentralized
-        # consensus_error), so their spec tree comes from the same
-        # abstract eval instead of a hardcoded key set.
-        out_state_shapes, out_metrics_shapes = jax.eval_shape(
-            train_step, state_shapes, batch_shapes)
-        st_specs_out = retree_specs(st_specs, out_state_shapes)
-        metrics_spec = jax.tree.map(lambda _: P(), out_metrics_shapes)
-        jitted = jax.jit(
-            train_step,
-            in_shardings=(to_shardings(st_specs, mesh),
-                          to_shardings(b_specs, mesh)),
-            out_shardings=(to_shardings(st_specs_out, mesh),
-                           to_shardings(metrics_spec, mesh)),
-            donate_argnums=(0,),
-        )
+    jitted = jit_train_step(train_step, st_specs, b_specs, state_shapes,
+                            batch_shapes, mesh)
+    with jax.set_mesh(mesh):    # traced under the mesh: constrain()
         lowered = jitted.lower(state_in, batch_in)
     return lowered
 
@@ -176,7 +159,7 @@ def lower_serve(rc: RunConfig, mesh):
             return continuous_decode_step(model.decode_step, params,
                                           cache, tokens, pos, active)
 
-    with mesh:
+    with jax.set_mesh(mesh):
         jitted = jax.jit(
             serve_step,
             in_shardings=tuple(jax.tree.map(
@@ -224,7 +207,7 @@ def lower_publish_pop(rc: RunConfig, mesh):
         jax.ShapeDtypeStruct((rows,), jnp.bfloat16,
                              sharding=NamedSharding(mesh, s_spec)),
     )
-    with mesh:
+    with jax.set_mesh(mesh):
         jitted = jax.jit(
             pop,
             in_shardings=tuple(x.sharding for x in pop_in),
@@ -306,7 +289,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
                          gossip_compression=gossip_compression,
                          delay_process=delay_process, tau_max=tau_max,
                          batch_schedule=batch_schedule, mesh=mesh_cfg)
-    mesh = make_mesh(rc.mesh)
+    mesh = make_mesh(rc.mesh.shape, rc.mesh.axis_names)
     t0 = time.time()
     publish_pop = None
     if rc.shape.kind in ("train", "prefill"):
@@ -321,8 +304,6 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
         # half of the train-while-serve channel on this mesh
         pp = lower_publish_pop(rc, mesh).compile()
         pp_cost = pp.cost_analysis()
-        if isinstance(pp_cost, (list, tuple)):
-            pp_cost = pp_cost[0] if pp_cost else {}
         pp_text = pp.as_text()
         publish_pop = {
             "flops": float(pp_cost.get("flops", -1)),
@@ -338,8 +319,6 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
 
     mem = compiled.memory_analysis()
     cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):   # older jax: one dict per program
-        cost = cost[0] if cost else {}
     hlo_text = compiled.as_text()
     coll = collective_bytes(hlo_text)
     # which master delay-ring path this cell lowered with: v2 per-slot
@@ -348,7 +327,8 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
     from repro.core import arena as arena_mod
     from repro.dist.context import sharding_profile
     from repro.kernels import resolve_impl
-    with mesh, sharding_profile(rc.mesh if rc.mesh.n_devices > 1 else None):
+    with jax.set_mesh(mesh), \
+            sharding_profile(rc.mesh if rc.mesh.n_devices > 1 else None):
         ring_impl = resolve_impl("auto", pod_shard_map=True)
     result = {
         "arch": arch, "shape": shape_name,
@@ -369,10 +349,10 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
         "bytes_accessed": float(cost.get("bytes accessed", -1)),
         "collectives": coll,
         "memory": {
-            "argument_bytes": getattr(mem, "argument_size_in_bytes", None),
-            "output_bytes": getattr(mem, "output_size_in_bytes", None),
-            "temp_bytes": getattr(mem, "temp_size_in_bytes", None),
-            "peak_bytes": getattr(mem, "peak_memory_in_bytes", None),
+            "argument_bytes": mem.argument_size_in_bytes,
+            "output_bytes": mem.output_size_in_bytes,
+            "temp_bytes": mem.temp_size_in_bytes,
+            "peak_bytes": mem.peak_memory_in_bytes,
         },
         "lower_s": round(t_lower, 1), "compile_s": round(t_compile, 1),
     }
@@ -421,7 +401,7 @@ def lower_prefill(rc: RunConfig, mesh):
                 sh.shape, sh.dtype, sharding=NamedSharding(mesh, sp)),
             specs, shapes, is_leaf=lambda x: isinstance(x, P))
 
-    with mesh:
+    with jax.set_mesh(mesh):
         jitted = jax.jit(
             fwd,
             in_shardings=(to_shardings(p_specs, mesh),

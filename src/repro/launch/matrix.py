@@ -45,8 +45,6 @@ from typing import Dict, Optional
 
 import jax
 import jax.numpy as jnp
-import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 import repro.configs as C
@@ -59,7 +57,7 @@ from repro.launch import dryrun
 from repro.launch import wire_model
 from repro.launch.hlo import (collective_bytes, collective_bytes_by_dtype,
                               copy_bytes, copy_records, copy_shapes)
-from repro.launch.mesh import mesh_label, parse_mesh
+from repro.launch.mesh import make_mesh, mesh_label, parse_mesh
 from repro.models import build_model
 
 # Matrix smoke shapes: small enough that every big-config smoke
@@ -348,7 +346,7 @@ def check_ring_copies(cell: MatrixCell, rc: RunConfig, rows: int,
 # Invariants B + C: the cell's exchange program, census vs wire model
 # ---------------------------------------------------------------------------
 def _scoped_mesh(n: int, axis: str) -> Mesh:
-    return Mesh(np.asarray(jax.devices()[:n]), (axis,))
+    return make_mesh((n,), (axis,))
 
 
 def _lower_master_exchange(rows: int, n_pods: int, compression: str):
@@ -370,8 +368,8 @@ def _lower_master_exchange(rows: int, n_pods: int, compression: str):
             return jax.lax.psum(slot[0], "pod")
         args = (jax.ShapeDtypeStruct((n_pods, rows, 128), jnp.float32),)
         in_specs = (P("pod", None, None),)
-    fn = jax.jit(shard_map(local, mesh=mesh, in_specs=in_specs,
-                           out_specs=P(None, None), check_rep=False))
+    fn = jax.jit(jax.shard_map(local, mesh=mesh, in_specs=in_specs,
+                               out_specs=P(None, None), check_vma=False))
     return fn.lower(*args).compile()
 
 
@@ -383,9 +381,9 @@ def _lower_variable_exchange(rows: int, n_pods: int):
     def local(acc):          # block (1, rows, 128) f32: the local fold
         return jax.lax.psum(acc[0], "pod")
 
-    fn = jax.jit(shard_map(local, mesh=mesh,
-                           in_specs=(P("pod", None, None),),
-                           out_specs=P(None, None), check_rep=False))
+    fn = jax.jit(jax.shard_map(local, mesh=mesh,
+                               in_specs=(P("pod", None, None),),
+                               out_specs=P(None, None), check_vma=False))
     arg = jax.ShapeDtypeStruct((n_pods, rows, 128), jnp.float32)
     return fn.lower(arg).compile()
 
@@ -405,8 +403,8 @@ def _lower_gossip_exchange(topology: str, n_workers: int, rows: int,
         def local(x, res):
             return consensus.gossip_rounds_shard(
                 x, "worker", topology, n_workers, GOSSIP_ROUNDS), res
-    fn = jax.jit(shard_map(local, mesh=mesh, in_specs=(sp, sp),
-                           out_specs=(sp, sp), check_rep=False))
+    fn = jax.jit(jax.shard_map(local, mesh=mesh, in_specs=(sp, sp),
+                               out_specs=(sp, sp), check_vma=False))
     arg = jax.ShapeDtypeStruct((n_workers, rows, 128), jnp.float32)
     return fn.lower(arg, arg).compile()
 
@@ -428,9 +426,9 @@ def _lower_publish_exchange(rows: int, n_shards: int):
         scales = jax.lax.bitcast_convert_type(s_all, jnp.bfloat16)
         return dequantize_int8_rows(q_all, scales)
 
-    fn = jax.jit(shard_map(local, mesh=mesh,
-                           in_specs=(P("flat", None), P("flat")),
-                           out_specs=P(None, None), check_rep=False))
+    fn = jax.jit(jax.shard_map(local, mesh=mesh,
+                               in_specs=(P("flat", None), P("flat")),
+                               out_specs=P(None, None), check_vma=False))
     args = (jax.ShapeDtypeStruct((rows, 128), jnp.int8),
             jax.ShapeDtypeStruct((rows,), jnp.uint16))
     return fn.lower(*args).compile()

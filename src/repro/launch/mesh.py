@@ -1,34 +1,43 @@
-"""Mesh construction for the dry-run / matrix harness.
+"""Mesh construction: the one constructor every mesh of the repo goes
+through.
 
-The production reference shapes stay what they were:
+``make_mesh`` builds a ``jax.sharding.Mesh`` whose axes are all
+``AxisType.Auto``: the sharding rules here are written for GSPMD
+(``with_sharding_constraint`` on bare PartitionSpecs, shard_map over
+named axes), and ``with_sharding_constraint`` refuses Explicit axes —
+which is what ``jax.make_mesh`` builds by default.
+
+The production reference shapes:
 
 Single pod : (data=16, model=16)            = 256 chips (TPU v5e pod)
 Multi-pod  : (pod=2, data=16, model=16)     = 512 chips, 'pod' crosses DCN
 
-but mesh shape is a real harness axis now: ``parse_mesh`` turns a
-``"DxM"`` / ``"PxDxM"`` spec string into a ``MeshConfig`` (a leading
-pod factor > 1 adds the DCN-crossing ``pod`` axis), and ``mesh_label``
-is its inverse — the canonical cell label the dry-run and the matrix
-runner emit.
+Mesh shape is a harness axis too: ``parse_mesh`` turns a ``"DxM"`` /
+``"PxDxM"`` spec string into a ``MeshConfig`` (a leading pod factor
+> 1 adds the DCN-crossing ``pod`` axis), and ``mesh_label`` is its
+inverse — the canonical cell label the dry-run and the matrix runner
+emit.
 
-``make_production_mesh`` is a function (not a module constant) so
-importing this module never touches jax device state.
+Importing this module never touches jax device state.
 """
 from __future__ import annotations
 
+from typing import Optional, Sequence
+
 import jax
+from jax.sharding import AxisType
 
 from repro.configs.base import MeshConfig
 
 
-def make_production_mesh(*, multi_pod: bool = False):
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
-
-
-def make_mesh(cfg: MeshConfig):
-    return jax.make_mesh(cfg.shape, cfg.axis_names)
+def make_mesh(shape: Sequence[int], axis_names: Sequence[str], *,
+              devices: Optional[Sequence] = None) -> jax.sharding.Mesh:
+    """A mesh of ``shape`` over the first ``prod(shape)`` of ``devices``
+    (default: ``jax.devices()``), every axis Auto. For a ``MeshConfig``
+    pass ``cfg.shape, cfg.axis_names``."""
+    return jax.make_mesh(tuple(shape), tuple(axis_names),
+                         axis_types=(AxisType.Auto,) * len(shape),
+                         devices=devices)
 
 
 def mesh_config(multi_pod: bool = False) -> MeshConfig:

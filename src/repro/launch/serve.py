@@ -16,6 +16,7 @@ import argparse
 
 import repro.configs as C
 from repro.configs.base import ServeConfig
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import build_model
 from repro.serve import Engine, RequestQueue, WeightPublisher
 
@@ -37,6 +38,7 @@ def main():
     ap.add_argument("--staleness-bound", type=int, default=4)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = (C.get_smoke_config(args.arch) if args.smoke
            else C.get_config(args.arch))

@@ -11,8 +11,7 @@ from __future__ import annotations
 import argparse
 import dataclasses
 import json
-
-import jax
+from typing import Tuple
 
 import repro.configs as C
 from repro.api import available_strategies
@@ -23,11 +22,13 @@ from repro.configs.base import (AmbdgConfig, BatchScheduleConfig,
 from repro.core.batch_schedule import BATCH_SCHEDULES
 from repro.core.delay_process import DELAY_PROCESSES
 from repro.core.worker_process import WORKER_PROCESSES
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import build_model
+from repro.models.api import Model
 from repro.train.loop import LoopConfig, train
 
 
-def main():
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--shape", default="train_4k")
@@ -101,8 +102,12 @@ def main():
     ap.add_argument("--ckpt-dir", default=None)
     ap.add_argument("--n-workers", type=int, default=4)
     ap.add_argument("--samples-per-worker", type=int, default=4)
-    args = ap.parse_args()
+    return ap
 
+
+def build_run(args: argparse.Namespace) -> Tuple[Model, RunConfig,
+                                                 LoopConfig]:
+    """The model, run config and loop config the parsed flags name."""
     model_cfg = (C.get_smoke_config(args.arch) if args.smoke
                  else C.get_config(args.arch))
     shape = SHAPES[args.shape]
@@ -143,10 +148,16 @@ def main():
             b_cap=args.batch_cap, growth_rate=args.batch_growth,
             seed=args.batch_schedule_seed),
         optimizer=args.optimizer)
-    model = build_model(model_cfg)
     loop = LoopConfig(n_steps=args.steps, ckpt_dir=args.ckpt_dir,
                       n_workers=args.n_workers,
                       samples_per_worker=args.samples_per_worker)
+    return build_model(model_cfg), rc, loop
+
+
+def main():
+    args = build_parser().parse_args()
+    enable_compile_cache()
+    model, rc, loop = build_run(args)
     out = train(model, rc, loop, log_fn=lambda m: print(json.dumps(m)))
     print(f"done: {len(out['history'])} log points, "
           f"final loss {out['history'][-1]['loss']:.4f}")

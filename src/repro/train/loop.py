@@ -6,8 +6,9 @@ strategy (``rc.strategy`` -> ``repro.api.build``). Each iteration:
   1. the data pipeline draws per-worker anytime counts b_i(t) (real
      timer on hardware; shifted-exponential model in CI) and emits the
      masked global batch;
-  2. the health tracker zeroes contributions of failed workers
-     (the aggregation stays exact — paper Sec. IV-C);
+  2. under an elastic worker process, the health tracker zeroes
+     contributions of dead workers (the aggregation stays exact —
+     paper Sec. IV-C);
   3. the strategy's jitted step runs (e.g. AMB-DG: anytime accumulate
      -> delayed pod exchange -> dual-averaging update; decentralized:
      anytime accumulate -> r gossip rounds -> per-worker prox);
@@ -117,12 +118,13 @@ def train(model: Model, rc: RunConfig, loop: LoopConfig,
     state = init_state(jax.random.PRNGKey(rc.seed))
     start_step = 0
     # heartbeats are driven by the elastic process on a virtual epoch
-    # clock (at=step; a missed epoch is a missed heartbeat), or by
-    # real wall time when no process runs
+    # clock (at=step; a missed epoch is a missed heartbeat). The static
+    # fleet has no liveness source, so it keeps no tracker: a
+    # wall-clock one with no heartbeats would call every worker failed
+    # once a run (its first compile included) outlasts the timeout
     health = (WorkerHealth(loop.n_workers, heartbeat_timeout=0.5,
                            eviction_misses=loop.eviction_misses, t0=0.0)
-              if elastic_proc is not None
-              else WorkerHealth(loop.n_workers))
+              if elastic_proc is not None else None)
     if loop.ckpt_dir and ckpt.latest_step(loop.ckpt_dir) is not None:
         state, extra = ckpt.restore(loop.ckpt_dir, state)
         pipeline.load_state_dict(extra["pipeline"])
@@ -205,13 +207,6 @@ def train(model: Model, rc: RunConfig, loop: LoopConfig,
                 remesh_events.append({"step": step, "event": "evict",
                                       "workers": newly_evicted,
                                       "plan": remesh_plan})
-        else:
-            # fault masking: failed workers contribute b_i = 0
-            failed = health.tick()
-            if failed:
-                w = batch["weights"].reshape(loop.n_workers, -1)
-                w[failed, :] = 0.0
-                batch["weights"] = w.reshape(-1)
         if delay_proc is not None:
             batch["delay"] = np.int32(delay_proc.next())
         if b_target is not None:
@@ -240,7 +235,7 @@ def train(model: Model, rc: RunConfig, loop: LoopConfig,
         if loop.ckpt_dir and ((step + 1) % loop.ckpt_every == 0
                               or remesh_plan is not None):
             save_ckpt(step + 1, plan=remesh_plan)
-    return {"state": state, "history": history,
+    return {"state": state, "history": history, "step_fn": step_fn,
             "b_history": pipeline.b_history,
             "remesh_events": remesh_events,
             "publisher": publisher}

@@ -398,9 +398,10 @@ _VARIABLE_DELAY_SCRIPT = textwrap.dedent("""
     from repro.configs.base import MeshConfig
     from repro.core import arena
     from repro.dist.context import sharding_profile
+    from repro.launch.mesh import make_mesh
 
     mesh_cfg = MeshConfig(n_pods=2, data=2, model=2)
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
     params = {"a": jnp.zeros((7,)), "b": jnp.zeros((300, 5)),
               "c": jnp.zeros((257,))}
     layout = arena.make_layout(params)
@@ -418,7 +419,7 @@ _VARIABLE_DELAY_SCRIPT = textwrap.dedent("""
         counts = jnp.full((n_pods,), 4.0)
         # both paths under the multi-pod GSPMD profile: the static
         # schedule vs the delay-tolerant masked fold fed tau_t = tau
-        with mesh, sharding_profile(mesh_cfg):
+        with jax.set_mesh(mesh), sharding_profile(mesh_cfg):
             gs_s, c_s, ar_s = arena.push_pop(
                 layout, ar_s, g, counts, "int8", impl="ref")
             gs_v, c_v, tau_obs, ar_v = arena.push_pop_variable(
@@ -579,9 +580,10 @@ _SHARD_MAP_SCRIPT = textwrap.dedent("""
     from repro.configs.base import MeshConfig
     from repro.core import arena
     from repro.dist.context import sharding_profile
+    from repro.launch.mesh import make_mesh
 
     mesh_cfg = MeshConfig(n_pods=2, data=2, model=2)
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
     params = {"a": jnp.zeros((7,)), "b": jnp.zeros((300, 5)),
               "c": jnp.zeros((257,))}
     layout = arena.make_layout(params)
@@ -598,7 +600,7 @@ _SHARD_MAP_SCRIPT = textwrap.dedent("""
         g = grads_at(t)
         counts = jnp.full((n_pods,), 4.0)
         # shard_map'd Pallas kernel (interpret) on the multi-pod mesh
-        with mesh, sharding_profile(mesh_cfg):
+        with jax.set_mesh(mesh), sharding_profile(mesh_cfg):
             gs_s, c_s, ar_s = arena.push_pop(
                 layout, ar_s, g, counts, "int8",
                 impl="pallas_sharded", interpret=True)

@@ -369,12 +369,13 @@ _SHARDED_VARPOP_SCRIPT = textwrap.dedent("""
     import numpy as np
     from repro.configs.base import MeshConfig
     from repro.dist.context import sharding_profile
+    from repro.launch.mesh import make_mesh
     from repro.kernels.delay_ring.ops import (ring_variable_pop,
                                               ring_variable_pop_ref,
                                               ring_variable_pop_sharded)
 
     mesh_cfg = MeshConfig(n_pods=2, data=2, model=2)
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
     n_slots, n_pods, rows = 5, 2, 256
     rng = np.random.default_rng(7)
 
@@ -390,7 +391,7 @@ _SHARDED_VARPOP_SCRIPT = textwrap.dedent("""
             scales = None
         for trial in range(6):
             mask = jnp.asarray(rng.integers(0, 2, size=(n_slots,)) > 0)
-            with mesh, sharding_profile(mesh_cfg):
+            with jax.set_mesh(mesh), sharding_profile(mesh_cfg):
                 got = ring_variable_pop_sharded(
                     ring, mask, scales=scales, mesh_cfg=mesh_cfg,
                     interpret=True)

@@ -194,3 +194,26 @@ def test_mlstm_chunked_matches_recurrence():
         ys[:, t] = num / den[..., None]
     np.testing.assert_allclose(np.asarray(y_chunk), ys, atol=2e-4,
                                rtol=1e-3)
+
+
+def test_resolve_impl_never_hides_the_device(monkeypatch):
+    """On the TPU a multi-pod profile resolves to the shard_map'd
+    kernels, which need an ambient mesh: without one, a caller that has
+    the wrapper gets an error, not the XLA reference path."""
+    import repro.kernels as K
+    from repro.configs.base import MeshConfig
+    from repro.dist.context import sharding_profile
+    from repro.launch.mesh import make_mesh
+
+    assert K.resolve_impl("auto", pod_shard_map=True) == "ref"   # CPU
+    monkeypatch.setattr(K, "on_tpu", lambda: True)
+    assert K.resolve_impl("auto", pod_shard_map=True) == "pallas"
+    with sharding_profile(MeshConfig(n_pods=2, data=1, model=1)):
+        assert K.resolve_impl("auto") == "ref"      # no wrapper
+        with pytest.raises(ValueError, match="ambient mesh"):
+            K.resolve_impl("auto", pod_shard_map=True)
+        with jax.set_mesh(make_mesh((1,), ("pod",))):
+            assert (K.resolve_impl("auto", pod_shard_map=True)
+                    == "pallas_sharded")
+    assert not K.resolve_interpret(None)
+    assert K.resolve_interpret(True)

@@ -151,6 +151,27 @@ def test_amb_is_tau0_ambdg_bitwise(model):
     assert amb.staleness_schedule().tau == 0
 
 
+def test_multi_pod_ambdg_step_needs_a_mesh(model):
+    """A multi-pod ambdg step traced with no ambient mesh is an error,
+    never a silent run of every pod on one device; the one-device run
+    is the caller's explicit act, ``sharding_profile(None)``."""
+    from repro.dist.context import sharding_profile
+    rc = make_rc("ambdg").replace(mesh=MeshConfig(n_pods=2, data=1,
+                                                  model=1))
+    s = api.build(model, rc)
+    state = s.init_state(jax.random.PRNGKey(0))
+    b = batches(1)[0]
+    with pytest.raises(ValueError, match="ambient mesh"):
+        jax.jit(s.train_step)(state, b)
+    with sharding_profile(rc.mesh), \
+            pytest.raises(ValueError, match="ambient mesh"):
+        jax.jit(s.train_step)(state, b)
+    with sharding_profile(None):
+        out, m = jax.jit(s.train_step)(state, b)
+    assert np.isfinite(float(m["loss"]))
+    assert out.arena.ring[0].shape[0] == 2
+
+
 @pytest.mark.parametrize("compression", ["none", "int8"])
 def test_fixed_delay_process_is_static_path_bitwise(model, compression):
     """rc.delay defaults to the 'fixed' process, which must BE the
@@ -516,13 +537,14 @@ def _run_decentralized_oracle_checks():
     from repro.dist.context import sharding_profile
     from repro.kernels.dual_update.ops import (dual_update_arena,
                                                dual_update_arena_sharded)
+    from repro.launch.mesh import make_mesh
     mesh_cfg = MeshConfig(n_pods=2, data=2, model=2)
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
     rows = 512
     z = jax.random.normal(jax.random.PRNGKey(0), (rows, 128))
     g = jax.random.normal(jax.random.PRNGKey(1), (rows, 128))
     count, a = jnp.float32(17.0), jnp.float32(0.03)
-    with mesh, sharding_profile(mesh_cfg):
+    with jax.set_mesh(mesh), sharding_profile(mesh_cfg):
         zs, ws = jax.jit(lambda z, g: dual_update_arena_sharded(
             z, g, count, a, mesh_cfg=mesh_cfg, interpret=True))(z, g)
     zu, wu = jax.jit(lambda z, g: dual_update_arena(
