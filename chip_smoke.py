@@ -33,9 +33,8 @@ vocabulary cut:
       every step's consensus state must equal the dense gossip fold
       applied to the step's own messages, bit for bit.
 
-Every number printed before the last line is informal: wall-clock
-figures are host-clock readings of one run, not metrics. The last line
-is one JSON object, ``{"ok": true, "device": {...}}``; any failed check
+It measures nothing: compile time, step rate and memory are the
+benchmark's (``bench/run.py``). The last line is one JSON object, ``{"ok": true, "device": {...}}``; any failed check
 exits non-zero without it. Exits non-zero, printing no result, when
 JAX finds no TPU. Runs in one process: it starts no child.
 """
@@ -49,7 +48,6 @@ import math
 import os
 import re
 import sys
-import time
 
 import jax
 import jax.numpy as jnp
@@ -124,20 +122,6 @@ def tree_rel_max_err(got, want) -> float:
     return max(errs) if errs else 0.0
 
 
-class CompileTimer:
-    """Sums XLA backend compile time reported by JAX's monitoring."""
-    EVENT = "/jax/core/compile/backend_compile_duration"
-
-    def __init__(self):
-        self.seconds, self.count = 0.0, 0
-        jax.monitoring.register_event_duration_secs_listener(self._on)
-
-    def _on(self, event, duration, **_):
-        if event == self.EVENT:
-            self.seconds += duration
-            self.count += 1
-
-
 # ---------------------------------------------------------------------------
 # One chip: the training launcher's path at full width
 # ---------------------------------------------------------------------------
@@ -162,24 +146,11 @@ def run_one_chip(checks: Checks) -> None:
         f"microbatches, tau={tau}, pod_compression="
         f"{rc.ambdg.pod_compression}, delay={rc.delay.process}")
 
-    timer = CompileTimer()
     history = []
     out = train(model, rc, loop, log_fn=history.append)
-    log(f"compile: {timer.count} XLA compiles, {timer.seconds:.1f} s "
-        "(informal)")
-    prev = 0.0
     for i, m in enumerate(history):
         log(f"step {i + 1}: loss={m['loss']!r} "
-            f"applied_count={m['applied_count']!r} "
-            f"wall={m['wall_s'] - prev:.3f}s (informal, host clock)")
-        prev = m["wall_s"]
-    timed = sorted(b["wall_s"] - a["wall_s"] for a, b in
-                   zip(history[WARMUP - 1:], history[WARMUP:]))
-    if timed:
-        log(f"timed steps: {len(timed)}, median "
-            f"{timed[len(timed) // 2]:.3f} s/step, "
-            f"{N_WORKERS * SAMPLES_PER_WORKER * SEQ_LEN} "
-            "tokens/step (informal, host clock)")
+            f"applied_count={m['applied_count']!r}")
 
     checks.check("steps_logged", len(history) == n_steps,
                  f"{len(history)} of {n_steps}")
@@ -327,7 +298,6 @@ def run_pod_exchange(cfg, devices, checks: Checks) -> None:
         batch = jax.device_put(b, dist.to_shardings(b_specs, mesh))
         structure = jax.tree.structure(state)
         if structure not in compiled:
-            t0 = time.perf_counter()
             jitted = dist.jit_train_step(strategy.train_step, st_specs,
                                          b_specs, state, batch, mesh)
             with jax.set_mesh(mesh):
@@ -335,8 +305,7 @@ def run_pod_exchange(cfg, devices, checks: Checks) -> None:
             text = compiled[structure].as_text()
             phase = state.arena.phase
             gathers = len(re.findall(r"all-gather(?:-start)?\(", text))
-            log(f"(a) phase {phase}: compiled in "
-                f"{time.perf_counter() - t0:.1f} s (informal), "
+            log(f"(a) phase {phase}: "
                 f"{text.count('tpu_custom_call')} tpu_custom_call, "
                 f"{gathers} all-gather ops")
             checks.check(f"a_kernels_and_collective_phase{phase}",

@@ -59,7 +59,14 @@ def _loss_with_remat(model: Model, rc: RunConfig):
     # Remat lives at the scanned-block level (ModelConfig.block_remat);
     # a whole-loss checkpoint would still store per-layer scan residuals
     # during the recompute, so rc.remat is only kept for ablations.
-    loss = lambda p, b: model.loss(p, b)
+    # The scope is inside what value_and_grad differentiates, so a
+    # profile tells the passes apart by JAX's own op_name markers:
+    # forward under jvp(worker.forward), backward under
+    # transpose(jvp(worker.forward)), recomputation under
+    # rematted_computation.
+    def loss(p, b):
+        with jax.named_scope("worker.forward"):
+            return model.loss(p, b)
     if rc.remat == "whole_loss":
         loss = jax.checkpoint(loss)
     return loss
@@ -82,12 +89,15 @@ def arena_master_update(layout, opt, params, opt_state, arena_state,
         grad_sum, count, arena_state = arena_mod.push_pop(
             layout, arena_state, pod_grads, pod_counts, compression)
     else:  # tau = 0: synchronous exchange, then one flat scatter
-        summed = jax.tree.map(delayed.pod_sum, pod_grads)
-        grad_sum = arena_mod.flatten_tree(layout, summed)
+        with jax.named_scope("master.exchange"):
+            summed = jax.tree.map(delayed.pod_sum, pod_grads)
+        with jax.named_scope("master.stage"):
+            grad_sum = arena_mod.flatten_tree(layout, summed)
         count = jnp.sum(pod_counts)
     grad_sum = constrain(grad_sum, ("flat", None))
-    params, opt_state = opt.update(opt_state, params, grad_sum, count,
-                                   b_sched=b_sched)
+    with jax.named_scope("master.update"):
+        params, opt_state = opt.update(opt_state, params, grad_sum, count,
+                                       b_sched=b_sched)
     return params, opt_state, arena_state, grad_sum, count
 
 
@@ -274,14 +284,16 @@ def build_step_fns(model: Model, rc: RunConfig):
             # adaptive: observed staleness of THIS update; otherwise
             # the static worst case is the ring cap tau_max (ring_tau)
             # — NOT the nominal cfg.tau a stochastic process exceeds
-            params, opt_state = opt.update(
-                state.opt_state, state.params, grad_sum_flat, count,
-                tau_obs=(tau_obs if rc.delay.adaptive_alpha
-                         else float(ring_tau)),
-                b_sched=b_sched)
+            with jax.named_scope("master.update"):
+                params, opt_state = opt.update(
+                    state.opt_state, state.params, grad_sum_flat, count,
+                    tau_obs=(tau_obs if rc.delay.adaptive_alpha
+                             else float(ring_tau)),
+                    b_sched=b_sched)
             buffer = None
-            g_norm = (jnp.sqrt(jnp.sum(jnp.square(grad_sum_flat)))
-                      / jnp.maximum(count, 1e-12))
+            with jax.named_scope("master.norm"):
+                g_norm = (jnp.sqrt(jnp.sum(jnp.square(grad_sum_flat)))
+                          / jnp.maximum(count, 1e-12))
         elif use_arena:
             params, opt_state, arena_state, grad_sum_flat, count = \
                 arena_master_update(layout, opt, state.params,
@@ -291,8 +303,9 @@ def build_step_fns(model: Model, rc: RunConfig):
             buffer = None
             # scalar divide after the reduce: same value as norm(g/c),
             # without a params-sized elementwise divide for a metric
-            g_norm = (jnp.sqrt(jnp.sum(jnp.square(grad_sum_flat)))
-                      / jnp.maximum(count, 1e-12))
+            with jax.named_scope("master.norm"):
+                g_norm = (jnp.sqrt(jnp.sum(jnp.square(grad_sum_flat)))
+                          / jnp.maximum(count, 1e-12))
         else:
             arena_state = None
             if state.buffer is not None:

@@ -51,7 +51,8 @@ def accumulate_scan(loss_fn: LossFn, params, batch: Dict, n_mb: int):
     grad_fn = jax.value_and_grad(loss_fn, has_aux=True)
     if n_mb == 1:  # no scan: keeps roofline measurement loop-free
         (loss_sum, aux), g = grad_fn(params, batch)
-        g = jax.tree.map(lambda x: x.astype(jnp.float32), g)
+        with jax.named_scope("worker.accumulate"):
+            g = jax.tree.map(lambda x: x.astype(jnp.float32), g)
         return g, aux["count"], {"loss_sum": aux["loss_sum"]}
     mbs = _split_batch(batch, n_mb)
 
@@ -61,7 +62,9 @@ def accumulate_scan(loss_fn: LossFn, params, batch: Dict, n_mb: int):
     def body(carry, mb):
         gsum, csum, lsum = carry
         (loss_sum, aux), g = grad_fn(params, mb)
-        gsum = jax.tree.map(lambda a, b: a + b.astype(jnp.float32), gsum, g)
+        with jax.named_scope("worker.accumulate"):
+            gsum = jax.tree.map(lambda a, b: a + b.astype(jnp.float32),
+                                gsum, g)
         return (gsum, csum + aux["count"], lsum + aux["loss_sum"]), None
 
     (gsum, count, loss_sum), _ = jax.lax.scan(body, carry0, mbs)
@@ -87,7 +90,9 @@ def accumulate_while(loss_fn: LossFn, params, batch: Dict, n_mb: int,
         i, gsum, csum, lsum = state
         mb = jax.tree.map(lambda x: x[i], mbs)
         (loss_sum, aux), g = grad_fn(params, mb)
-        gsum = jax.tree.map(lambda a, b: a + b.astype(jnp.float32), gsum, g)
+        with jax.named_scope("worker.accumulate"):
+            gsum = jax.tree.map(lambda a, b: a + b.astype(jnp.float32),
+                                gsum, g)
         return (i + 1, gsum, csum + aux["count"], lsum + aux["loss_sum"])
 
     _, gsum, count, loss_sum = jax.lax.while_loop(

@@ -514,7 +514,8 @@ def _pop_sum(ring, head, scales=None):
             s = constrain(s, (None, "flat"))
             popped = jax.lax.optimization_barrier(
                 popped.astype(jnp.float32) * s[..., None])
-        return jnp.sum(popped, axis=0)
+        with jax.named_scope("master.exchange"):
+            return jnp.sum(popped, axis=0)
 
     acc = None
     for p in range(n_pods):
@@ -554,7 +555,8 @@ def _slot_pop_sum(slot, scales_slot=None):
             s = constrain(scales_slot, (None, "flat"))
             slot = jax.lax.optimization_barrier(
                 q.astype(jnp.float32) * s[..., None])
-        return jnp.sum(slot, axis=0)
+        with jax.named_scope("master.exchange"):
+            return jnp.sum(slot, axis=0)
 
     acc = None
     for p in range(n_pods):
@@ -623,36 +625,41 @@ def _push_pop_v2(layout: ArenaLayout, arena: GradArena, pod_grads,
             # flatten into staging, form fed once: the scale pass needs
             # it, and the kernel consumes it directly (writing the new
             # residual into its buffer)
-            g_flat = flatten_tree(layout, pod_grads, leading=1,
-                                  out=arena.staging)
-            fed = g_flat + arena.residual
+            with jax.named_scope("master.stage"):
+                g_flat = flatten_tree(layout, pod_grads, leading=1,
+                                      out=arena.staging)
+                fed = g_flat + arena.residual
+                scale_new = row_scales(layout, fed)
             # buffer swap: the old residual becomes next step's scratch
             staging = arena.residual
-            scale_new = row_scales(layout, fed)
             if impl == "pallas_sharded":
                 from repro.dist.context import active_mesh
                 from repro.kernels.delay_ring.ops import \
                     ring_slot_rotate_int8_sharded
-                grad_sum, slot_new, sc_new, residual = \
-                    ring_slot_rotate_int8_sharded(
-                        arena.ring[pop_i], arena.scales[pop_i],
-                        arena.ring[push_i], arena.scales[push_i],
-                        fed, scale_new, mesh_cfg=active_mesh(),
-                        interpret=interpret)
+                with jax.named_scope("master.ring"):
+                    grad_sum, slot_new, sc_new, residual = \
+                        ring_slot_rotate_int8_sharded(
+                            arena.ring[pop_i], arena.scales[pop_i],
+                            arena.ring[push_i], arena.scales[push_i],
+                            fed, scale_new, mesh_cfg=active_mesh(),
+                            interpret=interpret)
             else:
                 from repro.kernels.delay_ring.ops import \
                     ring_slot_rotate_int8
-                popped, slot_new, sc_new, residual = \
-                    ring_slot_rotate_int8(
-                        arena.ring[pop_i], arena.scales[pop_i],
-                        arena.ring[push_i], arena.scales[push_i],
-                        fed, scale_new, interpret=interpret)
+                with jax.named_scope("master.ring"):
+                    popped, slot_new, sc_new, residual = \
+                        ring_slot_rotate_int8(
+                            arena.ring[pop_i], arena.scales[pop_i],
+                            arena.ring[push_i], arena.scales[push_i],
+                            fed, scale_new, interpret=interpret)
                 grad_sum = _pod_fold(popped)  # pod sum = DCN all-reduce
         else:
-            grad_sum = _slot_pop_sum(arena.ring[pop_i],
-                                     arena.scales[pop_i])
-            slot_new, sc_new, residual, staging = _int8_slot_push(
-                layout, arena, push_i, pod_grads)
+            with jax.named_scope("master.ring"):
+                grad_sum = _slot_pop_sum(arena.ring[pop_i],
+                                         arena.scales[pop_i])
+            with jax.named_scope("master.stage"):
+                slot_new, sc_new, residual, staging = _int8_slot_push(
+                    layout, arena, push_i, pod_grads)
         ring = _replace_slot(arena.ring, push_i, slot_new)
         scales = _replace_slot(arena.scales, push_i, sc_new)
     else:
@@ -660,9 +667,11 @@ def _push_pop_v2(layout: ArenaLayout, arena: GradArena, pod_grads,
         # slot, the push scatters the per-leaf strips straight into the
         # (donated) spare slot's buffer — under v2 the f32 ring
         # rotation IS just those two XLA ops, on every backend.
-        grad_sum = _slot_pop_sum(arena.ring[pop_i])
-        slot_new = flatten_tree(layout, pod_grads, leading=1,
-                                out=arena.ring[push_i])
+        with jax.named_scope("master.ring"):
+            grad_sum = _slot_pop_sum(arena.ring[pop_i])
+        with jax.named_scope("master.stage"):
+            slot_new = flatten_tree(layout, pod_grads, leading=1,
+                                    out=arena.ring[push_i])
         ring = _replace_slot(arena.ring, push_i, slot_new)
         scales, residual = None, None
         staging = arena.staging    # untouched pass-through (zero cost)
@@ -681,7 +690,8 @@ def _pod_fold(popped):
     """Deterministic left fold over the pod axis of an already-
     dequantized popped slot (the kernel path's pod reduction)."""
     from repro.core.delayed import pod_sum
-    return pod_sum(popped)
+    with jax.named_scope("master.exchange"):
+        return pod_sum(popped)
 
 
 def push_pop(layout: ArenaLayout, arena: GradArena, pod_grads, pod_counts,
@@ -726,42 +736,54 @@ def push_pop(layout: ArenaLayout, arena: GradArena, pod_grads, pod_counts,
     old_count = arena.counts[head]
 
     if impl == "pallas":
-        g_flat = flatten_tree(layout, pod_grads, leading=1,
-                              out=arena.staging)
+        with jax.named_scope("master.stage"):
+            g_flat = flatten_tree(layout, pod_grads, leading=1,
+                                  out=arena.staging)
         if compression == "int8":
-            fed = g_flat + arena.residual
-            scale_new = row_scales(layout, fed)
-            popped, ring, scales, residual = ring_push_pop(
-                arena.ring, fed, head, scales=arena.scales,
-                scale_new=scale_new, impl="pallas", interpret=interpret)
+            with jax.named_scope("master.stage"):
+                fed = g_flat + arena.residual
+                scale_new = row_scales(layout, fed)
+            with jax.named_scope("master.ring"):
+                popped, ring, scales, residual = ring_push_pop(
+                    arena.ring, fed, head, scales=arena.scales,
+                    scale_new=scale_new, impl="pallas",
+                    interpret=interpret)
             staging = arena.residual
         else:
-            popped, ring, scales, residual = ring_push_pop(
-                arena.ring, g_flat, head, impl="pallas",
-                interpret=interpret)
+            with jax.named_scope("master.ring"):
+                popped, ring, scales, residual = ring_push_pop(
+                    arena.ring, g_flat, head, impl="pallas",
+                    interpret=interpret)
             # "none" carries no staging (g_flat was a fresh temp) —
             # keep the state structure identical to init_arena's
             staging = arena.staging
         grad_sum = _pod_fold(popped)        # pod sum = DCN all-reduce
     elif compression == "int8":
-        fed = scatter_fed(layout, pod_grads, arena.residual,
-                          out=arena.staging)
-        scale_new = row_scales(layout, fed)
-        grad_sum = _pop_sum(arena.ring, head, arena.scales)
-        s = scale_new[..., None]
-        q = jnp.clip(jnp.round(fed / s), -127, 127)
+        with jax.named_scope("master.stage"):
+            fed = scatter_fed(layout, pod_grads, arena.residual,
+                              out=arena.staging)
+            scale_new = row_scales(layout, fed)
+        with jax.named_scope("master.ring"):
+            grad_sum = _pop_sum(arena.ring, head, arena.scales)
+        with jax.named_scope("master.stage"):
+            s = scale_new[..., None]
+            q = jnp.clip(jnp.round(fed / s), -127, 127)
         # XLA sequences the slot read above ahead of this in-place
         # overwrite itself (copy-protection where it must)
-        ring, scales = _update_slot_int8(arena.ring, arena.scales,
-                                         q.astype(jnp.int8), scale_new,
-                                         head)
+        with jax.named_scope("master.ring"):
+            ring, scales = _update_slot_int8(arena.ring, arena.scales,
+                                             q.astype(jnp.int8),
+                                             scale_new, head)
         # barrier mirrors delayed._dequantize: no FMA contraction, so
         # the residual stays bit-identical to the pytree reference
-        residual = fed - jax.lax.optimization_barrier(q * s)
+        with jax.named_scope("master.stage"):
+            residual = fed - jax.lax.optimization_barrier(q * s)
         staging = fed
     else:
-        grad_sum = _pop_sum(arena.ring, head)
-        ring = _scatter_slot(layout, arena.ring, pod_grads, head)
+        with jax.named_scope("master.ring"):
+            grad_sum = _pop_sum(arena.ring, head)
+        with jax.named_scope("master.stage"):
+            ring = _scatter_slot(layout, arena.ring, pod_grads, head)
         staging = arena.staging    # untouched pass-through (zero cost)
         scales = residual = None
 
@@ -818,7 +840,8 @@ def _variable_pop_ref(ring, scales, mask):
                 x.astype(jnp.float32) * s[..., None])
         m = mask.astype(jnp.float32)[:, None, None, None]
         local = jnp.sum(m * x, axis=0)       # per-pod masked fold, local
-        return jnp.sum(local, axis=0)        # ONE pod-axis DCN reduce
+        with jax.named_scope("master.exchange"):
+            return jnp.sum(local, axis=0)    # ONE pod-axis DCN reduce
 
     def slot_pod_sum(j):
         q = jax.lax.dynamic_index_in_dim(ring, j, 0, keepdims=False)
@@ -926,12 +949,14 @@ def push_pop_variable(layout: ArenaLayout, arena: GradArena, pod_grads,
         # helper): per-push quantization + EF residual, byte-for-byte;
         # the slot index k is STATIC, so the stacked update-slices
         # write in place on the donated ring
-        q, scale_new, residual, staging = _int8_quantize(
-            layout, arena, pod_grads)
-        ring = jax.lax.dynamic_update_slice(
-            arena.ring, q.astype(jnp.int8)[None], (k, 0, 0, 0))
-        scales = jax.lax.dynamic_update_slice(
-            arena.scales, scale_new[None], (k, 0, 0))
+        with jax.named_scope("master.stage"):
+            q, scale_new, residual, staging = _int8_quantize(
+                layout, arena, pod_grads)
+        with jax.named_scope("master.ring"):
+            ring = jax.lax.dynamic_update_slice(
+                arena.ring, q.astype(jnp.int8)[None], (k, 0, 0, 0))
+            scales = jax.lax.dynamic_update_slice(
+                arena.scales, scale_new[None], (k, 0, 0))
     else:
         from repro.dist.context import active_mesh
         mesh = active_mesh()
@@ -945,11 +970,15 @@ def push_pop_variable(layout: ArenaLayout, arena: GradArena, pod_grads,
             # determined) and land it with ONE row-aligned update,
             # exactly like the int8 branch above; the per-leaf traffic
             # stays on the temp.
-            fed = flatten_tree(layout, pod_grads, leading=1)
-            ring = jax.lax.dynamic_update_slice(
-                arena.ring, fed[None], (k, 0, 0, 0))
+            with jax.named_scope("master.stage"):
+                fed = flatten_tree(layout, pod_grads, leading=1)
+            with jax.named_scope("master.ring"):
+                ring = jax.lax.dynamic_update_slice(
+                    arena.ring, fed[None], (k, 0, 0, 0))
         else:
-            ring = _scatter_slot_stacked(layout, arena.ring, pod_grads, k)
+            with jax.named_scope("master.stage"):
+                ring = _scatter_slot_stacked(layout, arena.ring,
+                                             pod_grads, k)
         staging = arena.staging       # untouched pass-through (zero cost)
         scales, residual = None, None
 
@@ -971,19 +1000,22 @@ def push_pop_variable(layout: ArenaLayout, arena: GradArena, pod_grads,
     if impl == "pallas_sharded":
         from repro.dist.context import active_mesh
         from repro.kernels.delay_ring.ops import ring_variable_pop_sharded
-        grad_sum, meta = ring_variable_pop_sharded(
-            ring, mask, scales=scales, counts_stale=cs,
-            mesh_cfg=active_mesh(), interpret=interpret)
+        with jax.named_scope("master.ring"):
+            grad_sum, meta = ring_variable_pop_sharded(
+                ring, mask, scales=scales, counts_stale=cs,
+                mesh_cfg=active_mesh(), interpret=interpret)
         count, stale_sum = meta[0], meta[1]
     elif impl == "pallas":
         from repro.kernels.delay_ring.ops import ring_variable_pop
-        partial, meta = ring_variable_pop(
-            ring, mask, scales=scales, counts_stale=cs, impl="pallas",
-            interpret=interpret)
+        with jax.named_scope("master.ring"):
+            partial, meta = ring_variable_pop(
+                ring, mask, scales=scales, counts_stale=cs,
+                impl="pallas", interpret=interpret)
         grad_sum = _pod_fold(partial)   # pod sum = DCN all-reduce
         count, stale_sum = meta[0], meta[1]
     else:
-        grad_sum = _variable_pop_ref(ring, scales, mask)
+        with jax.named_scope("master.ring"):
+            grad_sum = _variable_pop_ref(ring, scales, mask)
         mf = mask.astype(jnp.float32)
         count = jnp.sum(mf * cs[0])
         stale_sum = jnp.sum(mf * cs[0] * cs[1])

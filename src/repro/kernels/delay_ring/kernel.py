@@ -116,6 +116,7 @@ def delay_ring_slot_fwd(slot_pop, scales_pop, slot_push, scales_push,
         # donate the push slot/scales; residual_new reuses fed's buffer
         input_output_aliases={2: 1, 3: 2, 4: 3},
         interpret=interpret,
+        name="delay_ring_slot_int8",
     )(slot_pop, scales3(scales_pop), slot_push, scales3(scales_push), fed,
       scales3(scale_new))
     return popped, slot_new, scales_new.reshape((n_pods, rows)), \
@@ -221,6 +222,7 @@ def variable_pop_fwd(ring, mask, scales=None, counts_stale=None, *,
         popped, meta = pl.pallas_call(
             _variable_pop_kernel_f32, grid_spec=grid_spec,
             out_shape=out_shape, interpret=interpret,
+            name="variable_pop_f32",
         )(mask, cs, ring)
         return (popped, meta.reshape((2,))) if with_meta else popped
 
@@ -234,6 +236,7 @@ def variable_pop_fwd(ring, mask, scales=None, counts_stale=None, *,
     popped, meta = pl.pallas_call(
         _variable_pop_kernel_int8, grid_spec=grid_spec,
         out_shape=out_shape, interpret=interpret,
+        name="variable_pop_int8",
     )(mask, cs, ring, scales.reshape((n_slots, n_pods, 1, rows)))
     return (popped, meta.reshape((2,))) if with_meta else popped
 
@@ -266,6 +269,7 @@ def delay_ring_fwd(ring, g, head, scales=None, scale_new=None, *,
             ],
             input_output_aliases={1: 1},    # donate ring -> ring_new
             interpret=interpret,
+            name="delay_ring_f32",
         )(head, ring, g)
         return popped, ring_new, None, None
 
@@ -289,6 +293,7 @@ def delay_ring_fwd(ring, g, head, scales=None, scale_new=None, *,
         # donate ring / scales in place; residual_new reuses fed's buffer
         input_output_aliases={1: 1, 2: 2, 3: 3},
         interpret=interpret,
+        name="delay_ring_int8",
     )(head, ring, scales.reshape((tau, n_pods, 1, rows)), g,
       scale_new.reshape((n_pods, 1, rows)))
     return popped, ring_new, scales_new.reshape(scales.shape), residual_new

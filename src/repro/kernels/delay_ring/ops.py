@@ -179,7 +179,8 @@ def ring_variable_pop_sharded(ring, mask, *, scales=None,
         acc = part[0]                     # local pods: deterministic
         for p in range(1, part.shape[0]):  # left fold, shard-local
             acc = acc + part[p]
-        acc = jax.lax.psum(acc, "pod")    # THE one DCN reduce
+        with jax.named_scope("master.exchange"):
+            acc = jax.lax.psum(acc, "pod")    # THE one DCN reduce
         return (acc, meta) if with_meta else acc
 
     out_specs = (row_spec, mask_spec) if with_meta else row_spec
@@ -249,8 +250,10 @@ def ring_slot_rotate_int8_sharded(slot_pop, scales_pop, slot_push,
     def local_rotate(slot_pop, scales_pop, slot_push, scales_push,
                      fed, scale_new):
         # the wire transfer: gather the compressed payload over pods
-        q_all = jax.lax.all_gather(slot_pop, "pod", axis=0, tiled=True)
-        s_all = jax.lax.all_gather(scales_pop, "pod", axis=0, tiled=True)
+        with jax.named_scope("master.exchange"):
+            q_all = jax.lax.all_gather(slot_pop, "pod", axis=0, tiled=True)
+            s_all = jax.lax.all_gather(scales_pop, "pod", axis=0,
+                                       tiled=True)
         acc = None
         for p in range(q_all.shape[0]):
             x = jax.lax.optimization_barrier(

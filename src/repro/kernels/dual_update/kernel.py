@@ -66,6 +66,7 @@ def dual_update_fused_fwd(z, g_sum, denom, alpha, *, block_rows: int = 256,
         ],
         input_output_aliases={1: 0},   # donate z -> z_new
         interpret=interpret,
+        name="dual_update_fused",
     )(scal, z, g_sum)
     return z_new, w_new
 
@@ -96,5 +97,6 @@ def dual_update_fwd(z, g, alpha, *, block_rows: int = 256,
         ],
         input_output_aliases={1: 0},   # donate z -> z_new
         interpret=interpret,
+        name="dual_update",
     )(alpha2d, z, g)
     return z_new, w_new

@@ -117,6 +117,7 @@ def flash_fwd_lse(q, k, v, *, causal, window, scale, block_q, block_k,
             pltpu.VMEM((block_q, D), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_attention_fwd",
     )(qf, kf, vf)
     return out.reshape(B, Hq, Sq, D), lse.reshape(B, Hq, Sq)
 
@@ -235,6 +236,7 @@ def flash_attention_bwd(q, k, v, o, lse, do, *, causal, window, scale,
         out_shape=jax.ShapeDtypeStruct((B * Hq, Sq, D), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
         interpret=interpret,
+        name="flash_attention_dq",
     )(qf, kf, vf, dof, lsef, deltaf)
 
     # dk/dv per q-head, group-summed after (GQA)
@@ -264,6 +266,7 @@ def flash_attention_bwd(q, k, v, o, lse, do, *, causal, window, scale,
             pltpu.VMEM((block_k, D), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_attention_dkv",
     )(qf, kf, vf, dof, lsef, deltaf)
 
     dk = dk_h.reshape(B, Hkv, G, Skv, D).sum(axis=2).astype(k.dtype)

@@ -93,5 +93,6 @@ def linear_scan_fwd(g, q, k, v, *, chunk: int = 128,
         out_shape=jax.ShapeDtypeStruct((BH, S, hd), v.dtype),
         scratch_shapes=[pltpu.VMEM((ds, hd), jnp.float32)],
         interpret=interpret,
+        name="linear_scan",
     )(g, q, k, v)
     return out

@@ -194,10 +194,12 @@ def attention_apply(p, cfg: ModelConfig, x, positions, mask,
     XLA stand-in for the Pallas flash kernel on TPU)."""
     q, k, v = _project_qkv(p, cfg, x, positions)
     B, S = x.shape[:2]
-    if mask_fn is not None and S > _CHUNK_THRESHOLD and S % _Q_CHUNK == 0:
-        out = chunked_gqa_attend(q, k, v, mask_fn, cfg.logit_softcap)
-    else:
-        out = gqa_attend(q, k, v, mask, cfg.logit_softcap)
+    with jax.named_scope("model.mixer"):
+        if (mask_fn is not None and S > _CHUNK_THRESHOLD
+                and S % _Q_CHUNK == 0):
+            out = chunked_gqa_attend(q, k, v, mask_fn, cfg.logit_softcap)
+        else:
+            out = gqa_attend(q, k, v, mask, cfg.logit_softcap)
     return out.reshape(B, S, -1) @ p["wo"].astype(x.dtype)
 
 
@@ -294,8 +296,10 @@ def _act(name: str):
 
 def mlp_apply(p, cfg: ModelConfig, x):
     act = _act(cfg.act)
-    h = act(x @ p["w_gate"].astype(x.dtype)) * (x @ p["w_up"].astype(x.dtype))
-    return h @ p["w_down"].astype(x.dtype)
+    with jax.named_scope("model.mlp"):
+        h = (act(x @ p["w_gate"].astype(x.dtype))
+             * (x @ p["w_up"].astype(x.dtype)))
+        return h @ p["w_down"].astype(x.dtype)
 
 
 # ---------------------------------------------------------------------------

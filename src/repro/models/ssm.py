@@ -172,8 +172,9 @@ def mamba2_apply(p, cfg: ModelConfig, u):
     dt_act = jax.nn.softplus(dt.astype(jnp.float32) +
                              p["dt_bias"].astype(jnp.float32))
     A = -jnp.exp(p["a_log"].astype(jnp.float32))
-    y, _ = ssd_chunked(x, dt_act, A, Bmat, Cmat, chunk,
-                       unroll=cfg.scan_unroll)
+    with jax.named_scope("model.mixer"):
+        y, _ = ssd_chunked(x, dt_act, A, Bmat, Cmat, chunk,
+                           unroll=cfg.scan_unroll)
     y = y + x * p["d_skip"].astype(x.dtype)[None, None, :, None]
     if pad:
         y = y[:, :S]

@@ -136,7 +136,8 @@ def forward(params, cfg: ModelConfig, tokens, *, extra: Optional[Dict] = None,
     """tokens: (B, S_text) int32. Returns (logits over full sequence,
     moe_aux_loss). For VLM, ``extra['patches']`` (B, P, d) is prepended."""
     dtype = jnp.dtype(cfg.compute_dtype)
-    h = embed_tokens(params, tokens, dtype) * math.sqrt(cfg.d_model)
+    with jax.named_scope("model.embed"):
+        h = embed_tokens(params, tokens, dtype) * math.sqrt(cfg.d_model)
     if cfg.family == VLM and extra is not None and "patches" in extra:
         patches = extra["patches"].astype(dtype)
         patches = patches @ params["patch_proj"].astype(dtype)
@@ -173,8 +174,9 @@ def forward(params, cfg: ModelConfig, tokens, *, extra: Optional[Dict] = None,
                                          params["layers"],
                                          unroll=cfg.scan_unroll)
 
-    h = rmsnorm(h, params["ln_final"], cfg.norm_eps)
-    logits = output_logits(params, cfg, h)
+    with jax.named_scope("model.head"):
+        h = rmsnorm(h, params["ln_final"], cfg.norm_eps)
+        logits = output_logits(params, cfg, h)
     return logits, aux_total
 
 
@@ -260,8 +262,9 @@ def lm_loss(params, cfg: ModelConfig, batch) -> Tuple[jax.Array, Dict]:
         logits = logits[:, batch["patches"].shape[1]:, :]
     logits = logits[:, :-1]
     targets = tokens[:, 1:]
-    logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
-    ll = jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
+    with jax.named_scope("model.head"):
+        logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
+        ll = jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
     per_sample = -jnp.sum(ll, axis=-1)                     # (B,)
     n_tok_per_sample = targets.shape[1]
     loss_sum = jnp.sum(per_sample * weights)

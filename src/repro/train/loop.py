@@ -24,6 +24,7 @@ from typing import Callable, Dict, Optional
 
 import jax
 import numpy as np
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 from repro.configs.base import RunConfig
 from repro.data.pipeline import AnytimePipeline
@@ -169,72 +170,85 @@ def train(model: Model, rc: RunConfig, loop: LoopConfig,
         ckpt.save(loop.ckpt_dir, next_step, state, extra=extra)
 
     for step in range(start_step, loop.n_steps):
-        batch = pipeline.next_global_batch()
-        b_target = None
-        if batch_sched is not None:
-            from repro.data.pipeline import apply_batch_target
-            b_target = batch_sched.target()
-            batch["weights"] = apply_batch_target(
-                batch["weights"], b_target, loop.n_workers,
-                loop.samples_per_worker)
-        remesh_plan = None
-        if elastic_proc is not None:
-            active, speeds = elastic_proc.step()
-            at = float(step)
-            for i in np.flatnonzero(active):
-                if int(i) in health.evicted:
-                    # elastic re-mesh, recovery half: the process
-                    # brought the worker back -> readmit explicitly
-                    health.readmit(int(i), at=at)
-                    remesh_events.append({"step": step,
-                                          "event": "readmit",
-                                          "worker": int(i)})
-                health.heartbeat(int(i), at=at)
-            before = set(health.evicted)
-            health.tick(at=at)
-            newly_evicted = sorted(health.evicted - before)
-            batch["weights"] = fold_anytime_weights(
-                batch["weights"], active, speeds, loop.n_workers,
-                loop.samples_per_worker)
-            if wants_active:
-                batch["active"] = active.astype(np.float32)
-            if newly_evicted:
-                # persistent failure -> elastic re-mesh plan + an
-                # immediate checkpoint after this step commits (the
-                # launcher would rebuild the mesh and restore it)
-                remesh_plan = health.rescale_plan()
-                remesh_plan["evicted"] = sorted(health.evicted)
-                remesh_events.append({"step": step, "event": "evict",
-                                      "workers": newly_evicted,
-                                      "plan": remesh_plan})
-        if delay_proc is not None:
-            batch["delay"] = np.int32(delay_proc.next())
-        if b_target is not None:
-            batch["b_sched"] = np.float32(b_target)
-        batch = jax.tree.map(jax.numpy.asarray, batch)
-        state, metrics = step_fn(state, batch)
-        if batch_sched is not None:
-            # closed-loop feedback: the step's loss damps adadamp, the
-            # observed staleness feeds the delay-aware scaling
-            batch_sched.observe(
-                loss=float(metrics["loss"]),
-                tau_obs=(float(metrics["tau_applied"])
-                         if "tau_applied" in metrics else None))
-        if publisher is not None and \
-                (step + 1) % rc.serve.publish_period == 0:
-            publisher.publish(_served_params(state, rc.strategy),
-                              step + 1)
-        if (step + 1) % loop.log_every == 0 or step == loop.n_steps - 1:
-            m = {k: float(v) for k, v in metrics.items()}
-            m["wall_s"] = time.monotonic() - t_start
-            if elastic_proc is not None:
-                m["active_workers"] = float(active.sum())
-            history.append(m)
-            if log_fn:
-                log_fn(m)
-        if loop.ckpt_dir and ((step + 1) % loop.ckpt_every == 0
-                              or remesh_plan is not None):
-            save_ckpt(step + 1, plan=remesh_plan)
+        # one step span, loop.* spans inside it: recorded only while a
+        # profiler session runs (docs/profiling.md)
+        with StepTraceAnnotation("train", step_num=step):
+            with TraceAnnotation("loop.batch"):
+                batch = pipeline.next_global_batch()
+            with TraceAnnotation("loop.draws") as draws:
+                b_target = None
+                if batch_sched is not None:
+                    from repro.data.pipeline import apply_batch_target
+                    b_target = batch_sched.target()
+                    batch["weights"] = apply_batch_target(
+                        batch["weights"], b_target, loop.n_workers,
+                        loop.samples_per_worker)
+                remesh_plan = None
+                if elastic_proc is not None:
+                    active, speeds = elastic_proc.step()
+                    at = float(step)
+                    for i in np.flatnonzero(active):
+                        if int(i) in health.evicted:
+                            # elastic re-mesh, recovery half: the process
+                            # brought the worker back -> readmit explicitly
+                            health.readmit(int(i), at=at)
+                            remesh_events.append({"step": step,
+                                                  "event": "readmit",
+                                                  "worker": int(i)})
+                        health.heartbeat(int(i), at=at)
+                    before = set(health.evicted)
+                    health.tick(at=at)
+                    newly_evicted = sorted(health.evicted - before)
+                    batch["weights"] = fold_anytime_weights(
+                        batch["weights"], active, speeds, loop.n_workers,
+                        loop.samples_per_worker)
+                    if wants_active:
+                        batch["active"] = active.astype(np.float32)
+                    if newly_evicted:
+                        # persistent failure -> elastic re-mesh plan + an
+                        # immediate checkpoint after this step commits (the
+                        # launcher would rebuild the mesh and restore it)
+                        remesh_plan = health.rescale_plan()
+                        remesh_plan["evicted"] = sorted(health.evicted)
+                        remesh_events.append({"step": step,
+                                              "event": "evict",
+                                              "workers": newly_evicted,
+                                              "plan": remesh_plan})
+                if delay_proc is not None:
+                    batch["delay"] = np.int32(delay_proc.next())
+                    draws.set_metadata(delay=int(batch["delay"]))
+                if b_target is not None:
+                    batch["b_sched"] = np.float32(b_target)
+            with TraceAnnotation("loop.put"):
+                batch = jax.tree.map(jax.numpy.asarray, batch)
+            with TraceAnnotation("loop.dispatch"):
+                state, metrics = step_fn(state, batch)
+            if batch_sched is not None:
+                # closed-loop feedback: the step's loss damps adadamp, the
+                # observed staleness feeds the delay-aware scaling
+                with TraceAnnotation("loop.observe"):
+                    batch_sched.observe(
+                        loss=float(metrics["loss"]),
+                        tau_obs=(float(metrics["tau_applied"])
+                                 if "tau_applied" in metrics else None))
+            if publisher is not None and \
+                    (step + 1) % rc.serve.publish_period == 0:
+                with TraceAnnotation("loop.publish"):
+                    publisher.publish(_served_params(state, rc.strategy),
+                                      step + 1)
+            if (step + 1) % loop.log_every == 0 or step == loop.n_steps - 1:
+                with TraceAnnotation("loop.log"):
+                    m = {k: float(v) for k, v in metrics.items()}
+                    m["wall_s"] = time.monotonic() - t_start
+                    if elastic_proc is not None:
+                        m["active_workers"] = float(active.sum())
+                    history.append(m)
+                    if log_fn:
+                        log_fn(m)
+            if loop.ckpt_dir and ((step + 1) % loop.ckpt_every == 0
+                                  or remesh_plan is not None):
+                with TraceAnnotation("loop.checkpoint"):
+                    save_ckpt(step + 1, plan=remesh_plan)
     return {"state": state, "history": history, "step_fn": step_fn,
             "b_history": pipeline.b_history,
             "remesh_events": remesh_events,

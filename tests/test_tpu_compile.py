@@ -1,4 +1,4 @@
-"""The arena kernels compiled for a TPU v5e by the chip's own compiler,
+"""The Pallas kernels compiled for a TPU v5e by the chip's own compiler,
 with no chip attached (``jax.experimental.topologies``).
 
 Interpret mode cannot show what the chip refuses — block shapes that
@@ -14,6 +14,7 @@ process at a time may load the TPU library.
 """
 import dataclasses
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -22,9 +23,13 @@ import pytest
 import repro.configs as C
 from repro.core import arena
 from repro.kernels import fit_block_rows
-from repro.kernels.delay_ring.kernel import (delay_ring_slot_fwd,
+from repro.kernels.delay_ring.kernel import (delay_ring_fwd,
+                                             delay_ring_slot_fwd,
                                              variable_pop_fwd)
-from repro.kernels.dual_update.kernel import dual_update_fused_fwd
+from repro.kernels.dual_update.kernel import (dual_update_fused_fwd,
+                                              dual_update_fwd)
+from repro.kernels.flash_attention.bwd import flash_attention_train
+from repro.kernels.flash_attention.kernel import flash_attention_fwd
 from repro.models import build_model
 
 BLOCK = 256
@@ -128,3 +133,63 @@ def test_int8_block_rows_follow_the_chip(one_chip):
     with pytest.raises(Exception, match="divisible by 8 and 128"):
         _compile_text(fn, one_chip, slot, scales, slot, scales,
                       ((1, r, 128), jnp.float32), scales)
+
+
+def _flash_attention_grads(q, k, v):
+    def loss(q, k, v):
+        return jnp.sum(flash_attention_train(q, k, v).astype(jnp.float32))
+    return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+
+_QKV = [((1, 2, 256, 128), jnp.bfloat16), ((1, 1, 256, 128), jnp.bfloat16),
+        ((1, 1, 256, 128), jnp.bfloat16)]
+_R = 1024            # arena rows of the small cases
+# kernel name -> (function, argument shapes): every pallas_call in
+# kernels/ at a small shape the chip takes, but linear_scan's, which the
+# chip's Pallas lowering refuses (it has no cumsum)
+NAMED_KERNELS = {
+    "dual_update": (
+        functools.partial(dual_update_fwd, block_rows=BLOCK),
+        [((_R, 128), jnp.float32)] * 2 + [((), jnp.float32)]),
+    "dual_update_fused": (
+        functools.partial(dual_update_fused_fwd, block_rows=BLOCK),
+        [((_R, 128), jnp.float32)] * 2 + [((), jnp.float32)] * 2),
+    "delay_ring_f32": (
+        lambda ring, g, head: delay_ring_fwd(ring, g, head,
+                                             block_rows=BLOCK)[:2],
+        [((2, 1, _R, 128), jnp.float32), ((1, _R, 128), jnp.float32),
+         ((), jnp.int32)]),
+    "delay_ring_int8": (
+        lambda ring, sc, g, head, sn: delay_ring_fwd(
+            ring, g, head, scales=sc, scale_new=sn, block_rows=BLOCK),
+        [((2, 1, _R, 128), jnp.int8), ((2, 1, _R), jnp.float32),
+         ((1, _R, 128), jnp.float32), ((), jnp.int32),
+         ((1, _R), jnp.float32)]),
+    "delay_ring_slot_int8": (
+        functools.partial(delay_ring_slot_fwd, block_rows=BLOCK),
+        [((1, _R, 128), jnp.int8), ((1, _R), jnp.float32)] * 2
+        + [((1, _R, 128), jnp.float32), ((1, _R), jnp.float32)]),
+    "variable_pop_f32": (
+        functools.partial(variable_pop_fwd, block_rows=BLOCK),
+        [((N_SLOTS, 1, _R, 128), jnp.float32), ((N_SLOTS,), jnp.bool_)]),
+    "variable_pop_int8": (
+        functools.partial(variable_pop_fwd, block_rows=BLOCK),
+        [((N_SLOTS, 1, _R, 128), jnp.int8), ((N_SLOTS,), jnp.bool_),
+         ((N_SLOTS, 1, _R), jnp.float32)]),
+    "flash_attention": (flash_attention_fwd, _QKV),
+    "flash_attention_fwd": (_flash_attention_grads, _QKV),
+    "flash_attention_dq": (_flash_attention_grads, _QKV),
+    "flash_attention_dkv": (_flash_attention_grads, _QKV),
+}
+
+
+@pytest.mark.parametrize("name", sorted(NAMED_KERNELS))
+def test_kernel_carries_its_name(one_chip, name):
+    """Each Mosaic custom call is named after its kernel (``name=`` on
+    the pallas_call), so a profile of the chip shows ``%<name>.N``
+    where it would show the enclosing function's name (under autodiff
+    with the transform around it: ``%transpose_jvp_<name>__.N``)."""
+    fn, shapes = NAMED_KERNELS[name]
+    text = _compile_text(fn, one_chip, *shapes)
+    assert re.search(rf"%(?:\w+_)?{name}_*(?:\.\d+)? = .*custom-call\(.*"
+                     r"custom_call_target=\"tpu_custom_call\"", text), name
