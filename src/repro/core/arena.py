@@ -1,22 +1,27 @@
-"""Persistent, lane-aligned flat gradient arena — the master pipeline's
+"""Persistent, tile-aligned flat gradient arena — the master pipeline's
 memory layout.
 
-Every leaf of the parameter pytree is flattened and padded up to a
-whole number of 128-lane rows; leaves are laid out back to back in one
-``(rows, 128)`` f32 buffer (rows padded to a multiple of the kernel
-block). The layout is computed ONCE at ``init_state`` and carried as a
-static closure constant (``ArenaLayout``); per-step work never
-re-flattens the tree with ``jnp.concatenate`` — the gradient is
-scattered into a preallocated buffer with static-offset update-slices,
-and the dual variable ``z``, the delay ring, and the int8
-error-feedback residual live in arena form permanently.
+Every leaf of the parameter pytree becomes a strip of 128-lane rows;
+strips lie back to back in one ``(rows, 128)`` f32 buffer (rows padded
+to a multiple of the kernel block). A leaf of rank 2 or more is stored
+as the memory image of its (8,128)-tiled form — the way the TPU holds
+it — so moving it in or out of the arena is a copy of whole tiles; a
+rank 0 or 1 leaf is its flat C-order strip. Every strip starts on an
+8-row boundary and fills whole 8-row tiles (``_leaf_strip`` and
+``_strip_leaf`` are the only conversions). The layout is computed ONCE
+at ``init_state`` and carried as a static closure constant
+(``ArenaLayout``); per-step work never re-flattens the tree with
+``jnp.concatenate`` — the gradient is scattered into a preallocated
+buffer with static-offset update-slices, and the dual variable ``z``,
+the delay ring, and the int8 error-feedback residual live in arena
+form permanently.
 
 Row alignment is what makes int8 compression cheap here: every row
 belongs to exactly one leaf, so the pytree path's *per-tensor* scales
 become *per-row* vectors through a static row->leaf map — elementwise
 multiplies in the kernel, no gathers — while staying bit-identical to
 the per-tensor reference (a max is a max regardless of reduction
-order).
+order, and the zero pads never raise it).
 
 The delay ring has three layouts (see ``GradArena``): the default v2
 stores one buffer per slot (tau+1 of them) and selects slots with
@@ -41,7 +46,27 @@ import jax.numpy as jnp
 import numpy as np
 
 LANES = 128
+SUBLANES = 8      # rows of one (8,128) f32 tile; every strip fills whole tiles
 BLOCK_ROWS = 256  # kernel grid block; total rows padded to a multiple
+LEAF_LAYOUT = 2   # leaf order in a strip (1: flat C order for every rank)
+# named scope of the staging fill (``flatten_tree`` / ``scatter_fed``):
+# the matrix harness attributes the fill's layout copies by it
+FILL_SCOPE = "arena.fill"
+
+
+def _round_up(n: int, m: int) -> int:
+    return -(-n // m) * m
+
+
+def _strip_rows(shape) -> int:
+    """Rows of a leaf's strip: its (8,128)-tiled image for rank >= 2,
+    its flat C-order rows for rank 0/1, either padded to whole tiles."""
+    if len(shape) >= 2:
+        *lead, m, n = shape
+        return (int(np.prod(lead)) * _round_up(m, SUBLANES)
+                * _round_up(n, LANES) // LANES)
+    size = int(np.prod(shape)) if shape else 1
+    return _round_up(-(-size // LANES), SUBLANES)
 
 
 class ArenaLayout:
@@ -52,24 +77,25 @@ class ArenaLayout:
         self.shapes = tuple(tuple(s) for s in shapes)
         self.dtypes = tuple(dtypes)
         self.sizes = tuple(int(np.prod(s)) if s else 1 for s in self.shapes)
-        self.row_counts = tuple(-(-s // LANES) for s in self.sizes)
-        offs, o = [], 0
-        for rc in self.row_counts:
-            offs.append(o)
-            o += rc
-        self.row_offsets = tuple(offs)
+        self.row_counts = tuple(_strip_rows(s) for s in self.shapes)
+        self.row_offsets = tuple(
+            int(o) for o in np.cumsum((0,) + self.row_counts[:-1]))
         self.n_leaves = len(self.sizes)
-        self.rows = -(-o // BLOCK_ROWS) * BLOCK_ROWS
+        self.rows = _round_up(sum(self.row_counts), BLOCK_ROWS)
         # static row -> leaf map; tail-pad rows get the sentinel segment
         # ``n_leaves`` (their scale is pinned to 1, their data to 0)
         r2l = np.full((self.rows,), self.n_leaves, np.int32)
         for i, (ro, rc) in enumerate(zip(self.row_offsets, self.row_counts)):
             r2l[ro:ro + rc] = i
         self.row_to_leaf = r2l
-
-    @property
-    def numel(self) -> int:
-        return self.rows * LANES
+        # share of the parameters in whole-tile images: rank >= 2
+        # leaves whose last two dims need no padding, so their strip is
+        # a pure permutation of the flat layout's rows
+        total = sum(self.sizes)
+        whole = sum(s for s, sh in zip(self.sizes, self.shapes)
+                    if len(sh) >= 2 and sh[-2] % SUBLANES == 0
+                    and sh[-1] % LANES == 0)
+        self.tiled_share = whole / total if total else 0.0
 
 
 def make_layout(params) -> ArenaLayout:
@@ -78,6 +104,66 @@ def make_layout(params) -> ArenaLayout:
     leaves, treedef = jax.tree.flatten(params)
     return ArenaLayout(treedef, [l.shape for l in leaves],
                        [l.dtype for l in leaves])
+
+
+def _leaf_strip(layout: ArenaLayout, i: int, leaf, leading: int):
+    """Leaf ``i`` (with ``leading`` extra leading dims) as its f32 strip
+    ``(*lead, row_counts[i], 128)``, zero-padded. Rank >= 2: pad the
+    last two dims to (8k, 128j) and lay the tiles out in row-major
+    order, each as its 8 sublanes; rank 0/1: the flat row-major rows."""
+    lead = tuple(leaf.shape[:leading])
+    shape = layout.shapes[i]
+    x = leaf.astype(np.float32)
+    if len(shape) >= 2:
+        m, n = shape[-2:]
+        pm, pn = _round_up(m, SUBLANES) - m, _round_up(n, LANES) - n
+        if pm or pn:
+            x = jnp.pad(x, [(0, 0)] * (x.ndim - 2) + [(0, pm), (0, pn)])
+        x = x.reshape(lead + (int(np.prod(shape[:-2])),
+                              (m + pm) // SUBLANES, SUBLANES,
+                              (n + pn) // LANES, LANES)).swapaxes(-3, -2)
+    else:
+        x = x.reshape(lead + (layout.sizes[i],))
+        pad = layout.row_counts[i] * LANES - layout.sizes[i]
+        if pad:
+            x = jnp.pad(x, [(0, 0)] * leading + [(0, pad)])
+    return x.reshape(lead + (layout.row_counts[i], LANES))
+
+
+def _strip_leaf(layout: ArenaLayout, i: int, strip):
+    """Inverse of ``_leaf_strip``: ``(*lead, row_counts[i], 128)`` ->
+    ``(*lead, *shapes[i])``, pads dropped, dtype kept."""
+    lead = tuple(strip.shape[:-2])
+    shape = layout.shapes[i]
+    if len(shape) >= 2:
+        m, n = shape[-2:]
+        m8, n128 = _round_up(m, SUBLANES), _round_up(n, LANES)
+        x = strip.reshape(lead + (int(np.prod(shape[:-2])), m8 // SUBLANES,
+                                  n128 // LANES, SUBLANES, LANES))
+        x = x.swapaxes(-3, -2).reshape(lead + shape[:-2] + (m8, n128))
+        return x[..., :m, :n]
+    x = strip.reshape(lead + (layout.row_counts[i] * LANES,))
+    return x[..., :layout.sizes[i]].reshape(lead + shape)
+
+
+def _scatter(layout: ArenaLayout, tree, leading: int, out, slot=None,
+             add=None):
+    """Write every leaf's strip into ``out`` at its row offset — into
+    ``out[slot]`` when a (static) slot index is given — plus the same
+    rows of ``add`` when given. Static-offset update-slices only."""
+    leaves = layout.treedef.flatten_up_to(tree)
+    at = () if slot is None else (slot,)
+    for i, leaf in enumerate(leaves):
+        x = _leaf_strip(layout, i, leaf, leading)
+        ofs = layout.row_offsets[i]
+        if add is not None:
+            x = x + jax.lax.slice_in_dim(add, ofs, ofs + layout.row_counts[i],
+                                         axis=leading)
+        if slot is not None:
+            x = x[None]
+        out = jax.lax.dynamic_update_slice(
+            out, x.astype(out.dtype), at + (0,) * leading + (ofs, 0))
+    return out
 
 
 def flatten_tree(layout: ArenaLayout, tree, leading: int = 0, out=None):
@@ -92,64 +178,42 @@ def flatten_tree(layout: ArenaLayout, tree, leading: int = 0, out=None):
     faster than materializing a fresh buffer: no zero-fill, no
     allocation, just the leaf writes).
     """
-    leaves = layout.treedef.flatten_up_to(tree)
-    lead = leaves[0].shape[:leading] if leaves else ()
-    if out is None:
-        out = jnp.zeros(lead + (layout.rows, LANES), jnp.float32)
     # NB: never reshape ``out`` — reshaping the donated accumulator
     # breaks XLA's in-place update-slice chain (measured 10x on CPU);
     # scatter along the row axis instead.
-    for leaf, ofs, size, rc in zip(leaves, layout.row_offsets,
-                                   layout.sizes, layout.row_counts):
-        x = _padded_leaf(leaf, size, rc, leading)
-        out = jax.lax.dynamic_update_slice_in_dim(
-            out, x.reshape(lead + (rc, LANES)), ofs, axis=leading)
-    return out
-
-
-def _padded_leaf(leaf, size: int, rc: int, leading: int):
-    """One leaf as a (*lead, rc*128) f32 row-aligned strip."""
-    lead = leaf.shape[:leading]
-    x = leaf.reshape(lead + (size,)).astype(jnp.float32)
-    pad = rc * LANES - size
-    if pad:
-        x = jnp.pad(x, [(0, 0)] * leading + [(0, pad)])
-    return x
+    if out is None:
+        leaves = layout.treedef.flatten_up_to(tree)
+        lead = leaves[0].shape[:leading] if leaves else ()
+        out = jnp.zeros(lead + (layout.rows, LANES), jnp.float32)
+    with jax.named_scope(FILL_SCOPE):
+        return _scatter(layout, tree, leading, out)
 
 
 def scatter_fed(layout: ArenaLayout, tree, residual, out):
     """int8 error feedback: build fed = g + residual in arena form with
     one scatter pass (leaf read + residual-row read + in-place write
     into the staging buffer), instead of flatten-then-add."""
-    n_pods = residual.shape[0]
-    leaves = layout.treedef.flatten_up_to(tree)
-    for leaf, ofs, size, rc in zip(leaves, layout.row_offsets,
-                                   layout.sizes, layout.row_counts):
-        r = jax.lax.dynamic_slice(residual, (0, ofs, 0),
-                                  (n_pods, rc, LANES))
-        x = _padded_leaf(leaf, size, rc, 1).reshape(n_pods, rc, LANES) + r
-        out = jax.lax.dynamic_update_slice(out, x, (0, ofs, 0))
-    return out
+    with jax.named_scope(FILL_SCOPE):
+        return _scatter(layout, tree, 1, out, add=residual)
 
 
 def unflatten_tree(layout: ArenaLayout, mat, cast: bool = True, scale=None):
-    """Gather arena rows back into the pytree (static slices — reads,
-    not copies-of-everything). ``cast=False`` keeps every leaf f32
-    (the dual-averaging ``w`` convention); ``cast=True`` restores the
-    layout dtypes. ``scale`` multiplies each slice on the way out —
-    the dual-averaging prox (w = -alpha z) rides the gather for free
-    instead of materializing a separate w buffer."""
-    lead = mat.shape[:-2]
-    flat = mat.reshape(lead + (layout.numel,))
+    """Gather arena rows back into the pytree: a static row slice per
+    leaf, then its tiling undone — never a reshape of the whole arena
+    (on the TPU, the (rows, 128) -> 1-D reshape is a relayout copy of
+    every row). ``cast=False`` keeps every leaf f32 (the dual-averaging
+    ``w`` convention); ``cast=True`` restores the layout dtypes.
+    ``scale`` multiplies each slice on the way out — the dual-averaging
+    prox (w = -alpha z) rides the gather for free instead of
+    materializing a separate w buffer."""
+    axis = mat.ndim - 2
     out = []
-    for ofs, size, shape, dtype in zip(layout.row_offsets, layout.sizes,
-                                       layout.shapes, layout.dtypes):
-        x = jax.lax.slice_in_dim(flat, ofs * LANES, ofs * LANES + size,
-                                 axis=len(lead))
+    for i, (ofs, rc) in enumerate(zip(layout.row_offsets, layout.row_counts)):
+        x = jax.lax.slice_in_dim(mat, ofs, ofs + rc, axis=axis)
         if scale is not None:
             x = scale * x
-        x = x.reshape(lead + shape)
-        out.append(x.astype(dtype) if cast else x)
+        x = _strip_leaf(layout, i, x)
+        out.append(x.astype(layout.dtypes[i]) if cast else x)
     return layout.treedef.unflatten(out)
 
 
@@ -158,21 +222,10 @@ def _scatter_slot(layout: ArenaLayout, ring, tree, head):
     over the (static, small) tau slots keeps every update-slice
     STATICALLY indexed — XLA:CPU then writes in place, where a dynamic
     head index degrades every chained update into a full ring copy."""
-    tau, n_pods = ring.shape[:2]
-    leaves = layout.treedef.flatten_up_to(tree)
-    strips = [
-        _padded_leaf(leaf, size, rc, 1).reshape(n_pods, rc, LANES)
-        for leaf, size, rc in zip(leaves, layout.sizes, layout.row_counts)]
-
-    def branch(k):
-        def push(r):
-            for strip, ofs in zip(strips, layout.row_offsets):
-                r = jax.lax.dynamic_update_slice(
-                    r, strip[None].astype(r.dtype), (k, 0, ofs, 0))
-            return r
-        return push
-
-    return jax.lax.switch(head, [branch(k) for k in range(tau)], ring)
+    tau = ring.shape[0]
+    return jax.lax.switch(
+        head, [lambda r, k=k: _scatter(layout, tree, 1, r, slot=k)
+               for k in range(tau)], ring)
 
 
 def _update_slot_int8(ring, scales, q, scale_new, head):
@@ -795,21 +848,6 @@ def push_pop(layout: ArenaLayout, arena: GradArena, pod_grads, pod_counts,
     return grad_sum, count, new_arena
 
 
-def _scatter_slot_stacked(layout: ArenaLayout, ring, tree, k: int):
-    """Per-leaf scatter straight into stacked slot ``ring[k]`` — every
-    index (slot AND row offset) is static, so XLA:CPU chains in-place
-    update-slices on the donated buffer, exactly like the v2 per-slot
-    scatter (no temp slot, no copy-protection)."""
-    n_pods = ring.shape[1]
-    leaves = layout.treedef.flatten_up_to(tree)
-    for leaf, ofs, size, rc in zip(leaves, layout.row_offsets,
-                                   layout.sizes, layout.row_counts):
-        x = _padded_leaf(leaf, size, rc, 1).reshape(n_pods, rc, LANES)
-        ring = jax.lax.dynamic_update_slice(
-            ring, x[None].astype(ring.dtype), (k, 0, ofs, 0))
-    return ring
-
-
 def _variable_pop_ref(ring, scales, mask):
     """Reference pop of the stacked delay-tolerant ring: fold the due
     slots, mesh-aware.
@@ -961,8 +999,8 @@ def push_pop_variable(layout: ArenaLayout, arena: GradArena, pod_grads,
         from repro.dist.context import active_mesh
         mesh = active_mesh()
         if mesh is not None and mesh.n_devices > 1:
-            # GSPMD cannot keep the per-leaf unaligned row-offset
-            # update-slices of _scatter_slot_stacked sharded — each one
+            # GSPMD cannot keep the per-leaf row-offset update-slices
+            # of the in-place slot scatter sharded — each one
             # rematerializes the WHOLE stacked ring through layout
             # copies (flagged by the matrix harness's ring-copy
             # invariant). Build the slot in a temp instead ("none"
@@ -976,9 +1014,12 @@ def push_pop_variable(layout: ArenaLayout, arena: GradArena, pod_grads,
                 ring = jax.lax.dynamic_update_slice(
                     arena.ring, fed[None], (k, 0, 0, 0))
         else:
+            # per-leaf scatter straight into stacked slot ring[k]: every
+            # index (slot AND row offset) is static, so XLA:CPU chains
+            # in-place update-slices on the donated buffer, like the v2
+            # per-slot scatter (no temp slot, no copy-protection)
             with jax.named_scope("master.stage"):
-                ring = _scatter_slot_stacked(layout, arena.ring,
-                                             pod_grads, k)
+                ring = _scatter(layout, pod_grads, 1, arena.ring, slot=k)
         staging = arena.staging       # untouched pass-through (zero cost)
         scales, residual = None, None
 

@@ -330,6 +330,8 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
     with jax.set_mesh(mesh), \
             sharding_profile(rc.mesh if rc.mesh.n_devices > 1 else None):
         ring_impl = resolve_impl("auto", pod_shard_map=True)
+    layout = arena_mod.make_layout(shapes_and_axes(
+        build_model(rc.model).init, jax.random.PRNGKey(0))[0])
     result = {
         "arch": arch, "shape": shape_name,
         # derived from the cell's ACTUAL mesh — an explicit rc with a
@@ -338,6 +340,8 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
         "strategy": rc.strategy,
         "master": {"ring_version": arena_mod.RING_VERSION,
                    "ring_impl": ring_impl,
+                   # share of parameters in whole-tile arena images
+                   "tiled_share": layout.tiled_share,
                    # delay-tolerant ring cells read all tau_max+1 slots
                    # per step (masked fold) instead of one static slot
                    "delay_process": rc.delay.process,

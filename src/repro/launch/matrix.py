@@ -36,7 +36,6 @@ groups cells per count and spawns one subprocess per group):
   PYTHONPATH=src python -m repro.launch.matrix --list
 """
 import argparse
-import inspect
 import json
 import sys
 import time
@@ -178,28 +177,18 @@ def _arena_rows(rc: RunConfig) -> int:
 # ---------------------------------------------------------------------------
 # Invariant A: zero ring-dtype copies (docs/arena.md donation contract)
 # ---------------------------------------------------------------------------
-def _staging_fill_spans():
-    """Source-line spans of the arena staging fill (``flatten_tree`` /
-    ``scatter_fed``): per-leaf row-offset update-slices that GSPMD
-    cannot keep row-sharded at scale, producing layout copies on
-    STAGING-shaped tensors. Computed via ``inspect`` so the allowlist
-    tracks the code instead of hardcoded line numbers."""
-    spans = []
-    for fn in (arena_mod.flatten_tree, arena_mod.scatter_fed):
-        src, start = inspect.getsourcelines(fn)
-        spans.append((start, start + len(src)))
-    return spans
-
-
-def _attribute_copy(rec: Dict, spans) -> Optional[str]:
+def _attribute_copy(rec: Dict) -> Optional[str]:
     """Attribute a copy to one of the KNOWN per-leaf-slicing classes
     (docs/matrix.md — the finding this harness filed), or None if it
     is unaccounted for:
 
-    ``staging_fill``    layout copies whose source line sits inside
-        ``arena.flatten_tree`` / ``arena.scatter_fed`` — the per-leaf
-        unaligned row-offset update-slices on the f32 staging buffer —
-        or metadata-less copies of a staging-fill fusion's result
+    ``staging_fill``    layout copies made under the named scope of
+        ``arena.flatten_tree`` / ``arena.scatter_fed``
+        (``arena.FILL_SCOPE``) — the per-leaf row-offset update-slices
+        on the f32 staging buffer; the ring-slot scatters run the same
+        ``arena._scatter`` outside that scope, so their copies still
+        count — or metadata-less copies of a staging-fill fusion's
+        result
         (XLA drops op metadata on copies it inserts at fusion
         boundaries; the producing fusion's name still carries the
         dynamic-update-slice root).
@@ -210,9 +199,7 @@ def _attribute_copy(rec: Dict, spans) -> Optional[str]:
         exempted — a failed donation of the ring/slot buffers would
         surface under its own ``state.arena.*`` name and still FAIL.
     """
-    f, ln = rec.get("source_file"), rec.get("source_line")
-    if f and ln is not None and f.endswith("core/arena.py") \
-            and any(lo <= ln < hi for lo, hi in spans):
+    if arena_mod.FILL_SCOPE in (rec.get("op_name") or ""):
         return "staging_fill"
     if rec.get("op_name") == "state.arena.residual":
         return "residual_slice"
@@ -296,7 +283,6 @@ def check_ring_copies(cell: MatrixCell, rc: RunConfig, rows: int,
     reported, not violations; on an uncompressed cell the ring IS f32
     and shape-identical to staging, so there only the attributed
     classes are exempt and any unaccounted copy still fails."""
-    spans = _staging_fill_spans()
     ring_dt = "s8" if cell.pod_compression == "int8" else "f32"
     if cell.kind == "decode":
         ring_keys = _publish_shape_keys(rc, rows)
@@ -323,7 +309,7 @@ def check_ring_copies(cell: MatrixCell, rc: RunConfig, rows: int,
             is_ring = rec["key"] in ring_keys
             if not is_ring and rec["key"] not in staging_keys:
                 continue
-            cls = _attribute_copy(rec, spans)
+            cls = _attribute_copy(rec)
             if cls is None and is_ring:
                 op_toks = [t for t in (rec.get("operand") or "").split()
                            if t.startswith("%")]

@@ -11,7 +11,11 @@ retention. Fault-tolerance contract:
     opt_state round-trip leaf-by-leaf like any other state;
   * writes go to ``<dir>/tmp.<step>`` then os.replace() into place, so
     a crash mid-save never corrupts the latest checkpoint;
-  * ``keep`` most-recent checkpoints are retained.
+  * ``keep`` most-recent checkpoints are retained;
+  * the manifest records the arena's leaf layout (``leaf_layout``,
+    ``core.arena.LEAF_LAYOUT``): arena-form state saved under an older
+    leaf order is moved into the current one on restore, never read
+    as if it were in it.
 
 Format: one .npz per checkpoint (leaves flattened with path-keys) +
 a small JSON manifest. Device arrays are fetched with device_get — on a
@@ -47,8 +51,9 @@ def save(ckpt_dir: str, step: int, state, extra: Optional[Dict] = None,
     os.makedirs(tmp, exist_ok=True)
     flat = _flatten_with_paths(state)
     np.savez(os.path.join(tmp, "state.npz"), **flat)
+    from repro.core.arena import LEAF_LAYOUT
     manifest = {"step": int(step), "keys": sorted(flat),
-                "extra": extra or {}}
+                "leaf_layout": LEAF_LAYOUT, "extra": extra or {}}
     with open(os.path.join(tmp, "manifest.json"), "w") as f:
         json.dump(manifest, f)
     if os.path.exists(final):
@@ -154,6 +159,99 @@ def _migrate_decentralized_residual(data, keys, paths
     return out
 
 
+def _arena_params(template):
+    """(parameter tree, leading dims of its leaves) that the template's
+    arena-form state lays out, or None: TrainState and KBatchState hold
+    the tree itself, DecentralizedState one copy per worker."""
+    from repro.core.strategy import DecentralizedState, KBatchState
+    node = template.base if isinstance(template, KBatchState) else template
+    params = (node.get("params") if isinstance(node, dict)
+              else getattr(node, "params", None))
+    if params is None:
+        return None
+    return params, int(isinstance(node, DecentralizedState))
+
+
+def _migrate_leaf_layout(arrays, version: int, keys, paths, template
+                         ) -> Dict[str, np.ndarray]:
+    """Arena-form state in the flat leaf layout (version 1: every leaf
+    C-order flattened into 128-lane rows, back to back) -> the
+    tiled-image strips of ``core.arena``, at the numpy level: each leaf
+    is cut out of its old rows and written back as its strip. The
+    arena-form arrays are the template's ``(..., rows, 128)`` leaves
+    outside the parameters (z, ring slots, residual, staging, optimizer
+    moments); per-row ``scales`` hold one value per leaf and follow
+    their leaf, pad rows 1. For qwen-like trees ``rows`` does not
+    change, so only this keeps such state from loading silently in the
+    wrong order. Returns an overlay dict."""
+    from repro.core import arena
+    if version == arena.LEAF_LAYOUT:
+        return {}
+    if version != 1:
+        raise ValueError(f"checkpoint leaf layout {version} is newer "
+                         f"than this program's ({arena.LEAF_LAYOUT})")
+    found = _arena_params(template)
+    if found is None:
+        return {}
+    params, lead = found
+    leaves = jax.tree.leaves(params)
+    layout = arena.make_layout([jax.ShapeDtypeStruct(l.shape[lead:],
+                                                     l.dtype)
+                                for l in leaves])
+    if any(tuple(l.shape[-2:]) == (layout.rows, arena.LANES)
+           for l in leaves):
+        raise ValueError("a parameter has the arena's (rows, 128) shape: "
+                         "the checkpoint's arena-form state cannot be "
+                         "told from parameter-shaped state")
+    # the flat layout: each leaf's C-order rows, back to back
+    old = np.cumsum([0] + [arena._round_up(size, arena.LANES) // arena.LANES
+                           for size in layout.sizes])
+    old_rows = arena._round_up(int(old[-1]), arena.BLOCK_ROWS)
+    strips = list(zip(old[:-1], layout.row_offsets, layout.row_counts))
+    is_param = {id(l) for l in leaves}
+    out: Dict[str, np.ndarray] = {}
+    for key, (_, leaf) in zip(keys, paths):
+        arr = arrays.get(key)
+        if arr is None or id(leaf) in is_param:
+            continue
+        if (tuple(leaf.shape[-2:]) == (layout.rows, arena.LANES)
+                and arr.shape[-2:] == (old_rows, arena.LANES)):
+            lead_shape = arr.shape[:-2]
+            flat = arr.reshape(lead_shape + (-1,))
+            new = np.zeros(lead_shape + (layout.rows, arena.LANES),
+                           arr.dtype)
+            for i, (o, ofs, rc) in enumerate(strips):
+                x = flat[..., o * arena.LANES:o * arena.LANES
+                         + layout.sizes[i]]
+                x = x.reshape(lead_shape + layout.shapes[i])
+                new[..., ofs:ofs + rc, :] = np.asarray(arena._leaf_strip(
+                    layout, i, x, len(lead_shape)))
+            out[key] = new
+        elif (re.search(r"scales(/\d+)?$", key)
+              and tuple(leaf.shape[-1:]) == (layout.rows,)
+              and arr.shape[-1:] == (old_rows,)):
+            new = np.ones(arr.shape[:-1] + (layout.rows,), arr.dtype)
+            for o, ofs, rc in strips:
+                new[..., ofs:ofs + rc] = arr[..., o:o + 1]
+            out[key] = new
+    return out
+
+
+def _migrate_publisher(extra: Dict, version: int) -> Dict:
+    """The publisher's snapshot ring (``serve.publisher``, in ``extra``)
+    holds per-row int8 arena rows. Saved under an older leaf layout its
+    rows are in the old order, and where a leaf is padded one old row
+    straddles leaves, so they cannot be reordered: the snapshots are
+    dropped (every slot marked unwritten), and pops miss until the next
+    publish, as before the first one."""
+    from repro.core import arena
+    if version == arena.LEAF_LAYOUT or "publisher" not in extra:
+        return extra
+    pub = dict(extra["publisher"])
+    pub["pub_step"] = np.full(np.shape(pub["pub_step"]), -1, np.int64)
+    return {**extra, "publisher": pub}
+
+
 def restore(ckpt_dir: str, state_template, step: Optional[int] = None
             ) -> Tuple[Any, Dict]:
     """Restore into the structure of ``state_template`` (arrays are
@@ -163,8 +261,11 @@ def restore(ckpt_dir: str, state_template, step: Optional[int] = None
     checkpoints into the stacked v3 layout
     (``_migrate_variable_ring_v2``), pre-residual decentralized
     checkpoints into the current DecentralizedState
-    (``_migrate_decentralized_residual``); every restored v2 arena
-    gets its static slot phase re-derived from the saved head
+    (``_migrate_decentralized_residual``), arena-form state saved in
+    the flat leaf layout into the tiled-image one
+    (``_migrate_leaf_layout``, and the publisher's snapshots in
+    ``extra`` are dropped: ``_migrate_publisher``); every restored v2
+    arena gets its static slot phase re-derived from the saved head
     counter."""
     step = step if step is not None else latest_step(ckpt_dir)
     if step is None:
@@ -177,16 +278,21 @@ def restore(ckpt_dir: str, state_template, step: Optional[int] = None
     paths, treedef = jax.tree_util.tree_flatten_with_path(state_template)
     keys = ["/".join(str(getattr(q, "key", getattr(q, "idx", q)))
                      for q in p) for p, _ in paths]
-    migrated = _migrate_ring_v1(data, keys)
-    migrated.update(_migrate_variable_ring_v2(data, keys, paths))
-    migrated.update(_migrate_decentralized_residual(data, keys, paths))
+    arrays = dict(data)
+    arrays.update(_migrate_ring_v1(data, keys))
+    arrays.update(_migrate_variable_ring_v2(data, keys, paths))
+    arrays.update(_migrate_decentralized_residual(data, keys, paths))
+    version = manifest.get("leaf_layout", 1)
+    arrays.update(_migrate_leaf_layout(arrays, version, keys, paths,
+                                       state_template))
     leaves = []
     for key, (p, leaf) in zip(keys, paths):
-        arr = migrated[key] if key in migrated else data[key]
+        arr = arrays[key]
         if tuple(arr.shape) != tuple(leaf.shape):
             raise ValueError(f"shape mismatch for {key}: "
                              f"{arr.shape} vs {leaf.shape}")
         leaves.append(arr.astype(leaf.dtype))
     restored = jax.tree_util.tree_unflatten(treedef, leaves)
     from repro.core.arena import sync_ring_phase
-    return sync_ring_phase(restored), manifest["extra"]
+    return (sync_ring_phase(restored),
+            _migrate_publisher(manifest["extra"], version))

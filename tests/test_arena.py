@@ -29,6 +29,13 @@ from repro.optim import make_arena_optimizer, make_optimizer
 
 # odd, row-misaligned leaf sizes exercise padding in every leaf
 SHAPES = {"a": (7,), "b": {"c": (3, 5), "d": (130,)}, "e": (257,)}
+# a tile-aligned model: every matrix whole (8,128) tiles, as qwen's are
+TILED = {"emb": (64, 256), "layers": {"w": (2, 16, 128), "b": (2, 128)},
+         "ln": (128,)}
+# whole-tile, padded and rank-1 leaves side by side
+MIXED = {"w": (24, 16, 256), "p": (6, 4, 200), "v": (40,)}
+MODELS = pytest.mark.parametrize("shapes", [SHAPES, TILED],
+                                 ids=["unaligned", "tiled"])
 
 
 def _rc(tau, compression, optimizer="dual_averaging"):
@@ -42,28 +49,29 @@ def _rc(tau, compression, optimizer="dual_averaging"):
                      optimizer=optimizer)
 
 
-def _params(key):
+def _params(key, shapes=SHAPES):
     leaves, treedef = jax.tree.flatten(
-        SHAPES, is_leaf=lambda x: isinstance(x, tuple))
+        shapes, is_leaf=lambda x: isinstance(x, tuple))
     ks = jax.random.split(key, len(leaves))
     return jax.tree.unflatten(
         treedef, [jax.random.normal(k, s, jnp.float32)
                   for k, s in zip(ks, leaves)])
 
 
-def _pod_grads(key, n_pods):
-    shapes, treedef = jax.tree.flatten(
-        SHAPES, is_leaf=lambda x: isinstance(x, tuple))
-    ks = jax.random.split(key, len(shapes))
+def _pod_grads(key, n_pods, shapes=SHAPES):
+    leaves, treedef = jax.tree.flatten(
+        shapes, is_leaf=lambda x: isinstance(x, tuple))
+    ks = jax.random.split(key, len(leaves))
     return jax.tree.unflatten(
         treedef, [jax.random.normal(k, (n_pods,) + s, jnp.float32)
-                  for k, s in zip(ks, shapes)])
+                  for k, s in zip(ks, leaves)])
 
 
+@MODELS
 @pytest.mark.parametrize("compression", ["none", "int8"])
 @pytest.mark.parametrize("n_pods", [1, 4])
 @pytest.mark.parametrize("tau", [0, 1, 4])
-def test_arena_bitexact_vs_pytree(tau, n_pods, compression):
+def test_arena_bitexact_vs_pytree(tau, n_pods, compression, shapes):
     """10 steps (tau=4 wraps the ring twice): params and the dual
     variable z must match the pytree reference bit for bit.
 
@@ -75,7 +83,7 @@ def test_arena_bitexact_vs_pytree(tau, n_pods, compression):
     accumulate). There we assert ULP-level agreement instead; see
     docs/arena.md."""
     rc = _rc(tau, compression)
-    params = _params(jax.random.PRNGKey(0))
+    params = _params(jax.random.PRNGKey(0), shapes)
     layout = arena.make_layout(params)
 
     opt_p = make_optimizer(rc)
@@ -113,7 +121,7 @@ def test_arena_bitexact_vs_pytree(tau, n_pods, compression):
             np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
     for t in range(10):
-        grads = _pod_grads(jax.random.PRNGKey(100 + t), n_pods)
+        grads = _pod_grads(jax.random.PRNGKey(100 + t), n_pods, shapes)
         counts = jnp.full((n_pods,), 3.0 + t)
         p_ref, opt_ref, buf = step_ref(p_ref, opt_ref, buf, grads, counts)
         p_arena, opt_ar, ar = step_arena(p_arena, opt_ar, ar, grads, counts)
@@ -201,6 +209,146 @@ def test_flatten_roundtrip_exact():
     back = arena.unflatten_tree(layout, g_flat)
     for a_leaf, b_leaf in zip(jax.tree.leaves(grads), jax.tree.leaves(back)):
         np.testing.assert_array_equal(np.asarray(a_leaf), np.asarray(b_leaf))
+
+
+def _tiled_image(x):
+    """A matrix leaf's (8,128)-tiled memory image, built tile by tile:
+    the pads zero, the tiles in row-major order, each as its 8 rows."""
+    *lead, m, n = x.shape
+    img = np.zeros(tuple(lead) + (-(-m // 8) * 8, -(-n // 128) * 128),
+                   np.float32)
+    img[..., :m, :n] = x
+    return np.concatenate([img[idx][8 * i:8 * i + 8, 128 * j:128 * j + 128]
+                           for idx in np.ndindex(*lead)
+                           for i in range(img.shape[-2] // 8)
+                           for j in range(img.shape[-1] // 128)])
+
+
+def test_tiled_image_roundtrip_exact():
+    """A leaf of rank >= 2 lies in the arena as its (8,128)-tiled image,
+    whole tiles ``(24, 16, 256)`` and a padded ``(6, 4, 200)`` alike; a
+    rank-1 leaf as its flat rows, padded to a whole tile; and the
+    round trip is bit-exact, pod-stacked too."""
+    params = _params(jax.random.PRNGKey(4), MIXED)
+    layout = arena.make_layout(params)
+    assert layout.row_counts == (96, 8, 768)       # p, v, w (tree order)
+    assert layout.row_offsets == (0, 96, 104) and layout.rows == 1024
+    mat = np.asarray(arena.flatten_tree(layout, params))
+    np.testing.assert_array_equal(mat[0:96],
+                                  _tiled_image(np.asarray(params["p"])))
+    np.testing.assert_array_equal(mat[96].reshape(-1)[:40],
+                                  np.asarray(params["v"]))
+    np.testing.assert_array_equal(mat[104:872],
+                                  _tiled_image(np.asarray(params["w"])))
+    assert not mat[96].reshape(-1)[40:].any() and not mat[97:104].any()
+    assert not mat[872:].any()
+    back = arena.unflatten_tree(layout, jnp.asarray(mat))
+    for a_leaf, b_leaf in zip(jax.tree.leaves(params),
+                              jax.tree.leaves(back)):
+        np.testing.assert_array_equal(np.asarray(a_leaf), np.asarray(b_leaf))
+    grads = _pod_grads(jax.random.PRNGKey(5), 3, MIXED)
+    g_flat = arena.flatten_tree(layout, grads, leading=1)
+    for a_leaf, b_leaf in zip(jax.tree.leaves(grads),
+                              jax.tree.leaves(arena.unflatten_tree(
+                                  layout, g_flat))):
+        np.testing.assert_array_equal(np.asarray(a_leaf), np.asarray(b_leaf))
+
+
+def test_every_scatter_writes_the_leaf_strip():
+    """flatten_tree, scatter_fed, the v1 switch scatter, the stacked
+    slot scatter and the v2 slot push all write ``_leaf_strip``'s rows
+    at each leaf's offset."""
+    n_pods, tau = 2, 2
+    params = _params(jax.random.PRNGKey(6), MIXED)
+    layout = arena.make_layout(params)
+    grads = _pod_grads(jax.random.PRNGKey(7), n_pods, MIXED)
+    strips = [np.asarray(arena._leaf_strip(layout, i, leaf, 1))
+              for i, leaf in enumerate(jax.tree.leaves(grads))]
+    residual = jax.random.normal(jax.random.PRNGKey(8),
+                                 (n_pods, layout.rows, 128))
+
+    def check(out, add=None):
+        out = np.asarray(out)
+        for strip, ofs, rc in zip(strips, layout.row_offsets,
+                                  layout.row_counts):
+            want = strip if add is None else (
+                strip + np.asarray(add)[:, ofs:ofs + rc])
+            np.testing.assert_array_equal(out[:, ofs:ofs + rc], want)
+
+    zeros = jnp.zeros((n_pods, layout.rows, 128))
+    check(arena.flatten_tree(layout, grads, leading=1))
+    check(arena.flatten_tree(layout, grads, leading=1, out=zeros + 1.0))
+    check(arena.scatter_fed(layout, grads, residual, out=zeros), residual)
+    ring = jnp.zeros((tau, n_pods, layout.rows, 128))
+    check(arena._scatter_slot(layout, ring, grads, jnp.int32(1))[1])
+    check(arena._scatter(layout, grads, 1, ring, slot=0)[0])
+    ar = arena.init_arena(layout, tau, n_pods)
+    _, _, pushed = arena.push_pop(layout, ar, grads, jnp.ones((n_pods,)))
+    check(pushed.ring[ar.phase])
+
+
+@pytest.mark.parametrize("shapes", [SHAPES, TILED, MIXED],
+                         ids=["unaligned", "tiled", "mixed"])
+def test_row_to_leaf_after_tile_alignment(shapes):
+    """Every strip starts on an 8-row boundary and fills whole tiles,
+    strips lie back to back, each row maps to its leaf and the tail
+    pad to the sentinel ``n_leaves``; per-row int8 scales are then one
+    value per leaf (the per-tensor scale), 1 on the tail."""
+    layout = arena.make_layout(_params(jax.random.PRNGKey(0), shapes))
+    end = 0
+    for i, (ofs, rc) in enumerate(zip(layout.row_offsets,
+                                      layout.row_counts)):
+        assert ofs == end and ofs % 8 == 0 and rc % 8 == 0
+        assert (layout.row_to_leaf[ofs:ofs + rc] == i).all()
+        end = ofs + rc
+    assert (layout.row_to_leaf[end:] == layout.n_leaves).all()
+    assert layout.rows % arena.BLOCK_ROWS == 0 and layout.rows >= end
+    grads = _pod_grads(jax.random.PRNGKey(9), 2, shapes)
+    scales = np.asarray(arena.row_scales(
+        layout, arena.flatten_tree(layout, grads, leading=1)))
+    for i, g in enumerate(jax.tree.leaves(grads)):
+        ofs, rc = layout.row_offsets[i], layout.row_counts[i]
+        amax = np.abs(np.asarray(g)).reshape(2, -1).max(axis=1)
+        want = np.maximum(amax, 1e-12).astype(np.float32) / np.float32(127)
+        np.testing.assert_array_equal(scales[:, ofs:ofs + rc],
+                                      np.repeat(want[:, None], rc, 1))
+    assert (scales[:, end:] == 1.0).all()
+
+
+# (configuration, parameters, of them not in whole-tile images, arena
+# rows). qwen: the 1,024-wide final norm. zamba2: its rank-1 norms
+# (3 x 2,560), the rank-2 leaves of 6 rows (6 x (2,560 + 3 x 80 + 5,248
+# + 5,120)), conv (6, 4, 5,248) and mamba.w_in (6, 2,560, 10,448).
+BENCH_LAYOUTS = [("qwen1.5-0.5b", 464118784, 1024, 3625984),
+                 ("zamba2-2.7b-p1", 508034720,
+                  3 * 2560 + 6 * (2560 + 3 * 80 + 5248 + 5120)
+                  + 6 * 4 * 5248 + 6 * 2560 * 10448, 3976192)]
+
+
+@pytest.mark.parametrize("name,total,padded,rows", BENCH_LAYOUTS,
+                         ids=[c[0] for c in BENCH_LAYOUTS])
+def test_tiled_share_of_bench_configs(name, total, padded, rows):
+    """``tiled_share`` is the share of parameters in whole-tile images
+    (rank >= 2, last two dims multiples of 8 and 128): every qwen leaf
+    but the final norm, so its rows are those of the flat layout; for
+    zamba2 68.4%, since ``mamba.w_in`` (6, 2560, 10448) pads to 10,496
+    lanes and the (6, n) leaves to 8 rows."""
+    import json
+    from repro.configs.base import SSMConfig
+    from repro.models import build_model
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(repo, "bench", "configs", f"{name}.json")) as f:
+        cfg = json.load(f)
+    kw = {k: v for k, v in cfg.items()
+          if k in {fl.name for fl in dataclasses.fields(ModelConfig)}}
+    if "ssm" in kw:
+        kw["ssm"] = SSMConfig(**kw["ssm"])
+    model = build_model(ModelConfig(**kw))
+    layout = arena.make_layout(jax.eval_shape(
+        lambda k: model.init(k)[0], jax.random.PRNGKey(0)))
+    assert sum(layout.sizes) == total
+    assert layout.tiled_share == (total - padded) / total
+    assert layout.rows == rows
 
 
 def test_head_wraparound_semantics():
@@ -300,16 +448,17 @@ def _stack(x):
         else np.asarray(x)
 
 
+@MODELS
 @pytest.mark.parametrize("compression", ["none", "int8"])
 @pytest.mark.parametrize("n_pods", [1, 4])
 @pytest.mark.parametrize("tau", [1, 2, 4])
-def test_ring_v2_matches_v1(tau, n_pods, compression):
+def test_ring_v2_matches_v1(tau, n_pods, compression, shapes):
     """Ring layout v2 (per-slot buffers, static phase) is bit-exact vs
     the stacked v1 layout across tau x pods x compression: same popped
     sums, same counts, and — through the v1 view, which undoes the
     phase permutation and drops the dead spare slot — the same ring
     contents, for 10 steps (tau=4 wraps the schedule twice)."""
-    params = _params(jax.random.PRNGKey(0))
+    params = _params(jax.random.PRNGKey(0), shapes)
     layout = arena.make_layout(params)
     ar1 = arena.init_arena(layout, tau, n_pods, compression,
                            ring_version=1)
@@ -323,7 +472,7 @@ def test_ring_v2_matches_v1(tau, n_pods, compression):
     step2 = jax.jit(functools.partial(arena.push_pop, layout,
                                       compression=compression))
     for t in range(10):
-        grads = _pod_grads(jax.random.PRNGKey(200 + t), n_pods)
+        grads = _pod_grads(jax.random.PRNGKey(200 + t), n_pods, shapes)
         counts = jnp.full((n_pods,), 2.0 + t)
         gs1, c1, ar1 = step1(ar1, grads, counts)
         gs2, c2, ar2 = step2(ar2, grads, counts)
@@ -343,9 +492,11 @@ def test_ring_v2_matches_v1(tau, n_pods, compression):
                                       np.asarray(view.counts))
 
 
+@MODELS
 @pytest.mark.parametrize("compression", ["none", "int8"])
 @pytest.mark.parametrize("tau", [1, 2, 4])
-def test_variable_ring_constant_delay_matches_static(tau, compression):
+def test_variable_ring_constant_delay_matches_static(tau, compression,
+                                                     shapes):
     """The delay-tolerant ring fed the CONSTANT sequence tau_t = tau is
     the static-phase v2 path: same popped sums, counts, ring slots,
     scales and residual — value-identical per step across three full
@@ -353,7 +504,7 @@ def test_variable_ring_constant_delay_matches_static(tau, compression):
     and the push schedule lands in the same slot indices). This is the
     degeneracy the fixed delay process rides."""
     n_pods = 2
-    params = _params(jax.random.PRNGKey(0))
+    params = _params(jax.random.PRNGKey(0), shapes)
     layout = arena.make_layout(params)
     ar_s = arena.init_arena(layout, tau, n_pods, compression)
     ar_v = arena.init_arena(layout, tau, n_pods, compression,
@@ -363,7 +514,7 @@ def test_variable_ring_constant_delay_matches_static(tau, compression):
     step_v = jax.jit(functools.partial(arena.push_pop_variable, layout,
                                        compression=compression))
     for t in range(3 * (tau + 1) + 2):
-        grads = _pod_grads(jax.random.PRNGKey(400 + t), n_pods)
+        grads = _pod_grads(jax.random.PRNGKey(400 + t), n_pods, shapes)
         counts = jnp.full((n_pods,), 2.0 + t)
         gs_s, c_s, ar_s = step_s(ar_s, grads, counts)
         gs_v, c_v, tau_obs, ar_v = step_v(ar_v, grads, counts,
@@ -568,6 +719,115 @@ def test_checkpoint_v1_ring_migration(tmp_path):
             np.testing.assert_array_equal(np.asarray(a_leaf),
                                           np.asarray(b_leaf))
         np.testing.assert_array_equal(np.asarray(o.z), np.asarray(ro.z))
+
+
+def _flat_order(layout, arr):
+    """An arena-form array in the flat leaf layout that checkpoints
+    without a ``leaf_layout`` entry hold: each leaf C-order flattened
+    into 128-lane rows, back to back, rows padded to the kernel block."""
+    lead = arr.shape[:-2]
+    rows = []
+    for leaf in jax.tree.leaves(arena.unflatten_tree(
+            layout, jnp.asarray(arr), cast=False)):
+        x = np.asarray(leaf).reshape(lead + (-1,))
+        x = np.pad(x, [(0, 0)] * len(lead) + [(0, -x.shape[-1] % 128)])
+        rows.append(x.reshape(lead + (-1, 128)))
+    flat = np.concatenate(rows, axis=-2)
+    return np.pad(flat, [(0, 0)] * len(lead)
+                  + [(0, -flat.shape[-2] % arena.BLOCK_ROWS), (0, 0)])
+
+
+def _flat_order_scales(layout, scales):
+    """Per-row int8 scales (one value per leaf) in the flat layout."""
+    scales = np.asarray(scales)
+    parts = [np.repeat(scales[..., ofs:ofs + 1], -(-size // 128), axis=-1)
+             for ofs, size in zip(layout.row_offsets, layout.sizes)]
+    flat = np.concatenate(parts, axis=-1)
+    return np.pad(flat, [(0, 0)] * (scales.ndim - 1)
+                  + [(0, -flat.shape[-1] % arena.BLOCK_ROWS)],
+                  constant_values=1.0)
+
+
+def test_checkpoint_flat_leaf_order_migration(tmp_path):
+    """A checkpoint saved before tiled-image strips (no ``leaf_layout``
+    in its manifest; z, ring slots, scales, residual and staging in the
+    flat C-order leaf layout) restores into the tiled layout and the
+    run continues bit for bit. With whole-tile leaves the arena keeps
+    its row count, so nothing but the migration would notice. The
+    publisher's int8 snapshots in ``extra`` are dropped from such a
+    checkpoint (pops miss) and kept from a current one."""
+    import json
+    from repro.configs.base import ServeConfig
+    from repro.serve.publisher import WeightPublisher
+    from repro.train import checkpoint as ckpt
+    compression, tau, n_pods = "int8", 2, 2
+    rc = _rc(tau, compression)
+    params = _params(jax.random.PRNGKey(3), MIXED)
+    layout = arena.make_layout(params)
+    opt_a = make_arena_optimizer(rc, layout)
+
+    @jax.jit
+    def step(p, o, a, grads, counts):
+        p, o, a, _, _ = ambdg.arena_master_update(
+            layout, opt_a, p, o, a, grads, counts, compression)
+        return p, o, a
+
+    def batches(t):
+        return (_pod_grads(jax.random.PRNGKey(500 + t), n_pods, MIXED),
+                jnp.full((n_pods,), 3.0))
+
+    p, o = params, opt_a.init()
+    ar = arena.init_arena(layout, tau, n_pods, compression)
+    for t in range(4):
+        p, o, ar = step(p, o, ar, *batches(t))
+    flat_z = _flat_order(layout, o.z)
+    assert flat_z.shape == o.z.shape and not np.array_equal(flat_z, o.z)
+    old = {"params": p, "opt": o._replace(z=flat_z),
+           "arena": ar._replace(
+               ring=tuple(_flat_order(layout, r) for r in ar.ring),
+               scales=tuple(_flat_order_scales(layout, sc)
+                            for sc in ar.scales),
+               residual=_flat_order(layout, ar.residual),
+               staging=_flat_order(layout, ar.staging))}
+    sc = ServeConfig(publish_period=1, staleness_bound=2)
+    pub = WeightPublisher(layout, sc)
+    pub.publish(p, 3)
+    # the JSON manifest holds the publisher's arrays as lists
+    pub_state = {k: v.tolist() if isinstance(v, np.ndarray) else v
+                 for k, v in pub.state_dict().items()}
+    path = ckpt.save(str(tmp_path), 3, old,
+                     extra={"step": 3, "publisher": pub_state})
+    with open(os.path.join(path, "manifest.json")) as f:
+        manifest = json.load(f)
+    assert manifest.pop("leaf_layout") == arena.LEAF_LAYOUT
+    with open(os.path.join(path, "manifest.json"), "w") as f:
+        json.dump(manifest, f)
+
+    template = {"params": p, "opt": opt_a.init(),
+                "arena": arena.init_arena(layout, tau, n_pods, compression)}
+    restored, extra = ckpt.restore(str(tmp_path), template)
+    served = WeightPublisher(layout, sc)
+    served.load_state_dict(extra["publisher"])
+    assert served.pop(3) == (None, None)
+    ckpt.save(str(tmp_path), 4, {"params": p, "opt": o, "arena": ar},
+              extra={"step": 4, "publisher": pub_state})
+    served.load_state_dict(ckpt.restore(str(tmp_path), template)[1][
+        "publisher"])
+    for a_leaf, b_leaf in zip(jax.tree.leaves(served.pop(3)),
+                              jax.tree.leaves(pub.pop(3))):
+        np.testing.assert_array_equal(np.asarray(a_leaf), np.asarray(b_leaf))
+    for a_leaf, b_leaf in zip(jax.tree.leaves({"params": p, "opt": o,
+                                               "arena": ar}),
+                              jax.tree.leaves(restored)):
+        np.testing.assert_array_equal(np.asarray(a_leaf), np.asarray(b_leaf))
+    rp, ro, r_ar = restored["params"], restored["opt"], restored["arena"]
+    for t in range(4, 8):
+        p, o, ar = step(p, o, ar, *batches(t))
+        rp, ro, r_ar = step(rp, ro, r_ar, *batches(t))
+        for a_leaf, b_leaf in zip(jax.tree.leaves((p, o, ar)),
+                                  jax.tree.leaves((rp, ro, r_ar))):
+            np.testing.assert_array_equal(np.asarray(a_leaf),
+                                          np.asarray(b_leaf))
 
 
 _SHARD_MAP_SCRIPT = textwrap.dedent("""

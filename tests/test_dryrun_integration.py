@@ -24,6 +24,16 @@ def _run(arch, shape, *extra):
     return json.loads(line)
 
 
+def _tiled_share(arch):
+    import jax
+    import repro.configs as C
+    from repro.core import arena
+    from repro.models import build_model
+    model = build_model(C.get_config(arch))
+    return arena.make_layout(jax.eval_shape(
+        lambda k: model.init(k)[0], jax.random.PRNGKey(0))).tiled_share
+
+
 @pytest.mark.slow
 @pytest.mark.parametrize("arch,shape", [
     ("qwen1.5-0.5b", "train_4k"),      # AMB-DG train step
@@ -48,6 +58,8 @@ def test_dryrun_cell_variable_delay():
     assert r["flops"] > 0
     assert r["master"]["delay_process"] == "heavy_tail"
     assert r["master"]["tau_max"] == 3
+    # every qwen leaf but the final norm is a whole-tile image
+    assert r["master"]["tiled_share"] == _tiled_share("qwen1.5-0.5b")
     mem = r["memory"]
     assert (mem["argument_bytes"] + mem["temp_bytes"]) < 16e9
     assert r["collectives"]["count"] > 0

@@ -14,6 +14,7 @@
 """
 import json
 import os
+import re
 import subprocess
 import sys
 
@@ -157,6 +158,82 @@ class TestResolveCellRcTauMax:
         out = dryrun.resolve_cell_rc("qwen1.5-0.5b", "train_4k", False,
                                      rc=rc)
         assert out.delay is rc.delay
+
+
+# ---------------------------------------------------------------------------
+# invariant A: a copy of the ring is reported, a staging-fill copy is not
+# ---------------------------------------------------------------------------
+@pytest.fixture(scope="module")
+def matrix(dryrun):
+    """``launch.matrix``, once ``dryrun`` (which it imports) is loaded
+    without leaking its flag append."""
+    import repro.launch.matrix as m
+    return m
+
+
+def _planted_copies(fill: bool, tag: bool) -> str:
+    """Compiled HLO of a write into an f32 buffer that stays live beside
+    its update, so XLA copies the buffer: the ring-slot scatter
+    (``arena._scatter`` into slot 1 of a stacked (2, 1, rows, 128)
+    ring) or, with ``fill``, the staging fill (``flatten_tree`` into a
+    (1, rows, 128) buffer, the shape of a ring slot). With
+    ``tag`` each copy gets the op metadata of the scatter's own
+    update-slice (op_name, and a source line inside ``_scatter``), as
+    GSPMD's layout copies carry it."""
+    import inspect
+    import jax
+    import jax.numpy as jnp
+    from repro.core import arena
+    tree = {"a": jnp.zeros((1, 16, 256)), "b": jnp.zeros((1, 200))}
+    layout = arena.make_layout(jax.tree.map(lambda x: x[0], tree))
+
+    def plant(buf, tree):
+        with jax.named_scope("master.stage"):
+            if fill:
+                return arena.flatten_tree(layout, tree, 1, out=buf), buf
+            return arena._scatter(layout, tree, 1, buf, slot=1), buf
+    buf = jnp.zeros((1, layout.rows, 128) if fill
+                    else (2, 1, layout.rows, 128))
+    text = jax.jit(plant).lower(buf, tree).compile().as_text()
+    if tag:
+        op = re.search(r'op_name="([^"]*dynamic_update_slice)"',
+                       text).group(1)
+        line = inspect.getsourcelines(arena._scatter)[1] + 1
+        meta = (f'metadata={{op_name="{op}" source_file="src/repro/core/'
+                f'arena.py" source_line={line}}}')
+        text = re.sub(r"( copy\(%[\w.]+\))$", rf"\1, {meta}", text,
+                      flags=re.M)
+    return text
+
+
+@pytest.mark.parametrize("fill,tag,reported", [
+    (False, False, True),     # the ring's own copy
+    (False, True, True),      # ... with the slot scatter's metadata
+    (True, True, False),      # a staging-fill copy: attributed
+], ids=["slot-scatter", "slot-scatter-tagged", "staging-fill"])
+def test_ring_copy_in_the_slot_scatter_is_reported(matrix, fill, tag,
+                                                   reported):
+    """Invariant A on a planted copy. A copy of the f32 ring made by the
+    ring-slot scatter is a violation even though the scatter shares
+    ``arena._scatter`` with the staging fill; only copies under the
+    fill's named scope (``arena.FILL_SCOPE``) are attributed to it."""
+    import repro.configs as C
+    from repro.configs.base import MeshConfig, RunConfig
+    cell = matrix.MatrixCell("planted", "qwen1.5-0.5b", "1x1",
+                             delay_process="heavy_tail", tau_max=1)
+    rc = RunConfig(model=C.get_smoke_config("qwen1.5-0.5b"),
+                   shape=matrix.MATRIX_TRAIN,
+                   mesh=MeshConfig(n_pods=1, data=1, model=1))
+    text = _planted_copies(fill, tag)
+    out = matrix.check_ring_copies(cell, rc, 256, text, None)
+    shape = "f32[1,256,128]" if fill else "f32[2,1,256,128]"
+    assert re.search(rf"= {re.escape(shape)}\S* copy\(", text)
+    if reported:
+        assert not out["ok"]
+        assert {v["key"] for v in out["violations"]} == {shape}
+    else:
+        assert out["ok"] and out["violations"] == []
+        assert out["attributed_copies"]["staging_fill"]["count"] >= 1
 
 
 # ---------------------------------------------------------------------------

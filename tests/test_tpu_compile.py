@@ -135,6 +135,118 @@ def test_int8_block_rows_follow_the_chip(one_chip):
                       ((1, r, 128), jnp.float32), scales)
 
 
+# instructions that move no data
+_NO_DATA = ("bitcast", "get-tuple-element", "parameter", "constant", "tuple")
+_DTYPE_BYTES = {"f32": 4, "s32": 4, "u32": 4, "bf16": 2, "s8": 1, "pred": 1}
+
+
+def _bytes(shape_text: str) -> int:
+    """Bytes of every array in an HLO result shape (tuples summed)."""
+    total = 0
+    for dt, dims in re.findall(r"\b(f32|s32|u32|bf16|s8|pred)\[([\d,]*)\]",
+                               shape_text):
+        n = 1
+        for d in filter(None, dims.split(",")):
+            n *= int(d)
+        total += _DTYPE_BYTES[dt] * n
+    return total
+
+
+_INSTR = re.compile(
+    r"\s*(?:ROOT )?%(\S+) = (.*?) ([a-z][\w-]*)\(([^)]*)\)(.*)")
+
+
+def _computations(text: str):
+    """Computation name ("ENTRY" for the entry) -> its instructions, each
+    (name, result shape, opcode, operands, rest of the line)."""
+    comps, body = {}, None
+    for line in text.splitlines():
+        head = re.match(r"(ENTRY )?%(\S+) .*\{$", line)
+        if head:
+            body = comps.setdefault(
+                "ENTRY" if head.group(1) else head.group(2), [])
+        elif body is not None and (m := _INSTR.match(line)):
+            body.append(m.groups())
+    return comps
+
+
+def _read_bytes(comps, shapes, instr) -> int:
+    """Bytes one instruction reads: a slice reads what it writes; a
+    fusion reads what its slices take of an operand, or all of an
+    operand that has another user; any other op reads its operands."""
+    _, shape, code, args, rest = instr
+    operands = re.findall(r"%([\w.\-]+)", args)
+    if code == "slice":
+        return _bytes(shape)
+    if code != "fusion":
+        return sum(_bytes(shapes[o]) for o in operands)
+    body = comps[re.search(r"calls=%([\w.\-]+)", rest).group(1)]
+    total = 0
+    for name, _, op, index, _ in body:
+        if op != "parameter":
+            continue
+        users = [u for u in body
+                 if f"%{name}" in re.findall(r"%[\w.\-]+", u[3])]
+        if users and all(u[2] == "slice" for u in users):
+            total += sum(_bytes(u[1]) for u in users)
+        else:
+            total += _bytes(shapes[operands[int(index)]])
+    return total
+
+
+def test_master_update_moves_the_arena_once(one_chip, monkeypatch):
+    """The arena master update at qwen1.5-0.5b widths, compiled for the
+    v5e (tau=1, one pod, the dual-update kernel): the unflatten of
+    ``w`` into the parameter tree is one row slice of each leaf's tiled
+    image, so no op holds the whole arena as one 1-D array, and the
+    ops under ``master.update`` other than the kernel read and write
+    the arena's bytes once each."""
+    import repro.kernels
+    from repro.configs.base import RunConfig, TRAIN_4K
+    from repro.core import ambdg
+    from repro.optim import make_arena_optimizer
+    monkeypatch.setattr(repro.kernels, "on_tpu", lambda: True)
+    cfg = C.get_config("qwen1.5-0.5b")
+    model = build_model(cfg)
+    shapes = jax.eval_shape(lambda k: model.init(k)[0],
+                            jax.random.PRNGKey(0))
+    layout = arena.make_layout(shapes)
+    opt = make_arena_optimizer(RunConfig(model=cfg, shape=TRAIN_4K), layout)
+
+    def master(state, grads, counts):
+        p, o, a = state
+        p, o, a, _, _ = ambdg.arena_master_update(layout, opt, p, o, a,
+                                                  grads, counts)
+        return p, o, a
+
+    def on_chip(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one_chip), tree)
+
+    state = jax.eval_shape(lambda: (shapes, opt.init(),
+                                    arena.init_arena(layout, 1, 1)))
+    grads = jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+        (1,) + x.shape, x.dtype, sharding=one_chip), shapes)
+    counts = jax.ShapeDtypeStruct((1,), jnp.float32, sharding=one_chip)
+    text = jax.jit(master, donate_argnums=(0,)).lower(
+        on_chip(state), grads, counts).compile().as_text()
+    arena_bytes = layout.rows * 128 * 4
+    assert not re.search(rf"\[{layout.rows * 128}\]", text)
+    comps = _computations(text)
+    results = {instr[0]: instr[1] for instr in comps["ENTRY"]}
+    moved, kernel = 0, 0
+    for instr in comps["ENTRY"]:
+        name, shape, op, _, rest = instr
+        if op in _NO_DATA or "master.update" not in rest:
+            continue
+        if name.startswith("dual_update"):
+            kernel += 1
+            continue
+        moved += _bytes(shape) + _read_bytes(comps, results, instr)
+    assert kernel == 1
+    assert moved <= 2 * arena_bytes, (moved, arena_bytes)
+
+
 def _flash_attention_grads(q, k, v):
     def loss(q, k, v):
         return jnp.sum(flash_attention_train(q, k, v).astype(jnp.float32))
