@@ -33,6 +33,15 @@ vocabulary cut:
       every step's consensus state must equal the dense gossip fold
       applied to the step's own messages, bit for bit.
 
+MoE routing (``--moe-rows SEED``). Not a smoke check but a probe of
+the benchmark's ``moonlight-ep8-moe-8k`` cell: its cell is driven
+through the training loop as ``bench/run.py`` drives it, from the
+benchmark's seeded weights, for 12 steps. Each step's held-expert rows
+(the step's ``moe_held_rows``: the first step's with the seeded
+weights, every later one's with the parameters the step before left)
+are printed against the balanced count, tokens x top_k x held / routed
+x MoE layers. One JSON line a step, then the shares by step.
+
 It measures nothing: compile time, step rate and memory are the
 benchmark's (``bench/run.py``). The last line is one JSON object, ``{"ok": true, "device": {...}}``; any failed check
 exits non-zero without it. Exits non-zero, printing no result, when
@@ -426,11 +435,55 @@ def run_gossip(cfg, devices, checks: Checks) -> None:
     checks.check("b_z_spread_over_devices", spread == n)
 
 
+# ---------------------------------------------------------------------------
+# MoE routing probe: held rows of the benchmark's moonlight cell
+# ---------------------------------------------------------------------------
+MOE_CELL = "moonlight-ep8-moe-8k"
+
+
+class _ProbeDone(Exception):
+    pass
+
+
+def run_moe_rows(seed: int, steps: int) -> dict:
+    """Each step's ``moe_held_rows`` (the program's own count, summed
+    over the MoE layers and microbatches) as a share of the balanced
+    count, tokens x top_k x held / routed x MoE layers."""
+    sys.path.insert(0, os.path.join(HERE, "bench"))
+    from harness import drive, spec
+    cell = spec.load_cell(MOE_CELL)
+    model, rc, loop, _ = drive.build(cell, seed)
+    cfg, t = rc.model, cell.traffic
+    balanced = (t["n_workers"] * t["samples_per_worker"] * t["seq_len"]
+                * cfg.moe.top_k * cfg.moe.n_experts / cfg.moe.router_width
+                * (cfg.n_layers - cfg.first_k_dense))
+    shares = []
+
+    def log_fn(m):
+        shares.append(m["moe_held_rows"] / balanced)
+        log(json.dumps({"step": int(m["step"]), "loss": m["loss"],
+                        "held_rows": m["moe_held_rows"],
+                        "share": shares[-1]}))
+        if len(shares) == steps:
+            raise _ProbeDone
+
+    loop.log_every = 1
+    try:
+        train(model, rc, loop, log_fn=log_fn)
+    except _ProbeDone:
+        pass
+    return {"cell": MOE_CELL, "seed": seed, "balanced_rows": balanced,
+            "share_by_step": shares}
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--four-chips", action="store_true",
                     help="run only the 4-chip pod-exchange and gossip "
                          "phases")
+    ap.add_argument("--moe-rows", type=int, default=None, metavar="SEED",
+                    help="probe the held-expert rows of the moonlight "
+                         "cell's first 12 steps instead")
     args = ap.parse_args(argv)
 
     backend = jax.default_backend()
@@ -440,6 +493,9 @@ def main(argv=None) -> int:
               file=sys.stderr)
         return 2
     log(f"compile cache: {enable_compile_cache()}")
+    if args.moe_rows is not None:
+        print(json.dumps(run_moe_rows(args.moe_rows, 12)))
+        return 0
 
     devices = jax.devices()
     want = 4 if args.four_chips else 1

@@ -16,7 +16,7 @@ from repro.configs.base import (  # noqa: F401
 from repro.configs import (
     mixtral_8x7b, mixtral_8x22b, xlstm_125m, paligemma_3b, qwen1_5_0_5b,
     yi_6b, chatglm3_6b, qwen3_1_7b, zamba2_2_7b, seamless_m4t_large_v2,
-    amb_linreg, amb_cnn,
+    moonlight_16b_a3b, amb_linreg, amb_cnn,
 )
 
 _MODULES = {
@@ -30,6 +30,7 @@ _MODULES = {
     "qwen3-1.7b": qwen3_1_7b,
     "zamba2-2.7b": zamba2_2_7b,
     "seamless-m4t-large-v2": seamless_m4t_large_v2,
+    "moonlight-16b-a3b": moonlight_16b_a3b,
     # paper's own experiments
     "amb-linreg": amb_linreg,
     "amb-cnn": amb_cnn,
@@ -56,7 +57,9 @@ def applicable_shapes(arch: str) -> list:
     cfg = get_config(arch)
     if cfg.family not in LM_FAMILIES:
         return []
-    out = [TRAIN_4K, PREFILL_32K, DECODE_32K]
+    out = [TRAIN_4K, PREFILL_32K]
+    if cfg.has_decoder:
+        out.append(DECODE_32K)
     if cfg.is_subquadratic:
         out.append(LONG_500K)
     return out

@@ -38,6 +38,24 @@ class MoEConfig:
     # Switch "group" trick): keeps dispatch tensors O(group^2) instead
     # of O(T^2) and keeps routing local to the batch shard.
     dispatch_group: int = 4096
+    # --- the held-expert dropless layer (moe_impl "dropless") ---
+    # experts the router scores (its published width); 0: n_experts,
+    # every expert held here. The layer holds n_experts of them,
+    # first_expert .. first_expert + n_experts - 1, and computes only
+    # their part of the result (an expert-parallel share).
+    n_routed: int = 0
+    first_expert: int = 0
+    d_expert: int = 0               # expert width; 0: ModelConfig.d_ff
+    # shared experts every token passes through, run as one gated MLP
+    # of width n_shared * d_expert
+    n_shared: int = 0
+    # the dropless layer's chosen weights (renormalised sigmoid scores,
+    # DeepSeek-V3) are multiplied by this
+    routed_scale: float = 1.0
+
+    @property
+    def router_width(self) -> int:
+        return self.n_routed or self.n_experts
 
 
 @dataclass(frozen=True)
@@ -89,6 +107,16 @@ class ModelConfig:
     ssm: Optional[SSMConfig] = None
     xlstm: Optional[XLSTMConfig] = None
     shared_attn_every: int = 0              # zamba2: shared attn block period
+    first_k_dense: int = 0                  # moe: leading dense-MLP layers
+    # multi-head latent attention (DeepSeek-V2 MLA, no q-LoRA) when
+    # kv_lora_rank > 0: keys and values come from a kv_lora_rank-wide
+    # latent; each q/k head is qk_nope_head_dim + qk_rope_head_dim
+    # wide, the rope part of the key shared by all heads; values are
+    # v_head_dim wide
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
     n_encoder_layers: int = 0               # encdec only
     cross_attention: bool = False           # encdec only
     frontend: Optional[str] = None          # "siglip_stub" | "w2vbert_stub"
@@ -98,7 +126,7 @@ class ModelConfig:
     image_size: int = 0
     n_classes: int = 0
     # --- implementation knobs (perf levers, not architecture) ---
-    moe_impl: str = "einsum"                # "einsum" | "gather" (see models/moe.py)
+    moe_impl: str = "einsum"                # "einsum" | "gather" | "dropless" (see models/moe.py)
     attn_impl: str = "xla"                  # "xla" | "flash" (Pallas, TPU only)
     # fully unroll layer/chunk scans (roofline measurement mode: makes
     # cost_analysis() and the HLO collective census count every
@@ -117,6 +145,26 @@ class ModelConfig:
     # --- numerics ---
     param_dtype: str = "float32"
     compute_dtype: str = "bfloat16"
+
+    def __post_init__(self):
+        # a configuration file gives the MoE group as a plain dict
+        if isinstance(self.moe, dict):
+            object.__setattr__(self, "moe", MoEConfig(**self.moe))
+        m = self.moe
+        if m is not None and self.moe_impl != "dropless" and (
+                m.router_width != m.n_experts or m.n_shared):
+            raise ValueError("a held expert share and shared experts need "
+                             "moe_impl 'dropless'")
+
+    @property
+    def is_mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def has_decoder(self) -> bool:
+        """Can the model decode token by token? A latent-attention
+        model has no latent cache here: training only."""
+        return not self.is_mla
 
     @property
     def padded_vocab_size(self) -> int:
@@ -159,6 +207,12 @@ def _count_params(cfg: ModelConfig, active_only: bool = False) -> int:
     total = emb + out_head + d  # final norm
 
     def attn_params() -> int:
+        if cfg.is_mla:
+            qk = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
+            r = cfg.kv_lora_rank
+            return (d * nq * qk + d * (r + cfg.qk_rope_head_dim) + r
+                    + r * nq * (cfg.qk_nope_head_dim + cfg.v_head_dim)
+                    + nq * cfg.v_head_dim * d)
         p = d * (nq * hd) + 2 * d * (nkv * hd) + (nq * hd) * d
         if cfg.qkv_bias:
             p += (nq + 2 * nkv) * hd
@@ -169,10 +223,15 @@ def _count_params(cfg: ModelConfig, active_only: bool = False) -> int:
 
     def moe_ffn() -> int:
         m = cfg.moe
-        per = 3 * d * cfg.d_ff
-        router = d * m.n_experts
-        n_used = m.top_k if active_only else m.n_experts
-        return router + n_used * per
+        per = 3 * d * (m.d_expert or cfg.d_ff)
+        router = d * m.router_width
+        if cfg.moe_impl == "dropless":       # the correction bias
+            router += m.router_width
+        # active: a token's top_k experts, of which a held share holds
+        # n_experts / router_width on average
+        n_used = (m.top_k * m.n_experts / m.router_width if active_only
+                  else m.n_experts)
+        return router + int(n_used * per) + m.n_shared * per
 
     def mamba2_params() -> int:
         s = cfg.ssm
@@ -191,7 +250,9 @@ def _count_params(cfg: ModelConfig, active_only: bool = False) -> int:
             total += cfg.n_frontend_tokens  # stub: positional table only
     elif cfg.family == MOE:
         per_layer = attn_params() + moe_ffn() + 2 * d
-        total += cfg.n_layers * per_layer
+        dense_layer = attn_params() + dense_ffn(cfg.d_ff) + 2 * d
+        total += ((cfg.n_layers - cfg.first_k_dense) * per_layer
+                  + cfg.first_k_dense * dense_layer)
     elif cfg.family == SSM:  # xlstm
         x = cfg.xlstm
         d_in_m = int(x.proj_factor_mlstm * d)

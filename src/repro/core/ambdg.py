@@ -202,7 +202,8 @@ def build_step_fns(model: Model, rc: RunConfig):
 
     def _pod_chunk_grads(params, batch):
         """Returns pod-stacked (grads (n_pods, ...), counts (n_pods,),
-        loss sums (n_pods,)). No cross-pod reduction."""
+        {"loss_sum" and the model's counters, each (n_pods,)}). No
+        cross-pod reduction."""
         def one_chunk(chunk):
             n_active = chunk.get("n_active", jnp.int32(n_mb))
             chunk = {k: v for k, v in chunk.items() if k != "n_active"}
@@ -214,15 +215,14 @@ def build_step_fns(model: Model, rc: RunConfig):
         if n_pods == 1:
             g, c, m = one_chunk(batch)
             stack = lambda x: x[None]
-            return (jax.tree.map(stack, g), c[None], m["loss_sum"][None])
+            return jax.tree.map(stack, g), c[None], jax.tree.map(stack, m)
 
         # reshape (B, ...) -> (n_pods, B/n_pods, ...); dim 0 is sharded
         # over the 'pod' mesh axis so each chunk computes on its own pod
         chunked = jax.tree.map(
             lambda x: x.reshape((n_pods, x.shape[0] // n_pods) + x.shape[1:]),
             batch)
-        g, c, m = jax.vmap(one_chunk, in_axes=(0,))(chunked)
-        return g, c, m["loss_sum"]
+        return jax.vmap(one_chunk, in_axes=(0,))(chunked)
 
     def train_step(state: TrainState, batch) -> Tuple[TrainState, Dict]:
         from repro.dist.context import (active_mesh, ambient_mesh,
@@ -264,8 +264,9 @@ def build_step_fns(model: Model, rc: RunConfig):
                     "from core.batch_schedule)")
             b_sched = jnp.asarray(batch["b_sched"], jnp.float32)
             batch = {k: v for k, v in batch.items() if k != "b_sched"}
-        pod_grads, pod_counts, pod_loss = _pod_chunk_grads(
+        pod_grads, pod_counts, pod_sums = _pod_chunk_grads(
             state.params, batch)
+        pod_loss = pod_sums.pop("loss_sum")
 
         if use_arena and variable_delay:
             grad_sum_flat, count, tau_obs, arena_state = \
@@ -327,6 +328,8 @@ def build_step_fns(model: Model, rc: RunConfig):
             "grad_norm": g_norm,
             "step": state.step + 1,
         }
+        # the model's counters (the dropless MoE layer's held rows)
+        metrics.update({k: jnp.sum(v) for k, v in pod_sums.items()})
         if tau_obs is not None:
             # observed staleness of the gradients applied this step
             # (count-weighted). Zero-arrival steps report the ring-cap

@@ -41,19 +41,28 @@ def _split_batch(batch: Dict, n_mb: int) -> Dict:
     return jax.tree.map(r, batch)
 
 
+def _counters(aux: Dict) -> Dict:
+    """The loss's sums other than the count and the loss: a model's
+    counters (the dropless MoE layer's ``moe_held_rows``)."""
+    return {k: v for k, v in aux.items() if k not in ("count", "loss_sum")}
+
+
 def accumulate_scan(loss_fn: LossFn, params, batch: Dict, n_mb: int):
     """Masked scan accumulation.
 
     Returns (grad_sum, count, metrics) where grad_sum is the *sum* of
     per-sample gradients (weighted), count the weighted sample/token
-    count — exactly the worker message m_i(t) = (g_i(t), b_i(t)).
+    count — exactly the worker message m_i(t) = (g_i(t), b_i(t)) — and
+    metrics the loss sum with the model's counters, each summed over
+    the microbatches.
     """
     grad_fn = jax.value_and_grad(loss_fn, has_aux=True)
     if n_mb == 1:  # no scan: keeps roofline measurement loop-free
         (loss_sum, aux), g = grad_fn(params, batch)
         with jax.named_scope("worker.accumulate"):
             g = jax.tree.map(lambda x: x.astype(jnp.float32), g)
-        return g, aux["count"], {"loss_sum": aux["loss_sum"]}
+        return g, aux["count"], {"loss_sum": aux["loss_sum"],
+                                 **_counters(aux)}
     mbs = _split_batch(batch, n_mb)
 
     zeros = jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32), params)
@@ -65,10 +74,12 @@ def accumulate_scan(loss_fn: LossFn, params, batch: Dict, n_mb: int):
         with jax.named_scope("worker.accumulate"):
             gsum = jax.tree.map(lambda a, b: a + b.astype(jnp.float32),
                                 gsum, g)
-        return (gsum, csum + aux["count"], lsum + aux["loss_sum"]), None
+        return ((gsum, csum + aux["count"], lsum + aux["loss_sum"]),
+                _counters(aux))
 
-    (gsum, count, loss_sum), _ = jax.lax.scan(body, carry0, mbs)
-    return gsum, count, {"loss_sum": loss_sum}
+    (gsum, count, loss_sum), counters = jax.lax.scan(body, carry0, mbs)
+    return gsum, count, {"loss_sum": loss_sum,
+                         **jax.tree.map(jnp.sum, counters)}
 
 
 def accumulate_while(loss_fn: LossFn, params, batch: Dict, n_mb: int,
@@ -77,6 +88,7 @@ def accumulate_while(loss_fn: LossFn, params, batch: Dict, n_mb: int,
     microbatches. ``n_active`` may differ across shards — there are no
     collectives in the body, so each device runs its own count and the
     program re-synchronizes at the first cross-device reduction after.
+    The model's counters are not carried on this path.
     """
     mbs = _split_batch(batch, n_mb)
     grad_fn = jax.value_and_grad(loss_fn, has_aux=True)

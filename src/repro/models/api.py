@@ -112,14 +112,18 @@ def build_model(cfg: ModelConfig) -> Model:
         )
 
     if cfg.family in (DENSE, MOE, SSM, HYBRID, VLM):
+        # a latent-attention model trains only: no latent cache here
+        decoder = cfg.has_decoder
         return Model(
             cfg,
             init=lambda k: tf_mod.init(k, cfg),
             loss=lambda p, b: tf_mod.lm_loss(p, cfg, b),
-            init_decode_state=lambda batch, max_len, dtype=jnp.bfloat16:
-                tf_mod.init_decode_state(cfg, batch, max_len, dtype),
-            decode_step=lambda p, c, t, pos: tf_mod.decode_step(
-                p, cfg, c, t, pos),
+            init_decode_state=(
+                lambda batch, max_len, dtype=jnp.bfloat16:
+                tf_mod.init_decode_state(cfg, batch, max_len, dtype)
+            ) if decoder else None,
+            decode_step=(lambda p, c, t, pos: tf_mod.decode_step(
+                p, cfg, c, t, pos)) if decoder else None,
             dummy_batch=lambda b, s, key=None: _lm_dummy_batch(cfg, b, s, key),
             input_specs=lambda b, s: _lm_input_specs(cfg, b, s),
         )

@@ -101,6 +101,8 @@ def prefix_lm_mask(q_len: int, kv_len: int, prefix_len, q_offset=0):
 # ---------------------------------------------------------------------------
 def attention_init(f: ParamFactory, cfg: ModelConfig, name: str = "attn",
                    d_model: Optional[int] = None):
+    if cfg.is_mla:
+        return mla_init(f, cfg, name)
     d = d_model or cfg.d_model
     hd = cfg.resolved_head_dim
     a = f.child(name)
@@ -140,7 +142,8 @@ def _project_qkv(p, cfg: ModelConfig, x, positions, rope: bool = True):
 
 
 def gqa_attend(q, k, v, mask, softcap: Optional[float] = None):
-    """Grouped-query attention core. q: (B,Sq,Hq,D); k,v: (B,Skv,Hkv,D);
+    """Grouped-query attention core. q, k: (B,S,H,D) with Hkv key
+    heads; v: (B,Skv,Hkv,Dv), Dv may differ from D (latent attention);
     mask: broadcastable to (B,Hkv,G,Sq,Skv) or (Sq,Skv)."""
     B, Sq, Hq, D = q.shape
     Hkv = k.shape[2]
@@ -154,18 +157,21 @@ def gqa_attend(q, k, v, mask, softcap: Optional[float] = None):
         mask = mask[None, None, None]
     scores = jnp.where(mask, scores, NEG_INF)
     w = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
-    out = jnp.einsum("bhgqk,bkhd->bqhgd", w, v)
-    return out.reshape(B, Sq, Hq, D)
+    out = jnp.einsum("bhgqk,bkhe->bqhge", w, v)
+    return out.reshape(B, Sq, Hq, v.shape[-1])
 
 
 _Q_CHUNK = 2048          # q-block size for long-sequence attention
 _CHUNK_THRESHOLD = 8192  # chunk when S exceeds this
 
 
-def chunked_gqa_attend(q, k, v, mask_fn, softcap=None, chunk=_Q_CHUNK):
+def chunked_gqa_attend(q, k, v, mask_fn, softcap=None, chunk=_Q_CHUNK,
+                       remat_chunks: bool = False):
     """Exact attention in q-blocks (lazy softmax over the full row per
     block): peak scores memory O(chunk * S) instead of O(S^2). mask_fn
-    (q_offset, q_len) -> (q_len, Skv) bool."""
+    (q_offset, q_len) -> (q_len, Skv) bool. ``remat_chunks``: each
+    block's scores are recomputed in the backward instead of saved,
+    so that one block's scores are live at a time there too."""
     from repro.dist.context import constrain
     # pin batch-sharded activations: with few kv heads (MQA) GSPMD can
     # otherwise trade the batch sharding away and materialize unsharded
@@ -182,9 +188,11 @@ def chunked_gqa_attend(q, k, v, mask_fn, softcap=None, chunk=_Q_CHUNK):
         q_i, m_i = inp
         return None, gqa_attend(q_i, k, v, m_i, softcap)
 
+    if remat_chunks:
+        body = jax.checkpoint(body)
     _, out = jax.lax.scan(body, None,
                           (jnp.moveaxis(qc, 1, 0), masks))
-    return jnp.moveaxis(out, 0, 1).reshape(B, Sq, Hq, D)
+    return jnp.moveaxis(out, 0, 1).reshape(B, Sq, Hq, v.shape[-1])
 
 
 def attention_apply(p, cfg: ModelConfig, x, positions, mask,
@@ -192,6 +200,8 @@ def attention_apply(p, cfg: ModelConfig, x, positions, mask,
     """Full-sequence (train / prefill) attention. For S beyond the chunk
     threshold, pass ``mask_fn`` to enable exact q-block chunking (the
     XLA stand-in for the Pallas flash kernel on TPU)."""
+    if cfg.is_mla:
+        return mla_apply(p, cfg, x, positions, mask, mask_fn)
     q, k, v = _project_qkv(p, cfg, x, positions)
     B, S = x.shape[:2]
     with jax.named_scope("model.mixer"):
@@ -201,6 +211,57 @@ def attention_apply(p, cfg: ModelConfig, x, positions, mask,
         else:
             out = gqa_attend(q, k, v, mask, cfg.logit_softcap)
     return out.reshape(B, S, -1) @ p["wo"].astype(x.dtype)
+
+
+# ---------------------------------------------------------------------------
+# Multi-head latent attention (DeepSeek-V2 section 2.1, no q-LoRA)
+# ---------------------------------------------------------------------------
+_MLA_Q_CHUNK = 1024     # q-block of the latent attention core
+
+
+def mla_init(f: ParamFactory, cfg: ModelConfig, name: str = "attn"):
+    d, H = cfg.d_model, cfg.n_heads
+    nope, rope, r = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+                     cfg.kv_lora_rank)
+    a = f.child(name)
+    a.param("wq", (d, H * (nope + rope)), ("embed", "heads"))
+    # [c_kv | k_pe]: the kv latent and the rope key all heads share
+    a.param("w_kv_a", (d, r + rope), ("embed", None))
+    a.child("kv_latent").param("norm_scale", (r,), (None,), init="ones")
+    a.param("w_kv_b", (r, H * (nope + cfg.v_head_dim)), (None, "heads"))
+    a.param("wo", (H * cfg.v_head_dim, d), ("heads", "embed"))
+
+
+def mla_apply(p, cfg: ModelConfig, x, positions, mask, mask_fn=None):
+    """q = W_q h, split into q_nope and q_pe; [c_kv | k_pe] = W_kv_a h;
+    [k_nope | v] = W_kv_b RMSNorm(c_kv) per head; RoPE on q_pe and on
+    the one k_pe; scores (q_nope.k_nope + q_pe.k_pe) / sqrt(nope + rope),
+    causal; out = W_o over the value heads. x: (B, S, d)."""
+    B, S, _ = x.shape
+    H, nope, rope = cfg.n_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+    r, vd = cfg.kv_lora_rank, cfg.v_head_dim
+    with jax.named_scope("model.attn"):
+        q = (x @ p["wq"].astype(x.dtype)).reshape(B, S, H, nope + rope)
+        kv_a = x @ p["w_kv_a"].astype(x.dtype)
+        c_kv = rmsnorm(kv_a[..., :r], p["kv_latent"]["norm_scale"],
+                       cfg.norm_eps)
+        kv = (c_kv @ p["w_kv_b"].astype(x.dtype)).reshape(B, S, H,
+                                                          nope + vd)
+        q_pe = apply_rope(q[..., nope:], positions, cfg.rope_theta)
+        k_pe = apply_rope(kv_a[..., None, r:], positions, cfg.rope_theta)
+        q = jnp.concatenate([q[..., :nope], q_pe], axis=-1)
+        k = jnp.concatenate(
+            [kv[..., :nope], jnp.broadcast_to(k_pe, (B, S, H, rope))],
+            axis=-1)
+        v = kv[..., nope:]
+    with jax.named_scope("model.mixer"):
+        if mask_fn is not None and S > _MLA_Q_CHUNK and S % _MLA_Q_CHUNK == 0:
+            out = chunked_gqa_attend(q, k, v, mask_fn, cfg.logit_softcap,
+                                     chunk=_MLA_Q_CHUNK, remat_chunks=True)
+        else:
+            out = gqa_attend(q, k, v, mask, cfg.logit_softcap)
+    with jax.named_scope("model.attn"):
+        return out.reshape(B, S, H * vd) @ p["wo"].astype(x.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -294,12 +355,16 @@ def _act(name: str):
     return {"silu": jax.nn.silu, "gelu": jax.nn.gelu}[name]
 
 
-def mlp_apply(p, cfg: ModelConfig, x):
+def gated_mlp(p, cfg: ModelConfig, x):
     act = _act(cfg.act)
+    h = (act(x @ p["w_gate"].astype(x.dtype))
+         * (x @ p["w_up"].astype(x.dtype)))
+    return h @ p["w_down"].astype(x.dtype)
+
+
+def mlp_apply(p, cfg: ModelConfig, x):
     with jax.named_scope("model.mlp"):
-        h = (act(x @ p["w_gate"].astype(x.dtype))
-             * (x @ p["w_up"].astype(x.dtype)))
-        return h @ p["w_down"].astype(x.dtype)
+        return gated_mlp(p, cfg, x)
 
 
 # ---------------------------------------------------------------------------

@@ -20,26 +20,45 @@ Two dispatch implementations, selectable via ``ModelConfig.moe_impl``
 
 Both drop tokens beyond capacity C_g = ceil(top_k * g / E * cf) with
 identical drop order, so they are numerically equivalent.
+
+A third, ``dropless`` (``moe_apply_dropless``), is the held-expert layer
+of expert parallelism with DeepSeek-V3's router: sigmoid scores over
+all ``router_width`` experts, the top-k chosen by the scores plus a
+correction bias no gradient reaches, the chosen scores renormalised
+and scaled. The layer holds ``n_experts`` experts from ``first_expert`` on,
+sorts the assignments that land on its experts by expert, runs the
+gated MLP as grouped products over their rows
+(``kernels/grouped_matmul``) and scatter-adds the weighted rows back to
+their tokens, dropping none. Shared experts, if any, are added once.
+Holding every expert, it is the uncut layer; holding a share, it
+computes that share's part, as one chip of an expert-parallel
+deployment does before the exchange.
 """
 from __future__ import annotations
 
-from typing import Tuple
+from typing import Dict, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
+from repro.kernels.grouped_matmul.ops import gmm
 from repro.models.common import ParamFactory
-from repro.models.layers import _act
+from repro.models.layers import _act, gated_mlp, mlp_init
 
 
 def moe_init(f: ParamFactory, cfg: ModelConfig, name: str = "moe"):
+    mc = cfg.moe
     m = f.child(name)
-    e, d, dff = cfg.moe.n_experts, cfg.d_model, cfg.d_ff
-    m.param("w_router", (d, e), ("embed", None))
+    e, d, dff = mc.n_experts, cfg.d_model, mc.d_expert or cfg.d_ff
+    m.param("w_router", (d, mc.router_width), ("embed", None))
+    if cfg.moe_impl == "dropless":
+        m.param("b_correction", (mc.router_width,), (None,), init="zeros")
     m.param("w_gate", (e, d, dff), ("expert", "embed", "mlp"))
     m.param("w_up", (e, d, dff), ("expert", "embed", "mlp"))
     m.param("w_down", (e, dff, d), ("expert", "mlp", "embed"))
+    if mc.n_shared:
+        mlp_init(m, cfg, "shared", d_ff=mc.n_shared * dff)
 
 
 def _capacity(cfg: ModelConfig, group: int) -> int:
@@ -151,8 +170,72 @@ def moe_apply_gather(p, cfg: ModelConfig, x) -> Tuple[jax.Array, jax.Array]:
     return y.reshape(B, S, d), aux
 
 
-def moe_apply(p, cfg: ModelConfig, x):
+def route(p, cfg: ModelConfig, x):
+    """x: (T, d) -> (weights (T, k) f32, expert ids (T, k) over the
+    router's whole width). In float32 at full precision, as the
+    published router is, so that bf16 rounding flips no choice."""
+    mc = cfg.moe
+    logits = jnp.dot(x.astype(jnp.float32),
+                     p["w_router"].astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    scores = jax.nn.sigmoid(logits)
+    choice = scores + p["b_correction"].astype(jnp.float32)
+    _, idx = jax.lax.top_k(jax.lax.stop_gradient(choice), mc.top_k)
+    w = jnp.take_along_axis(scores, idx, axis=-1)
+    w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+    return w * mc.routed_scale, idx
+
+
+def moe_apply_dropless(p, cfg: ModelConfig, x, impl: str = "auto"
+                       ) -> Tuple[jax.Array, jax.Array]:
+    """The held experts' part of the layer plus the shared experts, no
+    token dropped. x: (B, S, d) -> (y, held rows): the assignments the
+    held experts ran, the rows of each grouped product (f32; the layer
+    has no balance loss). ``impl`` picks the grouped product's kernel
+    (``kernels.grouped_matmul.ops.gmm``)."""
+    mc = cfg.moe
+    B, S, d = x.shape
+    T, E, k = B * S, mc.n_experts, mc.top_k
+    xt = x.reshape(T, d)
+    with jax.named_scope("moe.route"):
+        w, idx = route(p, cfg, xt)
+    with jax.named_scope("moe.dispatch"):
+        local = idx.reshape(T * k) - mc.first_expert
+        expert = jnp.where((local >= 0) & (local < E), local, E)
+        sizes = jnp.zeros((E + 1,), jnp.int32).at[expert].add(1)[:E]
+        # a token's k experts are distinct, so at most T * min(k, E)
+        # assignments land here; sorted, they come first
+        rows = T * min(k, E)
+        order = jnp.argsort(expert, stable=True)[:rows]
+        valid = jnp.arange(rows) < jnp.sum(sizes)
+        tok = order // k
+        xs = jnp.where(valid[:, None], jnp.take(xt, tok, axis=0), 0)
+        ws = jnp.where(valid, jnp.take(w.reshape(T * k), order), 0.0)
+    with jax.named_scope("moe.experts"):
+        h = _act(cfg.act)(gmm(xs, p["w_gate"].astype(x.dtype), sizes,
+                              impl=impl))
+        h = h * gmm(xs, p["w_up"].astype(x.dtype), sizes, impl=impl)
+        ys = gmm(h, p["w_down"].astype(x.dtype), sizes, impl=impl)
+    with jax.named_scope("moe.combine"):
+        ys = jnp.where(valid[:, None], ys.astype(jnp.float32)
+                       * ws[:, None], 0.0)
+        y = jax.ops.segment_sum(ys, tok, num_segments=T).astype(x.dtype)
+    if mc.n_shared:
+        with jax.named_scope("moe.shared"):
+            y = y + gated_mlp(p["shared"], cfg, xt)
+    return y.reshape(B, S, d), jnp.sum(sizes).astype(jnp.float32)
+
+
+def moe_apply(p, cfg: ModelConfig, x) -> Tuple[jax.Array, Dict]:
+    """(y, the layer's sums): ``aux``, the balance loss, and for the
+    dropless layer ``held_rows``."""
     impl = getattr(cfg, "moe_impl", "einsum")
-    if impl == "gather":
-        return moe_apply_gather(p, cfg, x)
-    return moe_apply_einsum(p, cfg, x)
+    with jax.named_scope("model.moe"):
+        if impl == "dropless":
+            y, rows = moe_apply_dropless(p, cfg, x)
+            return y, {"aux": jnp.float32(0.0), "held_rows": rows}
+        if impl == "gather":
+            y, aux = moe_apply_gather(p, cfg, x)
+        else:
+            y, aux = moe_apply_einsum(p, cfg, x)
+        return y, {"aux": aux}

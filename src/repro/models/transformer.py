@@ -3,7 +3,8 @@ with scan-over-layers (stacked params), KV-cache decode, and logical
 sharding axes throughout. Families:
 
   dense  : [attn -> mlp] x L
-  moe    : [attn -> moe_ffn] x L            (mixtral)
+  moe    : [attn -> moe_ffn] x L            (mixtral); moonlight: first
+           [attn -> mlp] x first_k_dense, latent attention (MLA)
   ssm    : [mLSTM | sLSTM] x L              (xlstm; slstm_every-th is sLSTM)
   hybrid : [mamba2] x L + shared attn block every k layers (zamba2)
   vlm    : dense backbone + patch-embedding stub, prefix-LM mask
@@ -49,10 +50,11 @@ def _remat(fn, cfg: ModelConfig):
     return fn
 
 
-def _layer_init(f: ParamFactory, cfg: ModelConfig):
-    """One scanned layer's params (dense/moe/hybrid backbone)."""
+def _layer_init(f: ParamFactory, cfg: ModelConfig, dense: bool = False):
+    """One scanned layer's params (dense/moe/hybrid backbone); ``dense``:
+    an MoE model's leading dense-MLP layer."""
     rmsnorm_init(f, "ln1", cfg.d_model)
-    if cfg.family in (DENSE, VLM):
+    if cfg.family in (DENSE, VLM) or dense:
         attention_init(f, cfg)
         rmsnorm_init(f, "ln2", cfg.d_model)
         mlp_init(f, cfg)
@@ -94,7 +96,10 @@ def init(key, cfg: ModelConfig) -> Tuple[Dict, Dict]:
             rmsnorm_init(sh, "ln2", cfg.d_model)
             mlp_init(sh, cfg)
         else:
-            f.vmapped_children("layers", cfg.n_layers,
+            if cfg.first_k_dense:
+                f.vmapped_children("dense_layers", cfg.first_k_dense,
+                                   lambda g: _layer_init(g, cfg, dense=True))
+            f.vmapped_children("layers", cfg.n_layers - cfg.first_k_dense,
                                lambda g: _layer_init(g, cfg))
         if cfg.family == VLM and cfg.frontend:
             # stub frontend: a learned projection applied to precomputed
@@ -110,20 +115,30 @@ def init(key, cfg: ModelConfig) -> Tuple[Dict, Dict]:
 # ---------------------------------------------------------------------------
 # Forward (train / prefill)
 # ---------------------------------------------------------------------------
+def _layer_sums(cfg: ModelConfig) -> Dict[str, jax.Array]:
+    """Zeros of what the decoder's layers add up beside their output:
+    ``aux``, the MoE balance loss, and for the dropless layer
+    ``held_rows``, the rows its held experts ran."""
+    sums = {"aux": jnp.float32(0.0)}
+    if cfg.family == MOE and cfg.moe_impl == "dropless":
+        sums["held_rows"] = jnp.float32(0.0)
+    return sums
+
+
 def _block_apply(layer_p, cfg: ModelConfig, h, positions, mask,
-                 mask_fn=None):
+                 mask_fn=None, dense: bool = False):
     h = h + attention_apply(
         layer_p["attn"], cfg, rmsnorm(h, layer_p["ln1"], cfg.norm_eps),
         positions, mask, mask_fn=mask_fn)
-    moe_aux = jnp.float32(0.0)
-    if cfg.family == MOE:
-        y, moe_aux = moe_apply(layer_p["moe"], cfg,
-                               rmsnorm(h, layer_p["ln2"], cfg.norm_eps))
+    sums = _layer_sums(cfg)
+    if cfg.family == MOE and not dense:
+        y, sums = moe_apply(layer_p["moe"], cfg,
+                            rmsnorm(h, layer_p["ln2"], cfg.norm_eps))
         h = h + y
     else:
         h = h + mlp_apply(layer_p["mlp"], cfg,
                           rmsnorm(h, layer_p["ln2"], cfg.norm_eps))
-    return h, moe_aux
+    return h, sums
 
 
 def _mamba_block_apply(layer_p, cfg: ModelConfig, h):
@@ -132,9 +147,11 @@ def _mamba_block_apply(layer_p, cfg: ModelConfig, h):
 
 
 def forward(params, cfg: ModelConfig, tokens, *, extra: Optional[Dict] = None,
-            prefix_len=None) -> Tuple[jax.Array, jax.Array]:
+            prefix_len=None) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """tokens: (B, S_text) int32. Returns (logits over full sequence,
-    moe_aux_loss). For VLM, ``extra['patches']`` (B, P, d) is prepended."""
+    the layers' sums: ``aux``, the MoE balance loss, and ``held_rows``
+    for the dropless MoE layer). For VLM, ``extra['patches']`` (B, P, d)
+    is prepended."""
     dtype = jnp.dtype(cfg.compute_dtype)
     with jax.named_scope("model.embed"):
         h = embed_tokens(params, tokens, dtype) * math.sqrt(cfg.d_model)
@@ -155,29 +172,35 @@ def forward(params, cfg: ModelConfig, tokens, *, extra: Optional[Dict] = None,
             qn, S, window=cfg.sliding_window, q_offset=off)
     mask = mask_fn(0, S)
 
-    aux_total = jnp.float32(0.0)
+    sums = _layer_sums(cfg)
 
     if cfg.family == SSM:
-        h, aux_total = _xlstm_forward(params, cfg, h)
+        h, sums["aux"] = _xlstm_forward(params, cfg, h)
     elif cfg.family == HYBRID:
         h = _hybrid_forward(params, cfg, h, positions, mask, mask_fn)
     else:
-        block = _remat(
-            lambda layer_p, hh: _block_apply(layer_p, cfg, hh, positions,
-                                             mask, mask_fn), cfg)
+        def stack(carry, layers, dense):
+            block = _remat(
+                lambda layer_p, hh: _block_apply(layer_p, cfg, hh,
+                                                 positions, mask, mask_fn,
+                                                 dense=dense), cfg)
 
-        def scan_body(carry, layer_p):
-            hh, aux = carry
-            hh, a = block(layer_p, _sp(hh, cfg))
-            return (hh, aux + a), None
-        (h, aux_total), _ = jax.lax.scan(scan_body, (h, aux_total),
-                                         params["layers"],
-                                         unroll=cfg.scan_unroll)
+            def scan_body(carry, layer_p):
+                hh, total = carry
+                hh, s = block(layer_p, _sp(hh, cfg))
+                return (hh, jax.tree.map(jnp.add, total, s)), None
+            return jax.lax.scan(scan_body, carry, layers,
+                                unroll=cfg.scan_unroll)[0]
+
+        carry = (h, sums)
+        if cfg.first_k_dense:
+            carry = stack(carry, params["dense_layers"], True)
+        h, sums = stack(carry, params["layers"], False)
 
     with jax.named_scope("model.head"):
         h = rmsnorm(h, params["ln_final"], cfg.norm_eps)
         logits = output_logits(params, cfg, h)
-    return logits, aux_total
+    return logits, sums
 
 
 def _xlstm_forward(params, cfg: ModelConfig, h):
@@ -243,9 +266,10 @@ def lm_loss(params, cfg: ModelConfig, batch) -> Tuple[jax.Array, Dict]:
     """Per-sample-weighted next-token cross entropy.
 
     batch: {"tokens": (B,S) int32, "weights": (B,) f32, optional extras}.
-    Returns (sum_weighted_loss, {"tokens": weighted token count, ...}) so
-    the AMB-DG aggregation can normalize by the *global* count (paper
-    eq. (5)).
+    Returns (sum_weighted_loss, {"count": weighted token count,
+    "loss_sum", and ``moe_held_rows`` for the dropless MoE layer: the
+    rows its held experts ran, over every MoE layer}) so the AMB-DG
+    aggregation can normalize by the *global* count (paper eq. (5)).
     """
     tokens = batch["tokens"]
     weights = batch.get("weights")
@@ -256,7 +280,7 @@ def lm_loss(params, cfg: ModelConfig, batch) -> Tuple[jax.Array, Dict]:
     # run the forward at the full (power-of-two) sequence length and
     # slice the logits — slicing the *inputs* would make every internal
     # shape odd (S-1) and break sharding divisibility throughout
-    logits, aux = forward(params, cfg, tokens, extra=extra or None)
+    logits, sums = forward(params, cfg, tokens, extra=extra or None)
     # VLM prepends patches: logits cover [patches, text]; loss on text only
     if cfg.family == VLM and "patches" in batch:
         logits = logits[:, batch["patches"].shape[1]:, :]
@@ -269,8 +293,10 @@ def lm_loss(params, cfg: ModelConfig, batch) -> Tuple[jax.Array, Dict]:
     n_tok_per_sample = targets.shape[1]
     loss_sum = jnp.sum(per_sample * weights)
     count = jnp.sum(weights) * n_tok_per_sample
-    return loss_sum + aux * count, {"count": count,
-                                    "loss_sum": loss_sum}
+    out = {"count": count, "loss_sum": loss_sum}
+    if "held_rows" in sums:
+        out["moe_held_rows"] = sums["held_rows"]
+    return loss_sum + sums["aux"] * count, out
 
 
 # ---------------------------------------------------------------------------

@@ -169,6 +169,7 @@ def train(model: Model, rc: RunConfig, loop: LoopConfig,
             extra["remesh_plan"] = plan
         ckpt.save(loop.ckpt_dir, next_step, state, extra=extra)
 
+    held_rows = None
     for step in range(start_step, loop.n_steps):
         # one step span, loop.* spans inside it: recorded only while a
         # profiler session runs (docs/profiling.md)
@@ -223,6 +224,13 @@ def train(model: Model, rc: RunConfig, loop: LoopConfig,
                 batch = jax.tree.map(jax.numpy.asarray, batch)
             with TraceAnnotation("loop.dispatch"):
                 state, metrics = step_fn(state, batch)
+            # the step before's held-expert rows (dropless MoE) named in
+            # a span, since a trace keeps names and not values; read one
+            # step late, so the host never waits on the step it queued
+            if held_rows is not None:
+                with TraceAnnotation(f"moe.held_rows={float(held_rows):.0f}"):
+                    pass
+            held_rows = metrics.get("moe_held_rows")
             if batch_sched is not None:
                 # closed-loop feedback: the step's loss damps adadamp, the
                 # observed staleness feeds the delay-aware scaling

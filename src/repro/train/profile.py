@@ -25,7 +25,8 @@ from typing import Dict, List, NamedTuple, Tuple
 
 OP_NAME_STAT = "tf_op"
 STEP_MODULE = re.compile(r"train_step")
-MODEL_PARTS = ("model.embed", "model.mixer", "model.mlp", "model.head")
+MODEL_PARTS = ("model.embed", "model.attn", "model.mixer", "model.mlp",
+               "model.moe", "model.head")
 HOST_PREP = ("loop.batch", "loop.draws", "loop.put")
 
 
@@ -201,7 +202,8 @@ def _leaves(ops: List[Op]) -> List[Op]:
 def parts(op_name: str) -> List[str]:
     """The parts one op counts toward. A pass (``forward``, ``backward``,
     ``recompute``) from JAX's markers around ``worker.forward``; the
-    innermost model scope under it; ``master`` and its innermost
+    innermost model scope under it, and inside ``model.moe`` its
+    innermost ``moe.*`` scope too; ``master`` and its innermost
     ``master.*`` scope; ``worker.accumulate``; else ``unscoped``."""
     out = []
     if "worker.forward" in op_name:
@@ -214,6 +216,8 @@ def parts(op_name: str) -> List[str]:
         model = [p for p in MODEL_PARTS if p in op_name]
         if model:
             out.append(max(model, key=op_name.rfind))
+        if out[-1] == "model.moe":
+            out += re.findall(r"\bmoe\.\w+", op_name)[-1:]
     elif "master." in op_name:
         out += ["master", re.findall(r"master\.\w+", op_name)[-1]]
     elif "worker.accumulate" in op_name:

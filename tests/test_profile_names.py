@@ -38,7 +38,10 @@ CASES = {
     "variable_ring": ("qwen1.5-0.5b",
                       DelayConfig(process="heavy_tail", tau_max=2,
                                   delay_min=1, seed=3)),
+    "moe": ("moonlight-16b-a3b", DelayConfig()),
 }
+MOE_SCOPES = ("model.attn", "model.moe", "moe.route", "moe.dispatch",
+              "moe.experts", "moe.shared", "moe.combine")
 
 
 def _op_names(case):
@@ -82,6 +85,25 @@ def test_step_carries_every_scope(case):
     assert any("master" in profile.parts(n) for n in names), case
 
 
+def test_moe_step_carries_its_scopes():
+    """Moonlight's step: the latent attention's projections under
+    ``model.attn`` beside its core under ``model.mixer``, and the
+    held-expert layer's five parts under ``model.moe``, each summed by
+    ``profile.parts`` in the three passes."""
+    names = _op_names("moe")
+    joined = "\n".join(names)
+    for scope in MOE_SCOPES:
+        assert scope in joined, scope
+    for p in ("forward", "backward", "recompute"):
+        found = set()
+        for n in names:
+            got = profile.parts(n)
+            if p in got:
+                found.update(got)
+        assert {"model.attn", "model.mixer", "model.moe", "moe.experts",
+                "moe.route", "moe.shared"} <= found, (p, found)
+
+
 def test_train_writes_step_and_loop_spans(tmp_path):
     from jax.profiler import ProfileData
     arch, delay = CASES["variable_ring"]
@@ -116,6 +138,38 @@ def test_train_writes_step_and_loop_spans(tmp_path):
     assert names.count("loop.log") == 1
     # the variable ring's draw carries the step's delay
     assert all("delay" in ev[3] for ev in events if ev[0] == "loop.draws")
+    # a model without the dropless MoE layer names no held rows
+    assert not [e for plane in data.planes for line in plane.lines
+                for e in line.events if e.name.startswith("moe.")]
+
+
+def test_train_names_the_held_rows_of_each_step(tmp_path):
+    """Moonlight's loop names each step's held-expert rows in a span
+    one step late, inside the next step's span: two spans for three
+    steps, each the step's ``moe_held_rows``."""
+    from jax.profiler import ProfileData
+    arch, delay = CASES["moe"]
+    model, rc = _rc(arch, delay=delay)
+    loop = LoopConfig(n_steps=3, n_workers=2, samples_per_worker=2,
+                      log_every=1)
+    train(model, rc, loop)          # compile outside the trace
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        out = train(model, rc, loop)
+    finally:
+        jax.profiler.stop_trace()
+    path = sorted(glob.glob(f"{tmp_path}/**/*.xplane.pb", recursive=True))
+    data = ProfileData.from_file(path[-1])
+    spans = sorted((e.start_ns, e.name) for plane in data.planes
+                   if plane.name.startswith("/host:")
+                   for line in plane.lines for e in line.events
+                   if e.name.startswith("moe.held_rows="))
+    rows = [m["moe_held_rows"] for m in out["history"]]
+    assert [n for _, n in spans] == [f"moe.held_rows={r:.0f}"
+                                     for r in rows[:2]]
+    assert all(r > 0 for r in rows)
 
 
 # one step of a made-up trace: a step module of 100 us; a forward mixer

@@ -30,6 +30,7 @@ from repro.kernels.dual_update.kernel import (dual_update_fused_fwd,
                                               dual_update_fwd)
 from repro.kernels.flash_attention.bwd import flash_attention_train
 from repro.kernels.flash_attention.kernel import flash_attention_fwd
+from repro.kernels.grouped_matmul.ops import gmm
 from repro.models import build_model
 
 BLOCK = 256
@@ -255,6 +256,24 @@ def _flash_attention_grads(q, k, v):
 
 _QKV = [((1, 2, 256, 128), jnp.bfloat16), ((1, 1, 256, 128), jnp.bfloat16),
         ((1, 1, 256, 128), jnp.bfloat16)]
+
+
+def _expert_mlp_grads(x, w_gate, w_up, w_down, sizes):
+    """The held-expert layer's three grouped products, forward and
+    backward (``models/moe.moe_apply_dropless``)."""
+    def loss(x, w_gate, w_up, w_down):
+        def f(w):
+            return gmm(x, w, sizes, impl="pallas", interpret=False)
+        h = jax.nn.silu(f(w_gate)) * f(w_up)
+        y = gmm(h, w_down, sizes, impl="pallas", interpret=False)
+        return jnp.sum(jnp.square(y.astype(jnp.float32)))
+    return jax.grad(loss, argnums=(0, 1, 2, 3))(x, w_gate, w_up, w_down)
+
+
+def _gmm_shapes(rows, d, f, groups):
+    return [((rows, d), jnp.bfloat16), ((groups, d, f), jnp.bfloat16),
+            ((groups, d, f), jnp.bfloat16), ((groups, f, d), jnp.bfloat16),
+            ((groups,), jnp.int32)]
 _R = 1024            # arena rows of the small cases
 # kernel name -> (function, argument shapes): every pallas_call in
 # kernels/ at a small shape the chip takes, but linear_scan's, which the
@@ -292,6 +311,8 @@ NAMED_KERNELS = {
     "flash_attention_fwd": (_flash_attention_grads, _QKV),
     "flash_attention_dq": (_flash_attention_grads, _QKV),
     "flash_attention_dkv": (_flash_attention_grads, _QKV),
+    "moe_gmm": (_expert_mlp_grads, _gmm_shapes(1024, 256, 384, 4)),
+    "moe_tgmm": (_expert_mlp_grads, _gmm_shapes(1024, 256, 384, 4)),
 }
 
 
@@ -305,3 +326,16 @@ def test_kernel_carries_its_name(one_chip, name):
     text = _compile_text(fn, one_chip, *shapes)
     assert re.search(rf"%(?:\w+_)?{name}_*(?:\.\d+)? = .*custom-call\(.*"
                      r"custom_call_target=\"tpu_custom_call\"", text), name
+
+
+def test_grouped_matmul_compiles_at_moonlight_widths(one_chip):
+    """The expert layer's grouped products at the moonlight cell's
+    shapes: one microbatch's 8,192 tokens x 6 choices as rows, the 8
+    held experts of width 1,408 over hidden 2,048, whole expert
+    matrices as one VMEM tile each; the chip takes every block."""
+    text = _compile_text(_expert_mlp_grads, one_chip,
+                         *_gmm_shapes(8192 * 6, 2048, 1408, 8))
+    calls = re.findall(r"%\w*moe_(t?)gmm\w*(?:\.\d+)? = ", text)
+    # forward: gate, up, down; backward: each product's rows (gmm) and
+    # matrices (tgmm)
+    assert sorted(calls) == [""] * 6 + ["t"] * 3, calls
