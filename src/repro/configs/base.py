@@ -127,7 +127,6 @@ class ModelConfig:
     n_classes: int = 0
     # --- implementation knobs (perf levers, not architecture) ---
     moe_impl: str = "einsum"                # "einsum" | "gather" | "dropless" (see models/moe.py)
-    attn_impl: str = "xla"                  # "xla" | "flash" (Pallas, TPU only)
     # fully unroll layer/chunk scans (roofline measurement mode: makes
     # cost_analysis() and the HLO collective census count every
     # iteration instead of one while-loop body)

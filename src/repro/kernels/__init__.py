@@ -4,8 +4,10 @@ The paper's contribution is an optimizer/communication scheme (no kernel
 of its own), but the framework's hot loops get TPU-native kernels:
 
   flash_attention/  blocked causal/SWA attention fwd + custom-vjp bwd
-                    (dq + group-summed dkv kernels, lse recomputation —
-                    no S^2 residuals; MXU 128-tiles)
+                    (dq + dkv kernels, lse recomputation — no S^2
+                    residuals) on the projections' sequence-minor
+                    layout; the training attention core on the TPU
+                    from 1,024 tokens (models.layers.takes_flash)
   linear_scan/      chunked SSD / gated-linear-attention scan
                     (Mamba2 + mLSTM inner loop)
   dual_update/      fused dual-averaging update z += g; w = -alpha z

@@ -1,14 +1,22 @@
-"""Flash-attention backward, Pallas TPU.
+"""Flash-attention backward, Pallas TPU, on the forward's
+sequence-minor layout (kernel.py).
 
-Two kernels over the recomputation trick (no S^2 residuals):
-  * forward (kernel.py) extended to emit the row logsumexp (lse);
-  * dq kernel: grid (B*Hq, nq, nk), kv innermost, dq accumulator in VMEM;
-  * dkv kernel: grid (B*Hq, nk, nq), q innermost, dk/dv accumulators in
-    VMEM — computed per q-head and group-summed outside (GQA).
+Two kernels over the recomputation trick (no S^2 residuals), on the
+blocks and head groups of ``kernel.Plan``, scores held transposed as
+in the forward:
+  * dq kernel: grid (B, groups, nq, nk), kv innermost, dq accumulated
+    in VMEM;
+  * dkv kernel: grid (B, groups, nk, nq), q innermost, dk/dv
+    accumulated in VMEM; the kv block, resident for the whole q loop,
+    is transposed into VMEM once, so no product in the loop takes a
+    transposed left operand; a kv head's q heads share its block
+    (GQA), so their sum is taken in the kernel.
 
-delta = rowsum(do * o) is a cheap elementwise pass done in jnp.
-``flash_attention_train`` wires these into a jax.custom_vjp so
-``jax.grad`` through the kernel matches the reference exactly
+The forward (kernel.py) emits each query's logsumexp; delta =
+rowsum(do * o) is a cheap elementwise pass done in jnp. The residuals
+stay in the kernels' layout.
+``flash_attention_train`` wires these into a jax.custom_vjp, so
+``jax.grad`` through the kernel matches the reference
 (tests/test_kernels_bwd.py, interpret mode).
 """
 from __future__ import annotations
@@ -21,287 +29,155 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.flash_attention.kernel import flash_attention_fwd
-
-NEG_INF = -1e30
-
-
-def _mask(block_q, block_k, qi, ki, *, causal, window, sq, skv):
-    q_pos = qi * block_q + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 0) + (skv - sq)
-    k_pos = ki * block_k + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 1)
-    m = jnp.ones((block_q, block_k), jnp.bool_)
-    if causal:
-        m &= q_pos >= k_pos
-    if window is not None:
-        m &= (q_pos - k_pos) < window
-    return m
+from repro.kernels.flash_attention.kernel import (Plan, dot_nn, dot_nt,
+                                                  dot_tn, flash_forward,
+                                                  flash_forward_t, heads,
+                                                  heads_t, masked, rows,
+                                                  scores_t)
 
 
-# ---------------------------------------------------------------------------
-# Forward with lse output (for the backward recomputation)
-# ---------------------------------------------------------------------------
-def _fwd_lse_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
-                    acc_scr, *, scale, causal, window, block_q, block_k,
-                    sq, skv):
-    qi, ki = pl.program_id(1), pl.program_id(2)
-    nk = pl.num_programs(2)
-
-    @pl.when(ki == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
-
-    q = q_ref[0].astype(jnp.float32)
-    k = k_ref[0].astype(jnp.float32)
-    v = v_ref[0].astype(jnp.float32)
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
-    s = jnp.where(_mask(block_q, block_k, qi, ki, causal=causal,
-                        window=window, sq=sq, skv=skv), s, NEG_INF)
-    m_prev, l_prev = m_scr[...][:, 0], l_scr[...][:, 0]
-    m_cur = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-    corr = jnp.exp(m_prev - m_cur)
-    p = jnp.exp(s - m_cur[:, None])
-    l_cur = l_prev * corr + jnp.sum(p, axis=-1)
-    acc_scr[...] = acc_scr[...] * corr[:, None] + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-    m_scr[...] = m_cur[:, None]
-    l_scr[...] = l_cur[:, None]
-
-    @pl.when(ki == nk - 1)
-    def _fin():
-        l = jnp.maximum(l_scr[...][:, 0], 1e-30)
-        o_ref[0] = (acc_scr[...] / l[:, None]).astype(o_ref.dtype)
-        lse_ref[0] = (m_scr[...][:, 0] + jnp.log(l))[:, None].astype(
-            lse_ref.dtype)
-
-
-def flash_fwd_lse(q, k, v, *, causal, window, scale, block_q, block_k,
-                  interpret):
-    B, Hq, Sq, D = q.shape
-    Hkv, Skv = k.shape[1], k.shape[2]
-    G = Hq // Hkv
-    qf = q.reshape(B * Hq, Sq, D)
-    kf = k.reshape(B * Hkv, Skv, D)
-    vf = v.reshape(B * Hkv, Skv, D)
-
-    def kv_head(bh):
-        return (bh // Hq) * Hkv + (bh % Hq) // G
-
-    out, lse = pl.pallas_call(
-        functools.partial(_fwd_lse_kernel, scale=scale, causal=causal,
-                          window=window, block_q=block_q, block_k=block_k,
-                          sq=Sq, skv=Skv),
-        grid=(B * Hq, Sq // block_q, Skv // block_k),
-        in_specs=[
-            pl.BlockSpec((1, block_q, D), lambda bh, qi, ki: (bh, qi, 0)),
-            pl.BlockSpec((1, block_k, D),
-                         lambda bh, qi, ki: (kv_head(bh), ki, 0)),
-            pl.BlockSpec((1, block_k, D),
-                         lambda bh, qi, ki: (kv_head(bh), ki, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_q, D), lambda bh, qi, ki: (bh, qi, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda bh, qi, ki: (bh, qi, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B * Hq, Sq, D), q.dtype),
-            jax.ShapeDtypeStruct((B * Hq, Sq, 1), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, D), jnp.float32),
-        ],
-        interpret=interpret,
-        name="flash_attention_fwd",
-    )(qf, kf, vf)
-    return out.reshape(B, Hq, Sq, D), lse.reshape(B, Hq, Sq)
-
-
-# ---------------------------------------------------------------------------
-# dq kernel
-# ---------------------------------------------------------------------------
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-               dq_scr, *, scale, causal, window, block_q, block_k, sq, skv):
-    qi, ki = pl.program_id(1), pl.program_id(2)
-    nk = pl.num_programs(2)
+               dq_scr, *, plan: Plan):
+    qi, ki = pl.program_id(2), pl.program_id(3)
+    dk, dv = plan.dk, plan.dv
 
     @pl.when(ki == 0)
     def _init():
         dq_scr[...] = jnp.zeros_like(dq_scr)
 
-    q = q_ref[0].astype(jnp.float32)
-    k = k_ref[0].astype(jnp.float32)
-    v = v_ref[0].astype(jnp.float32)
-    do = do_ref[0].astype(jnp.float32)
-    lse = lse_ref[0][:, 0]
-    delta = delta_ref[0][:, 0]
+    def body(mask):
+        for j, kh in plan.pairs():
+            k, v = rows(k_ref, kh, dk), rows(v_ref, kh, dv)
+            p = jnp.exp(scores_t(plan, k, rows(q_ref, j, dk), mask)
+                        - lse_ref[0, 0, j:j + 1, :])       # (bk, bq)
+            dp = dot_tn(v, rows(do_ref, j, dv))
+            ds = p * (dp - delta_ref[0, 0, j:j + 1, :]) * plan.scale
+            dq_scr[j] += dot_nn(k, ds.astype(k.dtype))
 
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
-    s = jnp.where(_mask(block_q, block_k, qi, ki, causal=causal,
-                        window=window, sq=sq, skv=skv), s, NEG_INF)
-    p = jnp.exp(s - lse[:, None])
-    dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-    ds = p * (dp - delta[:, None]) * scale
-    dq_scr[...] += jax.lax.dot_general(ds, k, (((1,), (0,)), ((), ())),
-                                       preferred_element_type=jnp.float32)
+    plan.run(qi, ki, ki, plan.kv_span(qi), body)
 
-    @pl.when(ki == nk - 1)
+    @pl.when(ki == plan.nk - 1)
     def _fin():
-        dq_ref[0] = dq_scr[...].astype(dq_ref.dtype)
+        for j, _ in plan.pairs():
+            dq_ref[0, j * dk:(j + 1) * dk, :] = dq_scr[j].astype(
+                dq_ref.dtype)
 
 
-# ---------------------------------------------------------------------------
-# dk/dv kernel (per q-head; group-summed outside for GQA)
-# ---------------------------------------------------------------------------
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                dk_ref, dv_ref, dk_scr, dv_scr, *, scale, causal, window,
-                block_q, block_k, sq, skv):
-    ki, qi = pl.program_id(1), pl.program_id(2)
-    nq = pl.num_programs(2)
+                dk_ref, dv_ref, kt_scr, vt_scr, dk_scr, dv_scr, *,
+                plan: Plan):
+    ki, qi = pl.program_id(2), pl.program_id(3)
+    dk, dv = plan.dk, plan.dv
 
     @pl.when(qi == 0)
     def _init():
+        # the kv block stays for the whole q loop: transpose it once
+        for kh in range(plan.hkb):
+            kt_scr[kh] = rows(k_ref, kh, dk).T
+            vt_scr[kh] = rows(v_ref, kh, dv).T
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
 
-    q = q_ref[0].astype(jnp.float32)
-    k = k_ref[0].astype(jnp.float32)
-    v = v_ref[0].astype(jnp.float32)
-    do = do_ref[0].astype(jnp.float32)
-    lse = lse_ref[0][:, 0]
-    delta = delta_ref[0][:, 0]
+    def body(mask):
+        for j, kh in plan.pairs():
+            q, do = rows(q_ref, j, dk), rows(do_ref, j, dv)
+            s = masked(dot_nn(kt_scr[kh], q) * plan.scale, mask)
+            p = jnp.exp(s - lse_ref[0, 0, j:j + 1, :])     # (bk, bq)
+            dv_scr[kh] += dot_nt(do, p.astype(do.dtype))
+            dp = dot_nn(vt_scr[kh], do)
+            ds = p * (dp - delta_ref[0, 0, j:j + 1, :]) * plan.scale
+            dk_scr[kh] += dot_nt(q, ds.astype(q.dtype))
 
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
-    s = jnp.where(_mask(block_q, block_k, qi, ki, causal=causal,
-                        window=window, sq=sq, skv=skv), s, NEG_INF)
-    p = jnp.exp(s - lse[:, None])                        # (bq, bk)
-    dv_scr[...] += jax.lax.dot_general(p, do, (((0,), (0,)), ((), ())),
-                                       preferred_element_type=jnp.float32)
-    dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-    ds = p * (dp - delta[:, None]) * scale               # (bq, bk)
-    dk_scr[...] += jax.lax.dot_general(ds, q, (((0,), (0,)), ((), ())),
-                                       preferred_element_type=jnp.float32)
+    plan.run(qi, ki, qi, plan.q_span(ki), body)
 
-    @pl.when(qi == nq - 1)
+    @pl.when(qi == plan.nq - 1)
     def _fin():
-        dk_ref[0] = dk_scr[...].astype(dk_ref.dtype)
-        dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
+        for kh in range(plan.hkb):
+            dk_ref[0, kh * dk:(kh + 1) * dk, :] = dk_scr[kh].astype(
+                dk_ref.dtype)
+            dv_ref[0, kh * dv:(kh + 1) * dv, :] = dv_scr[kh].astype(
+                dv_ref.dtype)
 
 
-def flash_attention_bwd(q, k, v, o, lse, do, *, causal, window, scale,
-                        block_q, block_k, interpret):
-    B, Hq, Sq, D = q.shape
-    Hkv, Skv = k.shape[1], k.shape[2]
-    G = Hq // Hkv
-    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
-                    axis=-1)                              # (B,Hq,Sq)
+def flash_backward_t(plan: Plan, q_t, k_t, v_t, o_t, lse, do_t, *,
+                     interpret: bool):
+    """dq^T, dk^T, dv^T of ``flash_forward_t``, on the kernels' layout."""
+    delta = jnp.sum((do_t.astype(jnp.float32)
+                     * o_t.astype(jnp.float32)).reshape(
+                         plan.b, plan.groups, plan.hb, plan.dv, plan.sq),
+                    axis=3)                               # as lse
+    args = (q_t, k_t, v_t, do_t, lse, delta)
 
-    qf = q.reshape(B * Hq, Sq, D)
-    kf = k.reshape(B * Hkv, Skv, D)
-    vf = v.reshape(B * Hkv, Skv, D)
-    dof = do.reshape(B * Hq, Sq, D)
-    lsef = lse.reshape(B * Hq, Sq, 1)
-    deltaf = delta.reshape(B * Hq, Sq, 1)
+    def in_specs(rows_of):
+        return [plan.q_spec(plan.dk, rows_of),
+                plan.kv_spec(plan.dk, rows_of),
+                plan.kv_spec(plan.dv, rows_of),
+                plan.q_spec(plan.dv, rows_of),
+                plan.stat_spec(rows_of), plan.stat_spec(rows_of)]
 
-    def kv_head(bh):
-        return (bh // Hq) * Hkv + (bh % Hq) // G
-
-    kw = dict(scale=scale, causal=causal, window=window,
-              block_q=block_q, block_k=block_k, sq=Sq, skv=Skv)
-
-    dq = pl.pallas_call(
-        functools.partial(_dq_kernel, **kw),
-        grid=(B * Hq, Sq // block_q, Skv // block_k),
-        in_specs=[
-            pl.BlockSpec((1, block_q, D), lambda bh, qi, ki: (bh, qi, 0)),
-            pl.BlockSpec((1, block_k, D),
-                         lambda bh, qi, ki: (kv_head(bh), ki, 0)),
-            pl.BlockSpec((1, block_k, D),
-                         lambda bh, qi, ki: (kv_head(bh), ki, 0)),
-            pl.BlockSpec((1, block_q, D), lambda bh, qi, ki: (bh, qi, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda bh, qi, ki: (bh, qi, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda bh, qi, ki: (bh, qi, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, block_q, D),
-                               lambda bh, qi, ki: (bh, qi, 0)),
-        out_shape=jax.ShapeDtypeStruct((B * Hq, Sq, D), q.dtype),
-        scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
+    dq_t = pl.pallas_call(
+        functools.partial(_dq_kernel, plan=plan),
+        grid=(plan.b, plan.groups, plan.nq, plan.nk),
+        in_specs=in_specs(plan.q_rows),
+        out_specs=plan.q_spec(plan.dk, plan.q_rows),
+        out_shape=jax.ShapeDtypeStruct(q_t.shape, q_t.dtype),
+        scratch_shapes=[pltpu.VMEM((plan.hb, plan.dk, plan.bq),
+                                   jnp.float32)],
+        compiler_params=plan.params(),
         interpret=interpret,
         name="flash_attention_dq",
-    )(qf, kf, vf, dof, lsef, deltaf)
+    )(*args)
 
-    # dk/dv per q-head, group-summed after (GQA)
-    dk_h, dv_h = pl.pallas_call(
-        functools.partial(_dkv_kernel, **kw),
-        grid=(B * Hq, Skv // block_k, Sq // block_q),
-        in_specs=[
-            pl.BlockSpec((1, block_q, D), lambda bh, ki, qi: (bh, qi, 0)),
-            pl.BlockSpec((1, block_k, D),
-                         lambda bh, ki, qi: (kv_head(bh), ki, 0)),
-            pl.BlockSpec((1, block_k, D),
-                         lambda bh, ki, qi: (kv_head(bh), ki, 0)),
-            pl.BlockSpec((1, block_q, D), lambda bh, ki, qi: (bh, qi, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda bh, ki, qi: (bh, qi, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda bh, ki, qi: (bh, qi, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_k, D), lambda bh, ki, qi: (bh, ki, 0)),
-            pl.BlockSpec((1, block_k, D), lambda bh, ki, qi: (bh, ki, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B * Hq, Skv, D), q.dtype),
-            jax.ShapeDtypeStruct((B * Hq, Skv, D), q.dtype),
-        ],
+    dk_t, dv_t = pl.pallas_call(
+        functools.partial(_dkv_kernel, plan=plan),
+        grid=(plan.b, plan.groups, plan.nk, plan.nq),
+        in_specs=in_specs(plan.kv_rows),
+        out_specs=[plan.kv_spec(plan.dk, plan.kv_rows),
+                   plan.kv_spec(plan.dv, plan.kv_rows)],
+        out_shape=[jax.ShapeDtypeStruct(k_t.shape, k_t.dtype),
+                   jax.ShapeDtypeStruct(v_t.shape, v_t.dtype)],
         scratch_shapes=[
-            pltpu.VMEM((block_k, D), jnp.float32),
-            pltpu.VMEM((block_k, D), jnp.float32),
+            pltpu.VMEM((plan.hkb, plan.bk, plan.dk), k_t.dtype),
+            pltpu.VMEM((plan.hkb, plan.bk, plan.dv), v_t.dtype),
+            pltpu.VMEM((plan.hkb, plan.dk, plan.bk), jnp.float32),
+            pltpu.VMEM((plan.hkb, plan.dv, plan.bk), jnp.float32),
         ],
+        compiler_params=plan.params(),
         interpret=interpret,
         name="flash_attention_dkv",
-    )(qf, kf, vf, dof, lsef, deltaf)
-
-    dk = dk_h.reshape(B, Hkv, G, Skv, D).sum(axis=2).astype(k.dtype)
-    dv = dv_h.reshape(B, Hkv, G, Skv, D).sum(axis=2).astype(v.dtype)
-    return dq.reshape(B, Hq, Sq, D), dk, dv
+    )(*args)
+    return dq_t, dk_t, dv_t
 
 
-# ---------------------------------------------------------------------------
-# custom_vjp wrapper
-# ---------------------------------------------------------------------------
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def flash_attention_train(q, k, v, causal=True, window=None,
-                          block_q=128, block_k=128, interpret=False):
-    D = q.shape[-1]
-    return flash_attention_fwd(q, k, v, causal=causal, window=window,
-                               block_q=block_q, block_k=block_k,
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def flash_attention_train(q, k, v, causal: bool = True,
+                          window: Optional[int] = None,
+                          interpret: bool = False):
+    """Differentiable flash attention. q: (B, Sq, Hq, Dk); k: (B, Skv,
+    Hkv, Dk); v: (B, Skv, Hkv, Dv) -> (B, Sq, Hq, Dv)."""
+    return flash_forward(q, k, v, causal=causal, window=window,
+                         interpret=interpret)
+
+
+def _train_fwd(q, k, v, causal, window, interpret):
+    plan = Plan(q.shape, k.shape, v.shape, causal=causal, window=window)
+    args = (heads_t(q), heads_t(k), heads_t(v))
+    o_t, lse = flash_forward_t(plan, *args, with_lse=True,
                                interpret=interpret)
+    return heads(o_t, plan.hq), (*args, o_t, lse)
 
 
-def _train_fwd(q, k, v, causal, window, block_q, block_k, interpret):
-    D = q.shape[-1]
-    o, lse = flash_fwd_lse(q, k, v, causal=causal, window=window,
-                           scale=D ** -0.5, block_q=block_q,
-                           block_k=block_k, interpret=interpret)
-    return o, (q, k, v, o, lse)
-
-
-def _train_bwd(causal, window, block_q, block_k, interpret, res, do):
-    q, k, v, o, lse = res
-    D = q.shape[-1]
-    dq, dk, dv = flash_attention_bwd(
-        q, k, v, o, lse, do, causal=causal, window=window,
-        scale=D ** -0.5, block_q=block_q, block_k=block_k,
-        interpret=interpret)
-    return dq, dk, dv
+def _train_bwd(causal, window, interpret, res, do):
+    q_t, k_t, v_t, o_t, lse = res
+    b, sq, hq, dv = do.shape
+    hkv, skv = v_t.shape[1] // dv, v_t.shape[2]
+    plan = Plan((b, sq, hq, q_t.shape[1] // hq),
+                (b, skv, hkv, k_t.shape[1] // hkv), (b, skv, hkv, dv),
+                causal=causal, window=window)
+    grads = flash_backward_t(plan, q_t, k_t, v_t, o_t, lse, heads_t(do),
+                             interpret=interpret)
+    return tuple(heads(g, n) for g, n in
+                 zip(grads, (plan.hq, plan.hkv, plan.hkv)))
 
 
 flash_attention_train.defvjp(_train_fwd, _train_bwd)

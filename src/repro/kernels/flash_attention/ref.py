@@ -11,14 +11,15 @@ NEG_INF = -1e30
 
 def attention_ref(q, k, v, *, causal: bool = True,
                   window: Optional[int] = None, scale: Optional[float] = None):
-    """q: (B, Hq, Sq, D); k, v: (B, Hkv, Skv, D). GQA via head grouping.
-    Returns (B, Hq, Sq, D) in q.dtype; softmax in f32."""
-    B, Hq, Sq, D = q.shape
-    Hkv, Skv = k.shape[1], k.shape[2]
+    """q: (B, Sq, Hq, D); k: (B, Skv, Hkv, D); v: (B, Skv, Hkv, Dv). GQA
+    via head grouping. Returns (B, Sq, Hq, Dv) in q.dtype; softmax in
+    f32."""
+    B, Sq, Hq, D = q.shape
+    Skv, Hkv = k.shape[1], k.shape[2]
     G = Hq // Hkv
     scale = scale if scale is not None else D ** -0.5
-    qg = q.reshape(B, Hkv, G, Sq, D)
-    s = jnp.einsum("bhgqd,bhkd->bhgqk", qg.astype(jnp.float32),
+    qg = q.reshape(B, Sq, Hkv, G, D)
+    s = jnp.einsum("bqhgd,bkhd->bhgqk", qg.astype(jnp.float32),
                    k.astype(jnp.float32)) * scale
     q_pos = jnp.arange(Sq)[:, None] + (Skv - Sq)  # right-aligned queries
     k_pos = jnp.arange(Skv)[None, :]
@@ -29,5 +30,5 @@ def attention_ref(q, k, v, *, causal: bool = True,
         mask &= (q_pos - k_pos) < window
     s = jnp.where(mask[None, None, None], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
-    o = jnp.einsum("bhgqk,bhkd->bhgqd", p, v.astype(jnp.float32))
-    return o.reshape(B, Hq, Sq, D).astype(q.dtype)
+    o = jnp.einsum("bhgqk,bkhe->bqhge", p, v.astype(jnp.float32))
+    return o.reshape(B, Sq, Hq, v.shape[-1]).astype(q.dtype)

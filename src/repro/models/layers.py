@@ -11,7 +11,11 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro import kernels
 from repro.configs.base import ModelConfig
+from repro.dist.context import active_mesh
+from repro.kernels.flash_attention.bwd import flash_attention_train
+from repro.kernels.flash_attention.kernel import seq_block
 from repro.models.common import ParamFactory
 
 NEG_INF = -1e30
@@ -163,6 +167,38 @@ def gqa_attend(q, k, v, mask, softcap: Optional[float] = None):
 
 _Q_CHUNK = 2048          # q-block size for long-sequence attention
 _CHUNK_THRESHOLD = 8192  # chunk when S exceeds this
+# the Pallas flash kernel from this length up. A call's device time on
+# the v5e, fitted over the training cells' layer calls (jnp at 512,
+# 2,048 and 8,192 tokens; the kernel with (B, H, S, D) relayouts):
+# jnp ~ 0.076 ms x (S/512)^2, flash ~ 0.092 ms + 0.031 ms x (S/512)^2,
+# so flash wins above ~730 tokens (1,024: 0.22 against 0.30 ms) and
+# loses at 512 (0.123 against 0.076 ms), where the fixed cost of the
+# kernel's grid outweighs the small causal square
+_FLASH_THRESHOLD = 1024
+
+
+def takes_flash(cfg: ModelConfig, seq_len: int, causal: bool) -> bool:
+    """Whether a full-sequence attention core runs the Pallas flash
+    kernel: on the TPU, outside any mesh of more than one device (the
+    kernel has no shard_map wrapper, so GSPMD would gather q, k and v to
+    every device, as ``kernels.resolve_impl`` says of the arena
+    kernels), for the causal (optionally sliding-window) mask that
+    ``transformer.forward`` builds (``causal``: the caller says so, and
+    a prefix-LM caller says not), with no logit softcap, at ``seq_len``
+    of at least ``_FLASH_THRESHOLD`` that the kernel's blocks divide.
+    Every other call keeps the jnp core."""
+    mesh = active_mesh()
+    return (kernels.on_tpu() and (mesh is None or mesh.n_devices == 1)
+            and causal and cfg.logit_softcap is None
+            and seq_len >= _FLASH_THRESHOLD
+            and seq_block(seq_len) is not None)
+
+
+def flash_attend(cfg: ModelConfig, q, k, v):
+    """The causal core through the flash kernel: q, k (B, S, H, D), v
+    (B, S, Hkv, Dv) -> (B, S, H, Dv)."""
+    return flash_attention_train(q, k, v, True, cfg.sliding_window,
+                                 kernels.resolve_interpret(None))
 
 
 def chunked_gqa_attend(q, k, v, mask_fn, softcap=None, chunk=_Q_CHUNK,
@@ -196,16 +232,21 @@ def chunked_gqa_attend(q, k, v, mask_fn, softcap=None, chunk=_Q_CHUNK,
 
 
 def attention_apply(p, cfg: ModelConfig, x, positions, mask,
-                    d_model: Optional[int] = None, mask_fn=None):
-    """Full-sequence (train / prefill) attention. For S beyond the chunk
-    threshold, pass ``mask_fn`` to enable exact q-block chunking (the
-    XLA stand-in for the Pallas flash kernel on TPU)."""
+                    d_model: Optional[int] = None, mask_fn=None,
+                    causal: bool = False):
+    """Full-sequence (train / prefill) attention. ``causal``: ``mask``
+    is the causal (or sliding-window) mask of ``transformer.forward``,
+    which lets ``takes_flash`` choose the Pallas kernel. Otherwise, for
+    S beyond the chunk threshold, pass ``mask_fn`` to enable exact
+    q-block chunking."""
     if cfg.is_mla:
-        return mla_apply(p, cfg, x, positions, mask, mask_fn)
+        return mla_apply(p, cfg, x, positions, mask, mask_fn, causal)
     q, k, v = _project_qkv(p, cfg, x, positions)
     B, S = x.shape[:2]
     with jax.named_scope("model.mixer"):
-        if (mask_fn is not None and S > _CHUNK_THRESHOLD
+        if takes_flash(cfg, S, causal):
+            out = flash_attend(cfg, q, k, v)
+        elif (mask_fn is not None and S > _CHUNK_THRESHOLD
                 and S % _Q_CHUNK == 0):
             out = chunked_gqa_attend(q, k, v, mask_fn, cfg.logit_softcap)
         else:
@@ -232,7 +273,8 @@ def mla_init(f: ParamFactory, cfg: ModelConfig, name: str = "attn"):
     a.param("wo", (H * cfg.v_head_dim, d), ("heads", "embed"))
 
 
-def mla_apply(p, cfg: ModelConfig, x, positions, mask, mask_fn=None):
+def mla_apply(p, cfg: ModelConfig, x, positions, mask, mask_fn=None,
+              causal: bool = False):
     """q = W_q h, split into q_nope and q_pe; [c_kv | k_pe] = W_kv_a h;
     [k_nope | v] = W_kv_b RMSNorm(c_kv) per head; RoPE on q_pe and on
     the one k_pe; scores (q_nope.k_nope + q_pe.k_pe) / sqrt(nope + rope),
@@ -255,7 +297,10 @@ def mla_apply(p, cfg: ModelConfig, x, positions, mask, mask_fn=None):
             axis=-1)
         v = kv[..., nope:]
     with jax.named_scope("model.mixer"):
-        if mask_fn is not None and S > _MLA_Q_CHUNK and S % _MLA_Q_CHUNK == 0:
+        if takes_flash(cfg, S, causal):
+            out = flash_attend(cfg, q, k, v)
+        elif (mask_fn is not None and S > _MLA_Q_CHUNK
+                and S % _MLA_Q_CHUNK == 0):
             out = chunked_gqa_attend(q, k, v, mask_fn, cfg.logit_softcap,
                                      chunk=_MLA_Q_CHUNK, remat_chunks=True)
         else:

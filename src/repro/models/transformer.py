@@ -129,7 +129,7 @@ def _block_apply(layer_p, cfg: ModelConfig, h, positions, mask,
                  mask_fn=None, dense: bool = False):
     h = h + attention_apply(
         layer_p["attn"], cfg, rmsnorm(h, layer_p["ln1"], cfg.norm_eps),
-        positions, mask, mask_fn=mask_fn)
+        positions, mask, mask_fn=mask_fn, causal=not cfg.prefix_lm)
     sums = _layer_sums(cfg)
     if cfg.family == MOE and not dense:
         y, sums = moe_apply(layer_p["moe"], cfg,
@@ -242,7 +242,8 @@ def _hybrid_forward(params, cfg: ModelConfig, h, positions, mask,
         lambda _p, hh: hh
         + attention_apply(sh["attn"], cfg,
                           rmsnorm(hh, sh["ln1"], cfg.norm_eps),
-                          positions, mask, mask_fn=mask_fn), cfg)
+                          positions, mask, mask_fn=mask_fn,
+                          causal=not cfg.prefix_lm), cfg)
 
     def group_body(hh, group_p):
         def layer_body(hhh, layer_p):

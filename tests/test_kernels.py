@@ -23,12 +23,19 @@ from repro.kernels.linear_scan.ref import linear_scan_ref
         (2, 4, 1, 256, 512, 64, True, None),      # MQA, right-aligned q
         (1, 4, 2, 256, 256, 64, True, 128),       # sliding window
         (1, 2, 2, 128, 256, 64, False, None),     # bidirectional
+        # a (q/k, v) width pair: 512-blocks, kv blocks above the
+        # diagonal skipped; latent attention's 192/128; zamba2's 80
+        (1, 2, 2, 1024, 1024, (64, 64), True, None),
+        (1, 2, 2, 1024, 1024, (192, 128), True, None),
+        (1, 4, 4, 1024, 1024, (80, 80), True, None),
+        (1, 1, 1, 2048, 2048, (64, 64), True, 300),  # blocks skipped below
     ])
 def test_flash_attention(B, Hq, Hkv, Sq, Skv, D, causal, window, dtype):
+    dk, dv = D if isinstance(D, tuple) else (D, D)
     keys = jax.random.split(jax.random.PRNGKey(0), 3)
-    q = jax.random.normal(keys[0], (B, Hq, Sq, D), dtype)
-    k = jax.random.normal(keys[1], (B, Hkv, Skv, D), dtype)
-    v = jax.random.normal(keys[2], (B, Hkv, Skv, D), dtype)
+    q = jax.random.normal(keys[0], (B, Sq, Hq, dk), dtype)
+    k = jax.random.normal(keys[1], (B, Skv, Hkv, dk), dtype)
+    v = jax.random.normal(keys[2], (B, Skv, Hkv, dv), dtype)
     out = flash_attention(q, k, v, causal=causal, window=window,
                           interpret=True)
     ref = attention_ref(q, k, v, causal=causal, window=window)

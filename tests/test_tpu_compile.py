@@ -29,7 +29,7 @@ from repro.kernels.delay_ring.kernel import (delay_ring_fwd,
 from repro.kernels.dual_update.kernel import (dual_update_fused_fwd,
                                               dual_update_fwd)
 from repro.kernels.flash_attention.bwd import flash_attention_train
-from repro.kernels.flash_attention.kernel import flash_attention_fwd
+from repro.kernels.flash_attention.kernel import flash_forward
 from repro.kernels.grouped_matmul.ops import gmm
 from repro.models import build_model
 
@@ -254,8 +254,13 @@ def _flash_attention_grads(q, k, v):
     return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
 
 
-_QKV = [((1, 2, 256, 128), jnp.bfloat16), ((1, 1, 256, 128), jnp.bfloat16),
-        ((1, 1, 256, 128), jnp.bfloat16)]
+def _qkv(b, s, hq, hkv, dk, dv):
+    """q, k, v in the model's (B, S, H, D) layout, bf16."""
+    return [((b, s, hq, dk), jnp.bfloat16), ((b, s, hkv, dk), jnp.bfloat16),
+            ((b, s, hkv, dv), jnp.bfloat16)]
+
+
+_QKV = _qkv(1, 256, 2, 1, 128, 128)
 
 
 def _expert_mlp_grads(x, w_gate, w_up, w_down, sizes):
@@ -307,7 +312,7 @@ NAMED_KERNELS = {
         functools.partial(variable_pop_fwd, block_rows=BLOCK),
         [((N_SLOTS, 1, _R, 128), jnp.int8), ((N_SLOTS,), jnp.bool_),
          ((N_SLOTS, 1, _R), jnp.float32)]),
-    "flash_attention": (flash_attention_fwd, _QKV),
+    "flash_attention": (flash_forward, _QKV),
     "flash_attention_fwd": (_flash_attention_grads, _QKV),
     "flash_attention_dq": (_flash_attention_grads, _QKV),
     "flash_attention_dkv": (_flash_attention_grads, _QKV),
@@ -326,6 +331,69 @@ def test_kernel_carries_its_name(one_chip, name):
     text = _compile_text(fn, one_chip, *shapes)
     assert re.search(rf"%(?:\w+_)?{name}_*(?:\.\d+)? = .*custom-call\(.*"
                      r"custom_call_target=\"tpu_custom_call\"", text), name
+
+
+# the training cells' attention cores, and a GQA group: (B, S, Hq, Hkv,
+# q/k width, v width)
+FLASH_WIDTHS = {
+    "qwen1.5-0.5b": (1, 2048, 16, 16, 64, 64),
+    "zamba2-2.7b": (1, 4096, 32, 32, 80, 80),
+    "moonlight-16b-a3b": (1, 8192, 16, 16, 192, 128),
+    "gqa-4": (1, 1024, 8, 2, 128, 128),
+}
+
+
+@pytest.mark.parametrize("model", sorted(FLASH_WIDTHS))
+def test_flash_attention_compiles_at_cell_widths(one_chip, model):
+    """The flash forward and backward at the widths the cells train
+    (bf16 operands, 512-blocks, causal skipping): the chip takes every
+    block, and each kernel appears once."""
+    text = _compile_text(_flash_attention_grads, one_chip,
+                         *_qkv(*FLASH_WIDTHS[model]))
+    calls = re.findall(r"%\w*flash_attention_(fwd|dq|dkv)\w*(?:\.\d+)? = ",
+                       text)
+    assert sorted(calls) == ["dkv", "dq", "fwd"], calls
+
+
+def _relayouts(text: str):
+    """The copy and transpose ops of a compiled program, scalars aside."""
+    return [line.split(" = ")[1].split("{")[0] for line in text.splitlines()
+            if re.search(r" = \w+\[[\d,]+\]\S* (copy|transpose)\(", line)]
+
+
+def test_flash_adds_no_relayout_to_the_dense_gradient(one_chip,
+                                                      monkeypatch):
+    """qwen1.5-0.5b's gradient at 2,048 tokens (2 layers), compiled for
+    the chip with the jnp core and with the flash kernel: the kernels
+    read and write the layout XLA gives the projections, so the kernel's
+    program has no copy or transpose the jnp one lacks."""
+    import repro.kernels
+    cfg = dataclasses.replace(C.get_config("qwen1.5-0.5b"), n_layers=2,
+                              vocab_size=8192)
+    model = build_model(cfg)
+    params = jax.eval_shape(lambda k: model.init(k)[0],
+                            jax.random.PRNGKey(0))
+    shapes = [(x.shape, x.dtype) for x in jax.tree.leaves(
+        (params, model.input_specs(1, 2048)))]
+    treedef = jax.tree.structure((params, model.input_specs(1, 2048)))
+
+    def grad(*leaves):
+        p, batch = jax.tree.unflatten(treedef, leaves)
+        return jax.grad(lambda p: model.loss(p, batch)[0])(p)
+
+    texts = {}
+    for platform in ("cpu", "tpu"):
+        monkeypatch.setattr(repro.kernels, "on_tpu",
+                            lambda: platform == "tpu")
+        # a fresh function each time: jit would re-use the first trace
+        texts[platform] = _compile_text(lambda *a: grad(*a), one_chip,
+                                        *shapes)
+    assert "flash_attention_dkv" in texts["tpu"]
+    assert "flash_attention" not in texts["cpu"]
+    jnp_ops, flash_ops = _relayouts(texts["cpu"]), _relayouts(texts["tpu"])
+    extra = [op for op in set(flash_ops)
+             if flash_ops.count(op) > jnp_ops.count(op)]
+    assert not extra, extra
 
 
 def test_grouped_matmul_compiles_at_moonlight_widths(one_chip):
